@@ -31,7 +31,8 @@ def main():
                             jnp.int32)
         gids = jnp.arange(E, dtype=jnp.int32)
         cc = clip_cumul(cumul, jnp.int32(F_SZ))
-        k_kernel = binsearch_map(cc, gids, tile=512, window=256)
+        k_kernel = binsearch_map(cc, gids, tile=512, window=256,
+                                 interpret=True)
         k_ref = R.binsearch_map_ref(cumul, gids)
         ok = np.asarray(gids) < int(cumul[-1])
         assert (np.asarray(k_kernel)[ok] == np.asarray(k_ref)[ok]).all()
@@ -45,7 +46,7 @@ def main():
     words = jnp.asarray(
         rng.integers(0, 2**32, size=(1 << 16) // 32, dtype=np.uint64)
         .astype(np.uint32))
-    won = visited_filter(v, valid, words, tile=256)
+    won = visited_filter(v, valid, words, tile=256, interpret=True)
     wref = [R.visited_filter_ref(v[i:i + 256], valid[i:i + 256], words)
             for i in range(0, 1 << 15, 256)]
     assert (np.asarray(won) == np.concatenate([np.asarray(w) for w in wref])).all()

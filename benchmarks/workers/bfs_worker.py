@@ -46,6 +46,9 @@ from repro.api import BFSConfig, DistGraph
 from repro.core.validate import count_component_edges, harmonic_mean
 from repro.dist.compat import make_mesh
 from repro.graphgen import rmat_edges
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 n = 1 << SCALE
 edges_np = np.asarray(rmat_edges(jax.random.key(42), SCALE, EF))

@@ -37,6 +37,9 @@ from repro.core import frontier as F
 from repro.core.partition import partition_2d_csr
 from repro.core.types import Grid2D
 from repro.graphgen import rmat_edges
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 n = 1 << SCALE
 edges = np.asarray(rmat_edges(jax.random.key(42), SCALE, EF))

@@ -31,6 +31,9 @@ import numpy as np
 from repro.api import BFSConfig, DistGraph
 from repro.dist.compat import make_mesh
 from repro.graphgen import rmat_edges
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 STRATEGIES = ("flat", "butterfly")
 CODECS = ("list", "bitmap", "delta")

@@ -29,6 +29,9 @@ import numpy as np
 from repro.core import Grid2D, partition_2d
 from repro.core import frontier as F
 from repro.graphgen import rmat_edges
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 n = 1 << SCALE
 edges = np.asarray(rmat_edges(jax.random.key(42), SCALE, EF))

@@ -32,7 +32,10 @@ import numpy as np
 
 from repro.api import BFSConfig, DistGraph
 from repro.graphgen import rmat_edges
+from repro.launch.compile_cache import use_compile_cache
 from repro.scenarios import run_matrix, standard_matrix
+
+use_compile_cache()
 
 N = 1 << SCALE
 edges = np.asarray(rmat_edges(jax.random.key(42), SCALE, EF))

@@ -42,8 +42,11 @@ import numpy as np
 from repro.api import BFSConfig, DistGraph
 from repro.dist.compat import make_mesh
 from repro.graphgen import rmat_edges
+from repro.launch.compile_cache import use_compile_cache
 from repro.runtime.fault import FaultInjector, RetryPolicy
 from repro.serve import GraphServer, ServeConfig
+
+use_compile_cache()
 
 mesh = make_mesh((R, C), ("r", "c"))
 config = BFSConfig(grid=(R, C), edge_chunk=16384, fold_codec="list")

@@ -41,6 +41,9 @@ from repro.api import BFSConfig, DistGraph
 from repro.core.validate import count_component_edges
 from repro.dist.compat import make_mesh
 from repro.graphgen import rmat_edges
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 CODECS = ("list", "bitmap", "delta")
 

@@ -595,14 +595,9 @@ class FrontierEngine:
         return tr
 
     def _place_carry(self, carry):
-        """Host (R, C[, B], ...) leaves -> device arrays on this topology's
-        mesh (the `reshard_state` placement of elastic resume; in a process
-        group, global-array construction via multihost.put_dev)."""
+        """Host (R, C[, B], ...) leaves -> device arrays sharded over this
+        topology's mesh (the placement of elastic resume)."""
         from repro.dist import multihost
         mesh, dev = self.topo.mesh, self.topo.dev_spec
-        if multihost.is_multiprocess():
-            return jax.tree_util.tree_map(
-                lambda x: multihost.put_dev(x, mesh, dev), carry)
-        from repro.ckpt.elastic import reshard_state
-        spec_tree = jax.tree_util.tree_map(lambda x: dev, carry)
-        return reshard_state(carry, spec_tree, mesh)
+        return jax.tree_util.tree_map(
+            lambda x: multihost.put_dev(x, mesh, dev), carry)

@@ -189,8 +189,9 @@ def scan_relax(col_off, row_idx, edge_vals, all_front, all_payload,
     For each edge u -> v of a frontier column u, proposes
     `relax(payload[u], edge_vals[edge])` for v; proposals for the same v
     combine by MIN (the monoid), so the result is independent of scan order.
-    Same chunked searchsorted edge walk as `frontier.expand_frontier`
-    (paper Alg. 3), same O(frontier edges + chunk) cost per level.
+    Same chunked edge walk as `frontier.expand_frontier` (paper Alg. 3,
+    slots by `frontier.edge_slots`), same O(frontier edges + chunk) cost
+    per level.
 
     expand_fn: optional value-carrying kernel override for one chunk (the
     fused Pallas path, `repro.kernels.expand.make_value_expand_fn`):

@@ -33,7 +33,7 @@ from repro.core.partition import (partition_2d, partition_2d_csr,
                                   partition_edge_vals,
                                   partition_edge_vals_csr)
 from repro.core.types import BFSOutput, LocalGraph2D
-from repro.core.validate import validate_bfs
+from repro.core.validate import edge_keys, validate_bfs
 from repro.dist import multihost
 from repro.dist.engine import DistBFSEngine
 from repro.dist.topology import Topology
@@ -182,9 +182,9 @@ class DistGraph:
                                      config.col_axes)
         lg = partition_2d(edges_np, grid)
         # device placement: per-device (R, C, ...) arrays land sharded over
-        # the grid axes -- a global jax.Array in a process group (every
-        # process materialises only its addressable shards), a plain local
-        # array otherwise (repro.dist.multihost)
+        # the grid axes, each device holding only its own block (in a
+        # process group every process materialises only its addressable
+        # shards; repro.dist.multihost)
         place = cls._placer(topology)
         csc = LocalGraph2D(place(lg.col_off), place(lg.row_idx),
                            place(lg.nnz))
@@ -203,7 +203,7 @@ class DistGraph:
     @staticmethod
     def _placer(topology: Topology):
         """Placement fn for per-device (R, C, ...) arrays on this topology
-        (global sharded array in a process group, plain local otherwise)."""
+        (sharded over the grid axes, one block per device)."""
         return lambda x: multihost.put_dev(x, topology.mesh,
                                            topology.dev_spec)
 
@@ -407,8 +407,9 @@ class GraphSession:
         n = self.graph.n
         level = np.asarray(out.level)
         pred = np.asarray(out.pred)
+        keys = edge_keys(edges, n)
         for b, root in enumerate(roots):
-            validate_bfs(edges, level[b][:n], pred[b][:n], int(root))
+            validate_bfs(edges, level[b][:n], pred[b][:n], int(root), keys)
 
     # ------------------------------------------------------------------
     # Frontier programs beyond BFS (DESIGN.md sec. 8)

@@ -10,8 +10,8 @@ Adaptation notes (DESIGN.md sec. 3):
     edge slot to reach v wins, deterministically);
   * `atomicInc` bucket append     -> stable sort by destination column +
     per-segment positions (the paper's own pre-Kepler compact variant);
-  * thread-per-edge scan+search   -> vectorised searchsorted over the
-    exclusive-scanned degree array, processed in fixed-size chunks inside a
+  * thread-per-edge scan+search   -> tiled search over the exclusive-scanned
+    degree array (`edge_slots`), processed in fixed-size chunks inside a
     `lax.while_loop` so per-level work stays O(frontier edges + chunk).
 """
 from __future__ import annotations
@@ -30,6 +30,107 @@ def exclusive_cumsum(x):
     """Thrust exclusive_scan equivalent, returns len(x)+1 (with total)."""
     c = jnp.cumsum(x, dtype=jnp.int32)
     return jnp.concatenate([jnp.zeros((1,), jnp.int32), c])
+
+
+def pick_tile(e: int, tile: int) -> int:
+    """Largest DIVISOR of the chunk length <= tile.  Never rounds UP to e:
+    a tile is a dense (tile, window) or (tile, tile) compare, so one e-wide
+    tile on a big odd chunk would be quadratic in the chunk.  Both
+    arguments are static (e is the engine's edge_chunk), so this runs at
+    trace time."""
+    t = min(tile, e)
+    while e % t:
+        t -= 1
+    return t
+
+
+def map_workload_tile(gid, cumul, *, window: int, n_cumul: int):
+    """k[t] = max { l : cumul[l] <= gid[t] } for ONE tile of consecutive edge
+    ids, as dense vector work (the paper's thread->edge mapping, sec. 3.4).
+
+    The ids of a tile are consecutive, so their k form a non-decreasing run
+    [k0, k_last]: one scalar binary search finds k0, then W-wide windowed
+    broadcast-compares count, per lane, the entries in (k0, ...] that are
+    <= gid.  The window loop runs ceil((k_last - k0 + 1) / W) times, so
+    cumul must be CLIPPED by the caller (entries no live gid can reach set
+    to I32_MAX) for the loop to stop after the live entries.
+
+    Operates on values (not refs): the body of the Pallas mapping kernels
+    (`repro.kernels`) and, vmapped over tiles, of `edge_slots`."""
+    g0 = gid[0]
+    gmax = gid[-1]
+
+    # --- 1. scalar binary search for k0 = max { l : cumul[l] <= g0 } ------
+    def bcond(s):
+        lo, hi = s
+        return hi - lo > 1
+
+    def bbody(s):
+        lo, hi = s
+        mid = (lo + hi) // 2
+        cm = jax.lax.dynamic_slice(cumul, (mid,), (1,))[0]
+        lo2 = jnp.where(cm <= g0, mid, lo)
+        hi2 = jnp.where(cm <= g0, hi, mid)
+        return lo2, hi2
+
+    k0, _ = jax.lax.while_loop(
+        bcond, bbody, (jnp.int32(0), jnp.int32(n_cumul)))
+
+    # --- 2. windowed broadcast-compare count over (k0, ...] ---------------
+    def wcond(s):
+        start, _ = s
+        probe = jax.lax.dynamic_slice(
+            cumul, (jnp.minimum(start, n_cumul - 1),), (1,))[0]
+        return (start < n_cumul) & (probe <= gmax)
+
+    def wbody(s):
+        start, count = s
+        base = jnp.minimum(start, n_cumul - window)
+        win = jax.lax.dynamic_slice(cumul, (base,), (window,))
+        idx_ok = base + jax.lax.iota(jnp.int32, window) >= start
+        hits = (win[None, :] <= gid[:, None]) & idx_ok[None, :]
+        return start + window, count + jnp.sum(
+            hits, axis=1, dtype=jnp.int32)
+
+    _, count = jax.lax.while_loop(
+        wcond, wbody, (k0 + 1, jnp.zeros_like(gid)))
+    return k0 + count
+
+
+def clip_by_value(cumul, total):
+    """cumul with every entry >= total set to I32_MAX.  No live gid
+    (< total) can reach such an entry, so `map_workload_tile` gives the same
+    k on live lanes and its window loop stops after the live entries.
+    Exact for any non-decreasing cumul: a live prefix then `total` repeated
+    (top-down) or a masked cumsum with zero-width runs (bottom-up)."""
+    return jnp.where(cumul < total, cumul, I32_MAX)
+
+
+def edge_slots(cumul, gids, total, *, tile: int = 512, window: int = 256):
+    """k[t] = max { l : cumul[l] <= gids[t] } on every live lane
+    (gids[t] < total) of a chunk of consecutive edge ids -- what
+    `searchsorted(cumul, gids, side="right") - 1` gives there.  Dead lanes
+    get some in-range slot; callers mask them.
+
+    `map_workload_tile` per tile of consecutive ids: one binary search per
+    tile instead of one per lane.  A per-lane search is log2(len(cumul))
+    dependent gathers for every edge, and on a TPU it took most of a
+    level's time (DESIGN.md sec. 9.4).  cumul is clipped with
+    `clip_by_value`.
+
+    The window loop of a tile runs once per `window` cumul entries its ids
+    span, and under vmap every tile pays the widest tile's count.  A run of
+    zero-width entries inside a tile (zero-degree or visited rows in the
+    bottom-up masked cumsum) widens that span beyond the tile's length."""
+    e = gids.shape[0]
+    n_cumul = cumul.shape[0]
+    tile = pick_tile(e, tile)
+    window = min(window, n_cumul)
+    cc = clip_by_value(cumul, total)
+    k = jax.vmap(lambda g: map_workload_tile(g, cc, window=window,
+                                             n_cumul=n_cumul))(
+        gids.reshape(e // tile, tile))
+    return k.reshape(e)
 
 
 def compact_blocks(vals, cnts, fill=-1, ops=None):
@@ -148,11 +249,11 @@ def reference_expand_chunk(gids, cumul, all_front, front_total, col_off,
     """
     ncl = all_front.shape[0]
     nnz_cap = row_idx.shape[0]
-    k = jnp.searchsorted(cumul, gids, side="right").astype(jnp.int32) - 1
-    k = jnp.clip(k, 0, ncl - 1)
+    total = cumul[front_total]
+    k = jnp.clip(edge_slots(cumul, gids, total), 0, ncl - 1)
     u = jnp.clip(all_front, 0, ncl - 1)[k]
     addr = jnp.clip(col_off[u] + gids - cumul[k], 0, nnz_cap - 1)
-    valid = gids < cumul[front_total]
+    valid = gids < total
     v = jnp.where(valid, row_idx[addr], 0)
     return v, u, k, addr, valid
 
@@ -191,8 +292,7 @@ def reference_bottomup_chunk(gids, cumul, total, row_off, col_idx, words, *,
     """
     nrl = cumul.shape[0] - 1
     nnz_cap = col_idx.shape[0]
-    r = jnp.searchsorted(cumul, gids, side="right").astype(jnp.int32) - 1
-    r = jnp.clip(r, 0, nrl - 1)
+    r = jnp.clip(edge_slots(cumul, gids, total), 0, nrl - 1)
     addr = jnp.clip(row_off[r] + gids - cumul[r], 0, nnz_cap - 1)
     valid = gids < total
     c = jnp.where(valid, col_idx[addr], 0)

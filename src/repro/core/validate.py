@@ -24,8 +24,20 @@ def _edge_set(edges):
     return u, v
 
 
-def validate_bfs(edges, level, pred, root: int) -> None:
-    """Raise AssertionError with a message on any rule violation."""
+def edge_keys(edges, n: int) -> np.ndarray:
+    """Sorted int64 keys u * (n + 1) + v of the edge list: rule 4's lookup
+    table.  Sort once and pass it to every `validate_bfs` of one graph."""
+    u, v = _edge_set(edges)
+    key = u * (n + 1) + v
+    key.sort()
+    return key
+
+
+def validate_bfs(edges, level, pred, root: int, keys=None) -> None:
+    """Raise AssertionError with a message on any rule violation.
+
+    keys: `edge_keys(edges, len(level))`, sorted once per graph; computed
+    here when omitted."""
     level = np.asarray(level)
     pred = np.asarray(pred)
     u, v = _edge_set(edges)
@@ -44,8 +56,7 @@ def validate_bfs(edges, level, pred, root: int) -> None:
 
     # tree edges must exist in the graph (directed edge p -> w or w -> p;
     # the input is symmetrised so checking one direction suffices)
-    key = u.astype(np.int64) * (level.shape[0] + 1) + v
-    key.sort()
+    key = edge_keys(edges, level.shape[0]) if keys is None else keys
     tkey = p.astype(np.int64) * (level.shape[0] + 1) + w
     pos = np.searchsorted(key, tkey)
     pos = np.clip(pos, 0, key.shape[0] - 1)

@@ -1,7 +1,7 @@
 """Distributed-engine layer shared by every BFS driver (DESIGN.md sec. 6).
 
 Layering:
-  compat    -- JAX version shim (shard_map / make_mesh / AxisType)
+  compat    -- the shard_map / make_mesh call sites
   topology  -- mesh + processor-grid geometry (1D = degenerate 1 x P grid)
   exchange  -- expand/fold collectives with pluggable fold wire codecs
   strategy  -- pluggable fold exchange routes (flat / butterfly)
@@ -16,7 +16,6 @@ multi-host worker does) must not drag the engine in eagerly.
 _EXPORTS = {
     "shard_map": "repro.dist.compat",
     "make_mesh": "repro.dist.compat",
-    "axis_types_kwargs": "repro.dist.compat",
     "Topology": "repro.dist.topology",
     "FOLD_CODECS": "repro.dist.exchange",
     "get_fold_codec": "repro.dist.exchange",

@@ -1,58 +1,26 @@
-"""JAX version shim for the distributed APIs (DESIGN.md sec. 6.1).
-
-The repo targets two JAX API generations:
-
-  * >= 0.5:  ``jax.shard_map``, ``jax.sharding.AxisType``,
-    ``jax.make_mesh(..., axis_types=...)``, ``check_vma=``;
-  * 0.4.x (this container ships 0.4.37): ``jax.experimental.shard_map``,
-    no ``AxisType``, ``jax.make_mesh`` without ``axis_types``, ``check_rep=``.
+"""The one home of the mesh and shard_map API calls (DESIGN.md sec. 6.1).
 
 Every module imports ``shard_map`` / ``make_mesh`` from here instead of from
-``jax`` directly (enforced by tests/test_fold_codecs.py); this file is the
-ONLY place allowed to probe the jax API surface.
+``jax`` directly (enforced by tests/test_fold_codecs.py), so a change of the
+JAX API surface touches this file only.
 """
 from __future__ import annotations
 
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # 0.4.x
-    AxisType = None
-
-HAS_AXIS_TYPE = AxisType is not None
-
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _OLD_SHARD_MAP
+from jax.sharding import AxisType, Mesh
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=False):
-    """``jax.shard_map`` on >= 0.5, ``jax.experimental.shard_map`` on 0.4.x.
-
-    ``check_vma`` maps onto 0.4.x's ``check_rep`` -- the same replication
-    checker under its earlier name (True is what makes shard_map transposes
-    insert psums for replicated operands, see repro.models.moe).
-    """
-    if _NEW_SHARD_MAP is not None:
-        return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma)
-    return _OLD_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``dict(axis_types=(AxisType.Auto,) * n)`` where supported, else {}."""
-    if HAS_AXIS_TYPE:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-    return {}
+    """``jax.shard_map`` (``check_vma=True`` is what makes shard_map
+    transposes insert psums for replicated operands, see repro.models.moe)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
+    """A mesh with Auto axis types.
 
     devices: optional explicit device list (e.g. the first 256 of 512
     placeholder devices).  ``jax.make_mesh`` cannot subset the device pool,
@@ -60,7 +28,8 @@ def make_mesh(axis_shapes, axis_names, *, devices=None):
     """
     axis_shapes = tuple(axis_shapes)
     axis_names = tuple(axis_names)
-    kw = axis_types_kwargs(len(axis_names))
+    axis_types = (AxisType.Auto,) * len(axis_names)
     if devices is not None:
-        return Mesh(np.asarray(devices).reshape(axis_shapes), axis_names, **kw)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+        return Mesh(np.asarray(devices).reshape(axis_shapes), axis_names,
+                    axis_types=axis_types)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)

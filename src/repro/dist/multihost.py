@@ -11,18 +11,19 @@ every host.  This module is the whole multi-host surface:
   global_mesh()   a mesh over `jax.devices()` -- ALL processes' devices in
                   process order, so every host constructs the identical
                   mesh deterministically.
-  put_dev()       host (R, C, ...) array -> global array sharded over the
-                  grid axes (each process materialises only its addressable
-                  shards; the host copy must be identical on every process,
-                  which the deterministic planner guarantees).
-  put_replicated()  host scalar/vector -> global fully-replicated array
-                  (search roots, source sets).
+  put_dev()       host (R, C, ...) array -> array sharded over the grid
+                  axes: each device holds only its own block, and each
+                  process materialises only its addressable shards (the
+                  host copy must be identical on every process, which the
+                  deterministic planner guarantees).
+  put_replicated()  host scalar/vector -> fully-replicated array on the
+                  mesh (search roots, source sets).
   fetch()         global array -> host numpy, `process_allgather`-ing the
                   non-addressable shards (identity in single-process runs).
 
-Everything degrades to the single-process identity: `DistGraph` and the
-engine call these helpers unconditionally, and a plain local run never pays
-for them.  The two-process harness `tests/dist/run_multihost.py` drives a
+Placement is the same in one process and in many: `DistGraph` and the
+engine call these helpers unconditionally, so the graph lands block by block
+on the mesh's devices and a query moves no graph data.  The two-process harness `tests/dist/run_multihost.py` drives a
 real multi-host BFS/CC/SSSP through this module and asserts bit-identity
 with the single-process reference.
 """
@@ -31,7 +32,6 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import compat
@@ -59,10 +59,6 @@ def initialize(coordinator_address: str, num_processes: int,
                                process_id=process_id, **kw)
 
 
-def is_multiprocess() -> bool:
-    return jax.process_count() > 1
-
-
 def global_mesh(axis_shapes, axis_names):
     """The deterministic global mesh: `jax.devices()` (all processes, in
     process order) reshaped to the grid axes.  Every process builds the
@@ -72,14 +68,12 @@ def global_mesh(axis_shapes, axis_names):
 
 
 def put_dev(x, mesh, spec: P):
-    """Host array -> global array sharded by `spec` over `mesh`.
+    """Host array -> array sharded by `spec` over `mesh`.
 
-    Single-process: plain `jnp.asarray` (uncommitted, like before).  Multi-
-    process: every process holds the identical host copy and materialises
-    only its addressable shards, so no cross-host data movement happens.
-    """
-    if not is_multiprocess():
-        return jnp.asarray(x)
+    Every device receives only its own block, in a single process too, so
+    the sharded program never moves graph data between devices.  In a
+    process group every process holds the identical host copy and
+    materialises only its addressable shards."""
     x = np.asarray(x)
     sharding = NamedSharding(mesh, spec)
     return jax.make_array_from_callback(x.shape, sharding,
@@ -87,18 +81,15 @@ def put_dev(x, mesh, spec: P):
 
 
 def put_replicated(x, mesh):
-    """Host array -> globally replicated array (search args)."""
-    if not is_multiprocess():
-        return jnp.asarray(x)
+    """Host array -> array replicated over every device of `mesh` (search
+    args)."""
     return put_dev(x, mesh, P())
 
 
 def arg_aval(shape, dtype, mesh):
-    """ShapeDtypeStruct for AOT-lowering a replicated search argument: in a
-    process group the aval must carry its sharding or the lowered
-    executable cannot bind the global argument arrays."""
-    if not is_multiprocess():
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """ShapeDtypeStruct for AOT-lowering a replicated search argument: it
+    carries its sharding, so the executable binds the arrays
+    `put_replicated` makes."""
     return jax.ShapeDtypeStruct(shape, dtype,
                                 sharding=NamedSharding(mesh, P()))
 

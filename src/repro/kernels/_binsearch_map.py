@@ -18,9 +18,11 @@ cumul must be CLIPPED by the caller: entries at index > front_total set to
 I32_MAX (`clip_cumul` below) so the window loop terminates after the live
 frontier prefix.
 
-`map_workload_tile` is the kernel body on VALUES: it is the workload-mapping
-STAGE of the fused local-expand pipeline (repro.kernels.expand) and the whole
-kernel of the standalone `binsearch_map` op.
+`map_workload_tile` (repro.core.frontier, where the jnp reference path runs
+it vmapped over tiles) is the kernel body on VALUES: it is the
+workload-mapping STAGE of the fused local-expand pipeline
+(repro.kernels.expand) and the whole kernel of the standalone
+`binsearch_map` op.
 """
 from __future__ import annotations
 
@@ -29,6 +31,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.core.frontier import map_workload_tile
 
 I32_MAX = jnp.int32(jnp.iinfo(jnp.int32).max)
 
@@ -40,57 +44,9 @@ def clip_cumul(cumul, front_total):
     return jnp.where(idx <= front_total, cumul, I32_MAX)
 
 
-def map_workload_tile(gid, cumul, *, window: int, n_cumul: int):
-    """k[t] = max { l : cumul[l] <= gid[t] } for ONE tile of consecutive edge
-    ids, as dense VPU work (the thread->edge mapping stage).
-
-    Operates on values (not refs): callable both from a Pallas kernel body
-    (the refs read once into values) and from the fused expand kernel."""
-    g0 = gid[0]
-    gmax = gid[-1]
-
-    # --- 1. scalar binary search for k0 = max { l : cumul[l] <= g0 } ------
-    def bcond(s):
-        lo, hi = s
-        return hi - lo > 1
-
-    def bbody(s):
-        lo, hi = s
-        mid = (lo + hi) // 2
-        cm = jax.lax.dynamic_slice(cumul, (mid,), (1,))[0]
-        lo2 = jnp.where(cm <= g0, mid, lo)
-        hi2 = jnp.where(cm <= g0, hi, mid)
-        return lo2, hi2
-
-    k0, _ = jax.lax.while_loop(
-        bcond, bbody, (jnp.int32(0), jnp.int32(n_cumul)))
-
-    # --- 2. windowed broadcast-compare count over (k0, ...] ---------------
-    def wcond(s):
-        start, _ = s
-        probe = jax.lax.dynamic_slice(
-            cumul, (jnp.minimum(start, n_cumul - 1),), (1,))[0]
-        return (start < n_cumul) & (probe <= gmax)
-
-    def wbody(s):
-        start, count = s
-        base = jnp.minimum(start, n_cumul - window)
-        win = jax.lax.dynamic_slice(cumul, (base,), (window,))
-        idx_ok = base + jax.lax.iota(jnp.int32, window) >= start
-        hits = (win[None, :] <= gid[:, None]) & idx_ok[None, :]
-        return start + window, count + jnp.sum(
-            hits, axis=1, dtype=jnp.int32)
-
-    _, count = jax.lax.while_loop(
-        wcond, wbody, (k0 + 1, jnp.zeros_like(gid)))
-    return k0 + count
-
-
 def _kernel(gids_ref, cumul_ref, k_ref, *, window: int, n_cumul: int):
     # the cumul block sits whole in VMEM; read it ONCE into a value so the
-    # while loops stay ref-free (JAX 0.4.x interpret mode cannot discharge
-    # ref reads inside a while cond; on TPU the dynamic_slices lower to the
-    # same VMEM accesses pl.load would)
+    # while loops stay ref-free
     k_ref[...] = map_workload_tile(gids_ref[...], cumul_ref[...],
                                    window=window, n_cumul=n_cumul)
 
@@ -98,7 +54,7 @@ def _kernel(gids_ref, cumul_ref, k_ref, *, window: int, n_cumul: int):
 @functools.partial(jax.jit,
                    static_argnames=("tile", "window", "interpret"))
 def binsearch_map(cumul, gids, *, tile: int = 512, window: int = 256,
-                  interpret: bool = True):
+                  interpret: bool):
     """k[t] = max { l : cumul[l] <= gids[t] }; gids must be sorted ascending
     (they are consecutive edge ids in the BFS).  cumul int32 non-decreasing.
     """

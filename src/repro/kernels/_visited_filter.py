@@ -44,7 +44,7 @@ def _kernel(v_ref, valid_ref, words_ref, won_ref):
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def visited_filter(v, valid, bitmap_words, *, tile: int = 256,
-                   interpret: bool = True):
+                   interpret: bool):
     """won (bool, same shape as v): first unvisited occurrence per vertex.
 
     NOTE: dedup is per-TILE (as the paper's dedup is per-race-window); the

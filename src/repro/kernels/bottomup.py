@@ -22,14 +22,17 @@ Three selectable implementations, bit-identical by construction ("pallas",
 "pallas-interpret", "reference" -- the pure-jnp
 `repro.core.frontier.reference_bottomup_chunk`); `resolve_bottomup_path`
 implements the `BFSConfig(bottomup=...)` rules with the REPRO_BOTTOMUP
-environment override, mirroring the expand/fold knobs.
+environment override, mirroring the expand/fold knobs.  The TPU compiler
+refuses this kernel for the same `dynamic_slice` in `map_workload_tile` as
+the expand kernel (DESIGN.md sec. 11), so "auto" is "reference" there.
 
-The kernel's cumul is clipped BY VALUE (entries >= total -> I32_MAX), not by
-index as the top-down kernel's `clip_cumul`: the masked cumsum has no live
-"prefix" -- visited rows pepper zero-width runs through the whole array --
-but every entry that reaches `total` can never satisfy cumul[l] <= gid for a
-valid gid < total, so the I32_MAX tail terminates `map_workload_tile`'s
-window loop without disturbing the row mapping on live lanes.
+The kernel's cumul is clipped BY VALUE (`repro.core.frontier.clip_by_value`:
+entries >= total -> I32_MAX), not by index as the top-down kernel's
+`clip_cumul`: the masked cumsum has no live "prefix" -- visited rows pepper
+zero-width runs through the whole array -- but every entry that reaches
+`total` can never satisfy cumul[l] <= gid for a valid gid < total, so the
+I32_MAX tail terminates `map_workload_tile`'s window loop without
+disturbing the row mapping on live lanes.
 
 This module needs jax.experimental.pallas; path SELECTION lives in
 `repro.kernels.select` so reference-path engines import clean without it.
@@ -44,16 +47,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.frontier import I32_MAX
-from repro.kernels._binsearch_map import map_workload_tile
-from repro.kernels.expand import _pick_tile
+from repro.core.frontier import (I32_MAX, clip_by_value, map_workload_tile,
+                                 pick_tile)
 from repro.kernels.select import (BOTTOMUP_ENV, BOTTOMUP_PATHS,  # noqa: F401
                                   resolve_bottomup_path)
-
-
-def _clip_by_value(cumul, total):
-    """Masked-cumsum analog of `clip_cumul` (see module docstring)."""
-    return jnp.where(cumul < total, cumul, I32_MAX)
 
 
 def _test_words(words, c, *, block: int):
@@ -95,7 +92,7 @@ def _bottomup_kernel(gids_ref, cumul_ref, total_ref, row_off_ref,
                    static_argnames=("block", "tile", "window", "interpret"))
 def bottomup_chunk(gids, cumul, total, row_off, col_idx, words, *,
                    block: int, tile: int = 512, window: int = 256,
-                   interpret: bool = True):
+                   interpret: bool):
     """The fused parent search over one chunk of consecutive edge ids.
 
     cumul: (nrl + 1,) exclusive cumsum of MASKED degrees (visited rows 0);
@@ -107,10 +104,10 @@ def bottomup_chunk(gids, cumul, total, row_off, col_idx, words, *,
     c into a per-row best-parent array.
     """
     e = gids.shape[0]
-    tile = _pick_tile(e, tile)
+    tile = pick_tile(e, tile)
     nrl = row_off.shape[0] - 1
     nnz_cap = col_idx.shape[0]
-    cc = _clip_by_value(cumul, total)
+    cc = clip_by_value(cumul, total)
     n_cumul = cc.shape[0]
     if n_cumul < window:   # tiny partition: pad so the window load is legal
         cc = jnp.concatenate(
@@ -161,7 +158,7 @@ def _value_bottomup_kernel(gids_ref, cumul_ref, total_ref, row_off_ref,
                    static_argnames=("block", "tile", "window", "interpret"))
 def bottomup_chunk_values(gids, cumul, total, row_off, col_idx, words,
                           dense_pay, *, block: int, tile: int = 512,
-                          window: int = 256, interpret: bool = True):
+                          window: int = 256, interpret: bool):
     """The fused VALUE-PULLING parent search over one chunk (CC / SSSP /
     multi-BFS in bottom-up levels).
 
@@ -172,11 +169,11 @@ def bottomup_chunk_values(gids, cumul, total, row_off, col_idx, words,
     applies its relax monoid and scatter-min combine.
     """
     e = gids.shape[0]
-    tile = _pick_tile(e, tile)
+    tile = pick_tile(e, tile)
     nrl = row_off.shape[0] - 1
     nnz_cap = col_idx.shape[0]
     ncl = dense_pay.shape[0]
-    cc = _clip_by_value(cumul, total)
+    cc = clip_by_value(cumul, total)
     n_cumul = cc.shape[0]
     if n_cumul < window:
         cc = jnp.concatenate(
@@ -210,7 +207,7 @@ def bottomup_chunk_values(gids, cumul, total, row_off, col_idx, words,
 # Engine hooks: the chunk closures the bottom-up steps thread into their scans
 # ----------------------------------------------------------------------------
 
-def make_bottomup_fn(*, path: str = "pallas-interpret", tile: int = 512,
+def make_bottomup_fn(*, path: str, tile: int = 512,
                      window: int = 256):
     """The kernel-backed chunk parent search for the bottom-up BFS step:
 
@@ -227,8 +224,8 @@ def make_bottomup_fn(*, path: str = "pallas-interpret", tile: int = 512,
     return bottomup_fn
 
 
-def make_value_bottomup_fn(*, path: str = "pallas-interpret",
-                           tile: int = 512, window: int = 256):
+def make_value_bottomup_fn(*, path: str, tile: int = 512,
+                           window: int = 256):
     """The kernel-backed value-pulling chunk parent search (value programs):
 
         (gids, cumul, total, row_off, col_idx, words, dense_pay, block=S)

@@ -5,7 +5,7 @@ neighbor gather and the atomicOr visited bitmap -- as ONE fused op over a
 chunk of consecutive edge ids:
 
   stage 1  workload map    k[t] = max { l : cumul[l] <= gid[t] }
-                           (repro.kernels._binsearch_map.map_workload_tile)
+                           (repro.core.frontier.map_workload_tile)
   stage 2  neighbor gather u = front[k]; v = row_idx[col_off[u] + gid -
                            cumul[k]] (the CSC column-scan addressing that the
                            old standalone gather_segments kernel DMA'd)
@@ -19,22 +19,23 @@ chunk of consecutive edge ids:
 
 Three selectable implementations, bit-identical by construction:
 
-  "pallas"            the fused Pallas kernel, compiled (GPU/TPU);
-  "pallas-interpret"  the same kernel body in Pallas interpret mode -- this
-                      is what CI drives on CPU runners via
+  "pallas"            the fused Pallas kernel, compiled;
+  "pallas-interpret"  the same kernel body in Pallas interpret mode (CPU
+                      only) -- this is what CI drives via
                       REPRO_EXPAND=pallas-interpret;
   "reference"         the pure-jnp formulas (exactly the inline path of
                       `repro.core.frontier.expand_frontier` / `scan_relax`).
 
-`resolve_expand_path` implements the `BFSConfig(expand=...)` selection rules:
-"auto" picks "pallas" on GPU/TPU and "reference" on CPU, and honors the
-REPRO_EXPAND environment variable so CI can force the interpret-mode kernel
-path without touching configs.
+`resolve_expand_path` implements the `BFSConfig(expand=...)` selection rules
+(`repro.kernels.select`): "auto" picks "reference" on CPU and TPU and honors
+the REPRO_EXPAND environment variable so CI can force the interpret-mode
+kernel path without touching configs.
 
-Production note: the fused kernel holds `row_idx` whole in VMEM, which is
-right for interpret mode and for local partitions up to a few MiB; the tuned
-TPU variant would keep row_idx in ANY/HBM and double-buffer the stage-2
-gather with pltpu.make_async_copy, with identical semantics.
+The TPU compiler refuses this kernel (DESIGN.md sec. 9): the scalar search
+in `map_workload_tile` uses `dynamic_slice`, which has no Pallas TPU
+lowering, and `row_idx` is held whole in VMEM and gathered with a vector
+`jnp.take`.  A chip version would keep row_idx in HBM and DMA windows of
+it, with identical semantics.
 
 This module needs jax.experimental.pallas; path SELECTION does not and lives
 in `repro.kernels.select` so reference-path engines import clean without it.
@@ -51,25 +52,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.frontier import (I32_MAX, exclusive_cumsum, pack_bitmap,
+from repro.core.frontier import (I32_MAX, exclusive_cumsum,
+                                 map_workload_tile, pack_bitmap, pick_tile,
                                  reference_expand_chunk, set_bits,
                                  winner_dedup)
-from repro.kernels._binsearch_map import clip_cumul, map_workload_tile
+from repro.kernels._binsearch_map import clip_cumul
 from repro.kernels.select import (EXPAND_ENV, EXPAND_PATHS,  # noqa: F401
                                   resolve_expand_path)
 from repro.kernels._visited_filter import filter_tile
-
-
-def _pick_tile(e: int, tile: int) -> int:
-    """Largest DIVISOR of the chunk length <= tile (the kernel grid needs
-    tile | chunk length).  Never rounds UP to e: the stage-3 dedup is a
-    dense (tile, tile) compare, so one e-wide tile on a big odd chunk
-    would be quadratic in the chunk.  Both arguments are static (e is the
-    engine's edge_chunk), so this runs at trace time."""
-    t = min(tile, e)
-    while e % t:
-        t -= 1
-    return t
 
 
 # ----------------------------------------------------------------------------
@@ -102,7 +92,7 @@ def _expand_kernel(gids_ref, cumul_ref, total_ref, front_ref, col_off_ref,
 @functools.partial(jax.jit, static_argnames=("tile", "window", "interpret"))
 def expand_chunk(gids, cumul, all_front, front_total, col_off, row_idx,
                  visited, words=None, *, tile: int = 512, window: int = 256,
-                 interpret: bool = True):
+                 interpret: bool):
     """The fused set-expand over one chunk of consecutive edge ids.
 
     Drop-in for `repro.core.frontier.expand_frontier(expand_fn=...)`:
@@ -119,7 +109,7 @@ def expand_chunk(gids, cumul, all_front, front_total, col_off, row_idx,
     """
     ncl = all_front.shape[0]
     e = gids.shape[0]
-    tile = _pick_tile(e, tile)
+    tile = pick_tile(e, tile)
     nnz_cap = row_idx.shape[0]
     cc = clip_cumul(cumul, front_total)
     total = cumul[front_total][None]
@@ -175,7 +165,7 @@ def _value_expand_kernel(gids_ref, cumul_ref, total_ref, front_ref, pay_ref,
 @functools.partial(jax.jit, static_argnames=("tile", "window", "interpret"))
 def expand_chunk_values(gids, cumul, all_front, all_payload, front_total,
                         col_off, row_idx, *, tile: int = 512,
-                        window: int = 256, interpret: bool = True):
+                        window: int = 256, interpret: bool):
     """The fused VALUE-CARRYING expand over one chunk (CC / SSSP / multi-BFS).
 
     Returns (v, payload, addr, valid): candidate local rows, the frontier
@@ -187,7 +177,7 @@ def expand_chunk_values(gids, cumul, all_front, all_payload, front_total,
     """
     ncl = all_front.shape[0]
     e = gids.shape[0]
-    tile = _pick_tile(e, tile)
+    tile = pick_tile(e, tile)
     nnz_cap = row_idx.shape[0]
     cc = clip_cumul(cumul, front_total)
     total = cumul[front_total][None]
@@ -222,7 +212,7 @@ def expand_chunk_values(gids, cumul, all_front, all_payload, front_total,
 # Engine hooks: the chunk closures FrontierEngine threads into the scans
 # ----------------------------------------------------------------------------
 
-def make_expand_fn(*, path: str = "pallas-interpret", tile: int = 512,
+def make_expand_fn(*, path: str, tile: int = 512,
                    window: int = 256):
     """The kernel-backed chunk expansion for
     `repro.core.frontier.expand_frontier(expand_fn=...)`:
@@ -246,7 +236,7 @@ def make_expand_fn(*, path: str = "pallas-interpret", tile: int = 512,
     return expand_fn
 
 
-def make_value_expand_fn(*, path: str = "pallas-interpret", tile: int = 512,
+def make_value_expand_fn(*, path: str, tile: int = 512,
                          window: int = 256):
     """The kernel-backed value-carrying chunk expansion for
     `repro.algos.program.scan_relax(expand_fn=...)`:

@@ -31,6 +31,11 @@ engines thread through `repro.dist.exchange` and `repro.algos.program`
 with the REPRO_FOLD override -- the exact mirror of the expand-path
 plumbing, DESIGN.md sec. 9.2).
 
+The TPU compiler refuses all five kernels (DESIGN.md sec. 10): each one
+blocks its (N, S) operands as (1, S) rows, and a block's second-to-last
+dimension must be divisible by 8 or equal the array's.  So "auto" resolves
+to "reference" on TPU.
+
 This module needs jax.experimental.pallas; path SELECTION does not and
 lives in `repro.kernels.select` so reference-path engines import clean
 without it.  Import this module only at top level (never lazily inside a
@@ -109,7 +114,7 @@ def _compact_rows(mask, arrays, fills, *, interpret: bool):
     return tuple(packed), inc[:, -1]
 
 
-def compact_rows(mask, arrays, fills, *, interpret: bool = True):
+def compact_rows(mask, arrays, fills, *, interpret: bool):
     """Front-pack each row's valid entries, preserving order (the argsort
     replacement shared by `pack_blocks`, `owned_to_front`,
     `expand_exchange_values`, `compact_blocks` and the bitmap decode).
@@ -141,7 +146,7 @@ def _pack_kernel(mask_ref, words_ref, *, W: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pack_bits(mask, *, interpret: bool = True):
+def pack_bits(mask, *, interpret: bool):
     """(N, S) bool -> (N, ceil(S/32)) uint32 little-endian bit packing
     (the kernel twin of `repro.core.frontier.pack_bitmap`)."""
     N, S = mask.shape
@@ -167,7 +172,7 @@ def _unpack_kernel(words_ref, bits_ref, *, W: int):
 
 
 @functools.partial(jax.jit, static_argnames=("S", "interpret"))
-def unpack_bits(words, S: int, *, interpret: bool = True):
+def unpack_bits(words, S: int, *, interpret: bool):
     """(N, W) uint32 -> (N, S) bool (the kernel twin of `unpack_bitmap`)."""
     N, W = words.shape
     bits = pl.pallas_call(
@@ -195,7 +200,7 @@ def _gaps_kernel(ts_ref, valid_ref, gaps_ref, *, S: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def delta_gaps(ts, valid, *, interpret: bool = True):
+def delta_gaps(ts, valid, *, interpret: bool):
     """Sorted per-row offsets -> uint16 first-order gaps (slot 0 absolute),
     the encode half of the delta codec on PRE-SORTED rows (the sort stays
     XLA; canonical value-fold buckets arrive already sorted)."""
@@ -215,7 +220,7 @@ def _positions_kernel(gaps_ref, pos_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def delta_positions(gaps, *, interpret: bool = True):
+def delta_positions(gaps, *, interpret: bool):
     """(N, S) uint16 gaps -> (N, S) int32 absolute offsets (cumsum), the
     decode half of the delta codec."""
     N, S = gaps.shape
@@ -239,7 +244,7 @@ class PallasFoldOps:
     call sites stay ignorant of the path.  `None` in its place means the
     reference jnp formulas (exactly the pre-sec.-10 code)."""
 
-    def __init__(self, path: str = "pallas-interpret"):
+    def __init__(self, path: str):
         if path not in ("pallas", "pallas-interpret"):
             raise ValueError(f"fold ops need a pallas path, got {path!r}")
         self.name = path
@@ -264,6 +269,6 @@ class PallasFoldOps:
         return delta_positions(gaps, interpret=self.interpret)
 
 
-def make_fold_ops(*, path: str = "pallas-interpret") -> PallasFoldOps:
+def make_fold_ops(*, path: str) -> PallasFoldOps:
     """The kernel bundle for a resolved non-reference fold path."""
     return PallasFoldOps(path)

@@ -246,12 +246,8 @@ class Roofline:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """compiled.cost_analysis() as a flat dict: JAX 0.4.x returns a
-    one-element list of dicts, >= 0.5 the dict itself."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """compiled.cost_analysis() as a dict ({} where XLA gives none)."""
+    return compiled.cost_analysis() or {}
 
 
 def analyze(compiled, *, model_flops: float | None = None,

@@ -301,10 +301,18 @@ def test_resolve_bottomup_path_rules(monkeypatch):
     assert resolve_bottomup_path("reference") == "reference"
     assert resolve_bottomup_path("pallas-interpret") == "pallas-interpret"
     assert resolve_bottomup_path("auto", platform="cpu") == "reference"
-    assert resolve_bottomup_path("auto", platform="tpu") == "pallas"
+    # the TPU compiler refuses the kernels: auto takes the jnp scan there,
+    # an explicit "pallas" is passed through to the compiler, and the
+    # interpreter is refused off CPU
+    assert resolve_bottomup_path("auto", platform="tpu") == "reference"
+    assert resolve_bottomup_path("pallas", platform="tpu") == "pallas"
+    with pytest.raises(ValueError, match="CPU only"):
+        resolve_bottomup_path("pallas-interpret", platform="tpu")
     assert resolve_bottomup_path(None, platform="gpu") == "pallas"
     monkeypatch.setenv(BOTTOMUP_ENV, "pallas-interpret")
-    assert resolve_bottomup_path("auto", platform="tpu") == "pallas-interpret"
+    assert resolve_bottomup_path("auto", platform="cpu") == "pallas-interpret"
+    with pytest.raises(ValueError, match=BOTTOMUP_ENV):
+        resolve_bottomup_path("auto", platform="tpu")
     # explicit spellings are NOT overridden by the environment
     assert resolve_bottomup_path("reference") == "reference"
     monkeypatch.setenv(BOTTOMUP_ENV, "nonsense")

@@ -46,3 +46,7 @@ def test_dist_suite_1d_direction_spmm():
 @pytest.mark.slow
 def test_session_api_grid_2x2():
     _run("run_session.py", 2, 2)
+
+
+def test_single_process_placement_is_sharded():
+    _run("run_placement.py")

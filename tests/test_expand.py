@@ -152,7 +152,7 @@ def test_value_chunk_matches_inline(rng):
     v, pay, addr, valid = expand_chunk_values(
         gids, jnp.asarray(cumul), jnp.asarray(front), jnp.asarray(payload),
         jnp.int32(cnt), jnp.asarray(col_off), jnp.asarray(row_idx),
-        tile=32, window=16)
+        tile=32, window=16, interpret=True)
     k = np.clip(np.searchsorted(cumul, np.arange(e), side="right") - 1,
                 0, n - 1)
     a_ref = np.clip(col_off[u_safe[k]] + np.arange(e) - cumul[k],
@@ -218,10 +218,18 @@ def test_resolve_expand_path_rules(monkeypatch):
     assert resolve_expand_path("reference") == "reference"
     assert resolve_expand_path("pallas-interpret") == "pallas-interpret"
     assert resolve_expand_path("auto", platform="cpu") == "reference"
-    assert resolve_expand_path("auto", platform="tpu") == "pallas"
+    # the TPU compiler refuses the kernels: auto takes the jnp scan there,
+    # an explicit "pallas" is passed through to the compiler, and the
+    # interpreter is refused off CPU
+    assert resolve_expand_path("auto", platform="tpu") == "reference"
+    assert resolve_expand_path("pallas", platform="tpu") == "pallas"
+    with pytest.raises(ValueError, match="CPU only"):
+        resolve_expand_path("pallas-interpret", platform="tpu")
     assert resolve_expand_path(None, platform="gpu") == "pallas"
     monkeypatch.setenv(EXPAND_ENV, "pallas-interpret")
-    assert resolve_expand_path("auto", platform="tpu") == "pallas-interpret"
+    assert resolve_expand_path("auto", platform="cpu") == "pallas-interpret"
+    with pytest.raises(ValueError, match=EXPAND_ENV):
+        resolve_expand_path("auto", platform="tpu")
     # explicit spellings are NOT overridden by the environment
     assert resolve_expand_path("reference") == "reference"
     monkeypatch.setenv(EXPAND_ENV, "nonsense")
@@ -256,14 +264,38 @@ def test_pick_tile_always_divides_chunk():
     """The kernel grid needs tile | chunk; the fallback must shrink to a
     divisor, never widen to one e-wide tile (the stage-3 dedup is a dense
     (tile, tile) compare -- e-wide would be quadratic in the chunk)."""
-    from repro.kernels.expand import _pick_tile
+    from repro.core.frontier import pick_tile
 
     for e, tile in [(8192, 512), (100_000, 512), (64, 512), (97, 64),
                     (513, 512)]:
-        t = _pick_tile(e, tile)
+        t = pick_tile(e, tile)
         assert e % t == 0 and t <= max(tile, 1) and t >= 1
-    assert _pick_tile(8192, 512) == 512
-    assert _pick_tile(100_000, 512) == 500
+    assert pick_tile(8192, 512) == 512
+    assert pick_tile(100_000, 512) == 500
+
+
+@pytest.mark.parametrize("e", [100, 512, 8192])
+def test_edge_slots_match_searchsorted(e, rng):
+    """The reference path's tiled slot search gives exactly the per-lane
+    `searchsorted` slot on every live lane, through zero-degree runs (2D
+    blocks, visited rows) and a dead tail (the last chunk of a level)."""
+    from repro.core.frontier import edge_slots, exclusive_cumsum
+
+    slots = jax.jit(edge_slots)
+    for _ in range(20):
+        n = int(rng.integers(1, 3000))
+        deg = rng.integers(0, 6, n) * (rng.random(n) < rng.random())
+        deg[int(rng.integers(0, n + 1)):] = 0
+        cumul = np.asarray(exclusive_cumsum(jnp.asarray(deg, jnp.int32)))
+        total = int(cumul[-1])
+        gids = int(rng.integers(0, max(total, 1))) + np.arange(
+            e, dtype=np.int32)
+        k = np.asarray(slots(jnp.asarray(cumul), jnp.asarray(gids),
+                             jnp.int32(total)))
+        live = gids < total
+        want = np.searchsorted(cumul, gids, side="right") - 1
+        np.testing.assert_array_equal(k[live], want[live])
+        assert ((k >= 0) & (k < cumul.shape[0])).all()
 
 
 def test_algo_engines_honor_custom_expand_fn(graphs):

@@ -407,17 +407,25 @@ def _butterfly_graph():
     """A 1x4 grid on a DUPLICATE-device mesh: the same single CPU device in
     every slot traces shard_map collectives fine (the program is only ever
     `make_jaxpr`-traced here, never executed), which lets the C=4 butterfly
-    lower without --xla_force_host_platform_device_count."""
+    lower without --xla_force_host_platform_device_count.  No array can be
+    placed on such a mesh, so the partition stays host-side: tracing needs
+    only its shapes."""
+    from repro.core.partition import partition_edge_vals
     from repro.dist.compat import make_mesh as mk
+    from repro.dist.topology import Topology
 
     dev = jax.devices()[0]
     fake = mk((1, 4), ("r", "c"), devices=[dev] * 4)
     edges = np.asarray(rmat_edges(jax.random.key(5), 8, 8))
     w = np.random.default_rng(0).integers(1, 256, size=edges.shape[1]) \
         .astype(np.uint8)
-    return DistGraph.from_edges(
-        edges, BFSConfig(grid=(1, 4), edge_chunk=256, expand="reference",
-                         fold="reference"), n=256, weights=w, mesh=fake)
+    grid = Grid2D.for_vertices(256, 1, 4)
+    lg = partition_2d(edges, grid)
+    return DistGraph(
+        Topology.for_grid(grid, fake), lg, edges=edges, n=256,
+        weights=partition_edge_vals(edges, w, grid),
+        config=BFSConfig(grid=(1, 4), edge_chunk=256, expand="reference",
+                         fold="reference"))
 
 
 @pytest.mark.parametrize("codec", ["list", "bitmap", "delta"])
@@ -467,10 +475,18 @@ def test_resolve_fold_path_rules(monkeypatch):
     assert resolve_fold_path("reference") == "reference"
     assert resolve_fold_path("pallas-interpret") == "pallas-interpret"
     assert resolve_fold_path("auto", platform="cpu") == "reference"
-    assert resolve_fold_path("auto", platform="tpu") == "pallas"
+    # the TPU compiler refuses the kernels: auto takes the jnp scan there,
+    # an explicit "pallas" is passed through to the compiler, and the
+    # interpreter is refused off CPU
+    assert resolve_fold_path("auto", platform="tpu") == "reference"
+    assert resolve_fold_path("pallas", platform="tpu") == "pallas"
+    with pytest.raises(ValueError, match="CPU only"):
+        resolve_fold_path("pallas-interpret", platform="tpu")
     assert resolve_fold_path(None, platform="gpu") == "pallas"
     monkeypatch.setenv(FOLD_ENV, "pallas-interpret")
-    assert resolve_fold_path("auto", platform="tpu") == "pallas-interpret"
+    assert resolve_fold_path("auto", platform="cpu") == "pallas-interpret"
+    with pytest.raises(ValueError, match=FOLD_ENV):
+        resolve_fold_path("auto", platform="tpu")
     # explicit spellings are NOT overridden by the environment
     assert resolve_fold_path("reference") == "reference"
     monkeypatch.setenv(FOLD_ENV, "nonsense")

@@ -27,7 +27,8 @@ def test_binsearch_map_sweep(tile, window, n_seg, rng):
     e = max(tile, ((total + tile - 1) // tile) * tile)
     gids = jnp.arange(e, dtype=jnp.int32)
     cc = clip_cumul(jnp.asarray(cumul), jnp.int32(n_seg))
-    k = np.asarray(binsearch_map(cc, gids, tile=tile, window=window))
+    k = np.asarray(binsearch_map(cc, gids, tile=tile, window=window,
+                                 interpret=True))
     k_ref = np.asarray(R.binsearch_map_ref(jnp.asarray(cumul), gids))
     ok = np.asarray(gids) < total
     np.testing.assert_array_equal(k[ok], k_ref[ok])
@@ -45,7 +46,8 @@ def test_binsearch_map_property(data):
         return
     gids = jnp.arange(128, dtype=jnp.int32)
     cc = clip_cumul(jnp.asarray(cumul), jnp.int32(len(degs)))
-    k = np.asarray(binsearch_map(cc, gids, tile=64, window=16))
+    k = np.asarray(binsearch_map(cc, gids, tile=64, window=16,
+                                 interpret=True))
     valid = np.arange(128) < total
     k_ref = np.asarray(R.binsearch_map_ref(jnp.asarray(cumul), gids))
     np.testing.assert_array_equal(k[valid], k_ref[valid])
@@ -73,7 +75,8 @@ def test_fused_gather_stage_sweep(tile, window, n_seg, rng):
     v, won, u = expand_chunk(
         gids, jnp.asarray(cumul), jnp.asarray(front), jnp.int32(ncl),
         jnp.asarray(col_off), jnp.asarray(row_idx),
-        jnp.zeros((10_000,), bool), tile=tile, window=window)
+        jnp.zeros((10_000,), bool), tile=tile, window=window,
+        interpret=True)
     concat = np.concatenate(
         [row_idx[col_off[c]:col_off[c + 1]] for c in front] or
         [np.zeros(0, np.int32)])
@@ -90,7 +93,8 @@ def test_visited_filter_sweep(tile, n_rows, rng):
     words = rng.integers(0, 2**32, size=(n_rows + 31) // 32,
                          dtype=np.uint64).astype(np.uint32)
     won = np.asarray(visited_filter(jnp.asarray(v), jnp.asarray(valid),
-                                    jnp.asarray(words), tile=tile))
+                                    jnp.asarray(words), tile=tile,
+                                    interpret=True))
     for t in range(4):
         s = slice(t * tile, (t + 1) * tile)
         ref = np.asarray(R.visited_filter_ref(
@@ -104,7 +108,8 @@ def test_visited_filter_semantics():
     words = jnp.asarray(np.array([0b100], np.uint32))  # vertex 2 visited
     v = jnp.asarray([2, 5, 5, 7], jnp.int32)
     valid = jnp.ones(4, bool)
-    won = np.asarray(visited_filter(v, valid, words, tile=4))
+    won = np.asarray(visited_filter(v, valid, words, tile=4,
+                                    interpret=True))
     assert won.tolist() == [False, True, False, True]
 
 
