@@ -78,8 +78,9 @@ def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
 
     with jax.named_scope("repro/expand"):
         # expand exchange: gather frontiers within the processor-column
-        all_front, front_total = X.expand_exchange(
-            st.front, st.front_cnt, topo=topo, ops=engine.fold_ops)
+        with jax.named_scope("exchange"):
+            all_front, front_total = X.expand_exchange(
+                st.front, st.front_cnt, topo=topo, ops=engine.fold_ops)
 
         # frontier expansion (local CSC column scan)
         ex = F.expand_frontier(
@@ -88,15 +89,17 @@ def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
             edge_chunk=engine.edge_chunk, expand_fn=engine.expand_fn,
             dedup=engine.dedup)
 
-    # own-column vertices go straight to the frontier (lines 15-16)
-    own_rows = jnp.take(ex.dst, j, axis=0)      # (S,) local rows, block j
-    own_cnt = jnp.take(ex.dst_cnt, j)
-    own_cols = (i * S + (own_rows - j * S)).astype(jnp.int32)  # ROW2COL
-    own_valid = jnp.arange(S, dtype=jnp.int32) < own_cnt
-    dst = ex.dst.at[j].set(-1)
-    dst_cnt = ex.dst_cnt.at[j].set(0)
+    with jax.named_scope("repro/update"):
+        # own-column vertices go straight to the frontier (lines 15-16)
+        own_rows = jnp.take(ex.dst, j, axis=0)      # (S,) local rows, block j
+        own_cnt = jnp.take(ex.dst_cnt, j)
+        own_cols = (i * S + (own_rows - j * S)).astype(jnp.int32)  # ROW2COL
+        own_valid = jnp.arange(S, dtype=jnp.int32) < own_cnt
 
     with jax.named_scope("repro/fold"):
+        # the own column never travels; the rest route to their owners
+        dst = ex.dst.at[j].set(-1)
+        dst_cnt = ex.dst_cnt.at[j].set(0)
         # fold exchange: route discoveries to their owners (same grid row)
         int_verts, int_cnt = engine.codec.fold(dst, dst_cnt, topo=topo, j=j)
 
@@ -111,16 +114,19 @@ def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
         up_valid = jnp.arange(S, dtype=jnp.int32) < up.new_cnt
         nf, nc = F.append_padded(nf, nc, up.new_front, up_valid)
         nf, nc = canonical_front(nf, nc)
-
-    st2 = BFSState(level=up.level, pred=up.pred, visited=up.visited,
-                   front=nf, front_cnt=nc, lvl=st.lvl + 1)
-    ex_strat = engine.exchange
-    aux = {"folded": dst_cnt.sum(dtype=jnp.int32),
-           "wire": jnp.uint32(ex_strat.wire_bytes(
-               engine.codec.wire_bytes(grid), grid.C)),
-           "msgs": jnp.int32(ex_strat.msgs_per_exchange(grid.C)),
-           "dir": jnp.int32(0)}
-    return st2, topo.psum_all(nc), ex.edges_scanned, aux
+        st2 = BFSState(level=up.level, pred=up.pred, visited=up.visited,
+                       front=nf, front_cnt=nc, lvl=st.lvl + 1)
+    with jax.named_scope("repro/loop"):
+        # the telemetry stamps (dead code unless telemetry is on) and the
+        # global frontier total the loop's exit test reads
+        ex_strat = engine.exchange
+        aux = {"folded": dst_cnt.sum(dtype=jnp.int32),
+               "wire": jnp.uint32(ex_strat.wire_bytes(
+                   engine.codec.wire_bytes(grid), grid.C)),
+               "msgs": jnp.int32(ex_strat.msgs_per_exchange(grid.C)),
+               "dir": jnp.int32(0)}
+        total = topo.psum_all(nc)
+    return st2, total, ex.edges_scanned, aux
 
 
 # ----------------------------------------------------------------------------

@@ -165,7 +165,7 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
     snd = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[:, None], (C, S))
 
     def step(st: BFSState, prev_total):
-        with jax.named_scope("repro/expand"):
+        with jax.named_scope("repro/expand"), jax.named_scope("bottomup"):
             all_words = frontier_words(topo, st.front, i)
             # masked-degree workload: only unvisited rows' in-edges are
             # scanned (the visited cache is consistent across the
@@ -193,9 +193,10 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
                 lambda s: s[0] < total, chunk_body,
                 (jnp.int32(0), jnp.full((nrl,), I32_MAX, jnp.int32)))
 
-        found = best < I32_MAX                 # rows with a frontier parent
-        visited1 = st.visited | found          # the send-suppression cache
-        parent_g = jnp.where(found, j * ncl + best, I32_MAX)
+        with jax.named_scope("repro/update"):
+            found = best < I32_MAX             # rows with a frontier parent
+            visited1 = st.visited | found      # the send-suppression cache
+            parent_g = jnp.where(found, j * ncl + best, I32_MAX)
 
         # value-fold (vertex, encoded parent) to the owners -- the same
         # exchange the value programs use, so every codec works here
@@ -205,43 +206,50 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
             ri, rc, rv = engine.codec.fold_values(ids, cnt, vals, topo=topo,
                                                   j=j)
 
-        # dense (C, S) per-sender parent table of my owned block (dump col S
-        # swallows the pads; senders propose each row at most once)
-        tt = jnp.where(ri >= 0, ri - j * S, S)
-        dense = jnp.full((C, S + 1), I32_MAX, jnp.int32).at[
-            snd.reshape(-1), tt.reshape(-1)].min(
-            jnp.where(ri >= 0, rv, I32_MAX).reshape(-1))[:, :S]
-        has = dense < I32_MAX
-        own_row = jnp.take(dense, j, axis=0)
-        own_has = own_row < I32_MAX
-        first_m = jnp.min(jnp.where(has, snd, C), axis=0)       # min sender
-        sel = jnp.where(own_has, j, jnp.clip(first_m, 0, C - 1))
-        parent = jnp.take_along_axis(dense, sel[None, :], axis=0)[0]
-        newly = own_has | (first_m < C)
+        with jax.named_scope("repro/update"):
+            # dense (C, S) per-sender parent table of my owned block (dump
+            # col S swallows the pads; senders propose each row at most
+            # once)
+            tt = jnp.where(ri >= 0, ri - j * S, S)
+            dense = jnp.full((C, S + 1), I32_MAX, jnp.int32).at[
+                snd.reshape(-1), tt.reshape(-1)].min(
+                jnp.where(ri >= 0, rv, I32_MAX).reshape(-1))[:, :S]
+            has = dense < I32_MAX
+            own_row = jnp.take(dense, j, axis=0)
+            own_has = own_row < I32_MAX
+            first_m = jnp.min(jnp.where(has, snd, C), axis=0)   # min sender
+            sel = jnp.where(own_has, j, jnp.clip(first_m, 0, C - 1))
+            parent = jnp.take_along_axis(dense, sel[None, :], axis=0)[0]
+            newly = own_has | (first_m < C)
 
-        rows_owned = j * S + jnp.arange(S, dtype=jnp.int32)
-        vis_owned_prev = jax.lax.dynamic_slice_in_dim(st.visited, j * S, S)
-        new = newly & ~vis_owned_prev
-        tgt = jnp.where(new, rows_owned, nrl)
-        visited2 = visited1.at[tgt].set(True, mode="drop")
-        level2 = st.level.at[tgt].set(jnp.where(new, st.lvl, 0), mode="drop")
-        pred2 = st.pred.at[tgt].set(jnp.where(new, parent, 0), mode="drop")
+            rows_owned = j * S + jnp.arange(S, dtype=jnp.int32)
+            vis_owned_prev = jax.lax.dynamic_slice_in_dim(st.visited, j * S,
+                                                          S)
+            new = newly & ~vis_owned_prev
+            tgt = jnp.where(new, rows_owned, nrl)
+            visited2 = visited1.at[tgt].set(True, mode="drop")
+            level2 = st.level.at[tgt].set(jnp.where(new, st.lvl, 0),
+                                          mode="drop")
+            pred2 = st.pred.at[tgt].set(jnp.where(new, parent, 0),
+                                        mode="drop")
 
-        lc = i * S + jnp.arange(S, dtype=jnp.int32)   # ROW2COL of owned rows
-        nf, nc = F.append_padded(jnp.full((S,), -1, jnp.int32),
-                                 jnp.int32(0), lc, new)
-        nf, nc = canonical_front(nf, nc)
-        st2 = BFSState(level=level2, pred=pred2, visited=visited2, front=nf,
-                       front_cnt=nc, lvl=st.lvl + 1)
-        folded = cnt.sum(dtype=jnp.int32)   # value fold: count-proportional
-        ex_strat = engine.exchange
-        aux = {"folded": folded,
-               "wire": jnp.uint32(ex_strat.wire_bytes(
-                   engine.codec.wire_bytes(grid), grid.C))
-               + ex_strat.value_extra_bytes(cnt, j, grid.C),
-               "msgs": jnp.int32(ex_strat.msgs_per_exchange(grid.C)),
-               "dir": jnp.int32(1)}
-        return st2, topo.psum_all(nc), total.astype(jnp.uint32), aux
+            lc = i * S + jnp.arange(S, dtype=jnp.int32)  # ROW2COL of owned
+            nf, nc = F.append_padded(jnp.full((S,), -1, jnp.int32),
+                                     jnp.int32(0), lc, new)
+            nf, nc = canonical_front(nf, nc)
+            st2 = BFSState(level=level2, pred=pred2, visited=visited2,
+                           front=nf, front_cnt=nc, lvl=st.lvl + 1)
+        with jax.named_scope("repro/loop"):
+            # value fold: count-proportional
+            folded = cnt.sum(dtype=jnp.int32)
+            ex_strat = engine.exchange
+            aux = {"folded": folded,
+                   "wire": jnp.uint32(ex_strat.wire_bytes(
+                       engine.codec.wire_bytes(grid), grid.C))
+                   + ex_strat.value_extra_bytes(cnt, j, grid.C),
+                   "msgs": jnp.int32(ex_strat.msgs_per_exchange(grid.C)),
+                   "dir": jnp.int32(1)}
+            return st2, topo.psum_all(nc), total.astype(jnp.uint32), aux
 
     return step
 
@@ -300,21 +308,26 @@ class DirectionProgram(FrontierProgram):
         lo_thr = jnp.int32(n // self.beta)    # leave it below this
 
         def step(st: DirState, prev_total):
+            # the switch's predicate and bookkeeping are loop control; the
+            # `lax.cond` itself runs under no scope, so each branch's
+            # instructions keep the step's own layer scopes
             if self.mode == "bottomup":
                 use_bu = jnp.bool_(True)
                 inner2, total, scanned, aux = bu(st.inner, prev_total)
             else:
-                use_bu = jnp.where(st.dir == 1, prev_total > lo_thr,
-                                   prev_total > hi_thr)
+                with jax.named_scope("repro/loop"):
+                    use_bu = jnp.where(st.dir == 1, prev_total > lo_thr,
+                                       prev_total > hi_thr)
                 # both branches return (state, total, scanned, aux) with
                 # identical aux structure, so telemetry rides the cond
                 inner2, total, scanned, aux = jax.lax.cond(
                     use_bu, lambda s: bu(s, prev_total),
                     lambda s: td(s, prev_total), st.inner)
-            dirs = st.dirs.at[jnp.minimum(st.k, L - 1)].set(
-                use_bu.astype(jnp.int32))
-            st2 = DirState(inner=inner2, dir=use_bu.astype(jnp.int32),
-                           dirs=dirs, k=st.k + 1)
+            with jax.named_scope("repro/loop"):
+                dirs = st.dirs.at[jnp.minimum(st.k, L - 1)].set(
+                    use_bu.astype(jnp.int32))
+                st2 = DirState(inner=inner2, dir=use_bu.astype(jnp.int32),
+                               dirs=dirs, k=st.k + 1)
             return st2, total, scanned, aux
 
         return step

@@ -188,9 +188,6 @@ class FrontierEngine:
         # -- an off-path engine never constructs (or traces) them, which is
         # the no-retrace guarantee tests assert
         self._ft_progs = {}
-        # last assembled LevelTrace (scalar) or tuple of traces (batched);
-        # None until a telemetry-enabled search completes
-        self.last_trace = None
         # traces of the level loop (scalar or batched); jit/AOT cache hits do
         # not retrace, so tests can assert a 64-root sweep compiles once
         self.trace_count = 0
@@ -212,18 +209,27 @@ class FrontierEngine:
 
         def device_fn(col_off, row_idx, nnz, *rest):
             extra, arg = rest[:-1], rest[-1]
-            graph = LocalGraph2D(col_off=col_off[0, 0], row_idx=row_idx[0, 0],
-                                 nnz=nnz[0, 0])
-            extra = tuple(e[0, 0] for e in extra)
-            i, j = topo.device_coords()
+            with jax.named_scope("repro/loop"):
+                graph = LocalGraph2D(col_off=col_off[0, 0],
+                                     row_idx=row_idx[0, 0], nnz=nnz[0, 0])
+                extra = tuple(e[0, 0] for e in extra)
+                i, j = topo.device_coords()
 
+            # One layer scope per instruction (DESIGN.md sec. 13.4): the level
+            # loop's own control runs under `repro/loop`, the step under the
+            # scopes the program opens, and the `while_loop` / `lax.map`
+            # calls themselves under none, so no layer scope encloses
+            # another.
             def search(a):
-                st = prog.init(self, graph, extra, a, i, j)
-                step = prog.make_step(self, graph, extra, i, j)
+                with jax.named_scope("repro/loop"):
+                    st = prog.init(self, graph, extra, a, i, j)
+                    step = prog.make_step(self, graph, extra, i, j)
+                    init_total = prog.init_total(self, st)
 
                 def cond(carry):
                     st, total = carry[0], carry[1]
-                    return prog.keep_going(self, st, total)
+                    with jax.named_scope("repro/loop"):
+                        return prog.keep_going(self, st, total)
 
                 def run_step(st, total):
                     # steps return (st', total, scanned[, aux]); aux is the
@@ -238,31 +244,34 @@ class FrontierEngine:
                 def body(carry):
                     st, total, hi, lo = carry[:4]
                     st2, total2, scanned, aux = run_step(st, total)
-                    hi, lo = wide_add(hi, lo, scanned)
-                    if not telemetry:
-                        return st2, total2, hi, lo
-                    tr = T.record_level(
-                        carry[4], frontier=total,
-                        front_dev=prog.front_count(st), scanned=scanned,
-                        aux=T.normalize_aux(aux))
+                    with jax.named_scope("repro/loop"):
+                        hi, lo = wide_add(hi, lo, scanned)
+                        if not telemetry:
+                            return st2, total2, hi, lo
+                        tr = T.record_level(
+                            carry[4], frontier=total,
+                            front_dev=prog.front_count(st), scanned=scanned,
+                            aux=T.normalize_aux(aux))
                     return st2, total2, hi, lo, tr
 
-                init_total = prog.init_total(self, st)
-                carry = (st, init_total, jnp.uint32(0), jnp.uint32(0))
-                if telemetry:
-                    carry += (T.init_trace(self.max_levels),)
+                with jax.named_scope("repro/loop"):
+                    carry = (st, init_total, jnp.uint32(0), jnp.uint32(0))
+                    if telemetry:
+                        carry += (T.init_trace(self.max_levels),)
                 carry = jax.lax.while_loop(cond, body, carry)
                 st, hi, lo = carry[0], carry[2], carry[3]
-                outs = tuple(prog.finalize(self, st, i, j)) + (hi, lo)
-                if telemetry:
-                    outs += T.trace_outputs(carry[4])
+                with jax.named_scope("repro/finalize"):
+                    outs = tuple(prog.finalize(self, st, i, j)) + (hi, lo)
+                    if telemetry:
+                        outs += T.trace_outputs(carry[4])
                 return outs
 
             if batched:
                 outs = jax.lax.map(search, arg)
             else:
                 outs = search(arg)
-            return tuple(o[None, None] for o in outs)
+            with jax.named_scope("repro/finalize"):
+                return tuple(o[None, None] for o in outs)
 
         dev = topo.dev_spec
         out_specs = tuple(prog.out_specs(self)) + (dev, dev)
@@ -283,7 +292,7 @@ class FrontierEngine:
     def assemble(self, outs, B):
         """Gathered device outputs -> output object, with telemetry split
         off, assembled into a host `LevelTrace`, attached to the output's
-        `trace` field and kept as `self.last_trace`.
+        `trace` field (`GraphSession.last_trace()` keeps it per session).
 
         This is the ONE funnel both invocation paths share: `run` /
         `run_batch` here, and the session layer's AOT executables (which
@@ -305,7 +314,6 @@ class FrontierEngine:
         if trace is not None:
             import dataclasses
             out = dataclasses.replace(out, trace=trace)
-            self.last_trace = trace
         return out
 
     def run(self, graph: LocalGraph2D, arg, *extra):

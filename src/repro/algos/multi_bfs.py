@@ -113,53 +113,64 @@ class MultiSourceBFSProgram(FrontierProgram):
         wire_base = jnp.uint32(engine.codec.wire_bytes(grid))
 
         def step(st: MultiBFSState, prev_total):
-            if scan is not None:
-                cand, scanned = scan(st)
-            else:
-                all_front, all_pay, ftot = X.expand_exchange_values(
-                    st.front, st.front_cnt, st.payload, topo=topo,
-                    fill=I32_MAX, ops=fold_ops)
-                cand, scanned = PR.scan_relax(
-                    graph.col_off, graph.row_idx, None, all_front, all_pay,
-                    ftot, lambda p, w: p, n_rows=nrl, grid=grid,
-                    edge_chunk=engine.edge_chunk,
-                    expand_fn=engine.value_expand_fn)
-            # first fold per vertex per device (the BFS visited discipline)
-            improved = (cand < I32_MAX) & ~st.visited
-            vis1 = st.visited | improved
-            ids, cnt, vals = PR.pack_blocks(improved, cand, grid,
-                                            ops=fold_ops)
-            ri, rc, rv = engine.codec.fold_values(ids, cnt, vals,
-                                                  topo=topo, j=j)
-            inc = PR.scatter_min_received(ri, rv, j, S)
-            # claims merge against the PRE-scan owned state: this device's
-            # own discoveries travel through the self all_to_all block, so
-            # judging them here would shadow a smaller source id arriving
-            # from a peer in the same wave
-            vis_owned_prev = jax.lax.dynamic_slice_in_dim(st.visited,
-                                                          j * S, S)
-            changed = (inc < I32_MAX) & ~vis_owned_prev
-            src_prev = jax.lax.dynamic_slice_in_dim(st.src, j * S, S)
-            lvl_prev = jax.lax.dynamic_slice_in_dim(st.level, j * S, S)
-            new_src = jnp.where(changed, inc, src_prev)
-            new_lvl = jnp.where(changed, st.lvl, lvl_prev)
-            src2 = jax.lax.dynamic_update_slice(st.src, new_src, (j * S,))
-            lvl2 = jax.lax.dynamic_update_slice(st.level, new_lvl, (j * S,))
-            vis_owned = jax.lax.dynamic_slice_in_dim(vis1, j * S, S)
-            vis2 = jax.lax.dynamic_update_slice(vis1, vis_owned | changed,
-                                                (j * S,))
-            front, payload, nc = PR.owned_to_front(changed, new_src, i, S,
-                                                   ops=fold_ops)
-            st2 = MultiBFSState(visited=vis2, level=lvl2, src=src2,
-                                front=front, payload=payload, front_cnt=nc,
-                                lvl=st.lvl + 1)
-            # per-level telemetry channel: value folds ship 4 extra payload
-            # bytes per folded entry on top of the codec's static frame
-            folded = cnt.sum(dtype=jnp.int32)
-            aux = {"folded": folded,
-                   "wire": wire_base + 4 * folded.astype(jnp.uint32),
-                   "dir": step_dir}
-            return st2, topo.psum_all(nc), scanned, aux
+            with jax.named_scope("repro/expand"):
+                if scan is not None:
+                    with jax.named_scope("bottomup"):
+                        cand, scanned = scan(st)
+                else:
+                    with jax.named_scope("exchange"):
+                        all_front, all_pay, ftot = X.expand_exchange_values(
+                            st.front, st.front_cnt, st.payload, topo=topo,
+                            fill=I32_MAX, ops=fold_ops)
+                    cand, scanned = PR.scan_relax(
+                        graph.col_off, graph.row_idx, None, all_front,
+                        all_pay, ftot, lambda p, w: p, n_rows=nrl,
+                        grid=grid, edge_chunk=engine.edge_chunk,
+                        expand_fn=engine.value_expand_fn)
+            with jax.named_scope("repro/update"):
+                # first fold per vertex per device (the BFS visited
+                # discipline)
+                improved = (cand < I32_MAX) & ~st.visited
+                vis1 = st.visited | improved
+            with jax.named_scope("repro/fold"):
+                ids, cnt, vals = PR.pack_blocks(improved, cand, grid,
+                                                ops=fold_ops)
+                ri, rc, rv = engine.codec.fold_values(ids, cnt, vals,
+                                                      topo=topo, j=j)
+            with jax.named_scope("repro/update"):
+                inc = PR.scatter_min_received(ri, rv, j, S)
+                # claims merge against the PRE-scan owned state: this
+                # device's own discoveries travel through the self
+                # all_to_all block, so judging them here would shadow a
+                # smaller source id arriving from a peer in the same wave
+                vis_owned_prev = jax.lax.dynamic_slice_in_dim(st.visited,
+                                                              j * S, S)
+                changed = (inc < I32_MAX) & ~vis_owned_prev
+                src_prev = jax.lax.dynamic_slice_in_dim(st.src, j * S, S)
+                lvl_prev = jax.lax.dynamic_slice_in_dim(st.level, j * S, S)
+                new_src = jnp.where(changed, inc, src_prev)
+                new_lvl = jnp.where(changed, st.lvl, lvl_prev)
+                src2 = jax.lax.dynamic_update_slice(st.src, new_src,
+                                                    (j * S,))
+                lvl2 = jax.lax.dynamic_update_slice(st.level, new_lvl,
+                                                    (j * S,))
+                vis_owned = jax.lax.dynamic_slice_in_dim(vis1, j * S, S)
+                vis2 = jax.lax.dynamic_update_slice(
+                    vis1, vis_owned | changed, (j * S,))
+                front, payload, nc = PR.owned_to_front(changed, new_src, i,
+                                                       S, ops=fold_ops)
+                st2 = MultiBFSState(visited=vis2, level=lvl2, src=src2,
+                                    front=front, payload=payload,
+                                    front_cnt=nc, lvl=st.lvl + 1)
+            with jax.named_scope("repro/loop"):
+                # per-level telemetry channel: value folds ship 4 extra
+                # payload bytes per folded entry on top of the codec's
+                # static frame
+                folded = cnt.sum(dtype=jnp.int32)
+                aux = {"folded": folded,
+                       "wire": wire_base + 4 * folded.astype(jnp.uint32),
+                       "dir": step_dir}
+                return st2, topo.psum_all(nc), scanned, aux
 
         return step
 

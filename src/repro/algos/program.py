@@ -309,19 +309,22 @@ def make_value_step(engine, graph, i, j, *, relax, edge_vals=None,
     def step(st: ValueState, prev_total):
         with jax.named_scope("repro/expand"):
             if scan is not None:
-                cand, scanned = scan(st)
+                with jax.named_scope("bottomup"):
+                    cand, scanned = scan(st)
             else:
-                all_front, all_pay, ftot = X.expand_exchange_values(
-                    st.front, st.front_cnt, st.payload, topo=topo,
-                    fill=expand_fill, ops=fold_ops)
+                with jax.named_scope("exchange"):
+                    all_front, all_pay, ftot = X.expand_exchange_values(
+                        st.front, st.front_cnt, st.payload, topo=topo,
+                        fill=expand_fill, ops=fold_ops)
                 cand, scanned = scan_relax(
                     graph.col_off, graph.row_idx, edge_vals, all_front,
                     all_pay, ftot, relax, n_rows=nrl, grid=grid,
                     edge_chunk=engine.edge_chunk,
                     expand_fn=engine.value_expand_fn)
-        # propose only strict improvements over what we already know
-        improved = cand < st.val
-        val1 = jnp.minimum(st.val, cand)
+        with jax.named_scope("repro/update"):
+            # propose only strict improvements over what we already know
+            improved = cand < st.val
+            val1 = jnp.minimum(st.val, cand)
         with jax.named_scope("repro/fold"):
             ids, cnt, vals = pack_blocks(improved, cand, grid, ops=fold_ops)
             ri, rc, rv = engine.codec.fold_values(ids, cnt, vals, topo=topo,
@@ -337,14 +340,16 @@ def make_value_step(engine, graph, i, j, *, relax, edge_vals=None,
             val2 = jax.lax.dynamic_update_slice(val1, new_owned, (j * S,))
             front, payload, nc = owned_to_front(changed, new_owned, i, S,
                                                 ops=fold_ops)
-        st2 = ValueState(val=val2, front=front, payload=payload,
-                         front_cnt=nc, it=st.it + 1)
-        folded = cnt.sum(dtype=jnp.int32)
-        aux = {"folded": folded,
-               "wire": wire_base + ex_strat.value_extra_bytes(cnt, j, grid.C),
-               "msgs": step_msgs,
-               "dir": step_dir}
-        return st2, topo.psum_all(nc), scanned, aux
+            st2 = ValueState(val=val2, front=front, payload=payload,
+                             front_cnt=nc, it=st.it + 1)
+        with jax.named_scope("repro/loop"):
+            folded = cnt.sum(dtype=jnp.int32)
+            aux = {"folded": folded,
+                   "wire": wire_base + ex_strat.value_extra_bytes(
+                       cnt, j, grid.C),
+                   "msgs": step_msgs,
+                   "dir": step_dir}
+            return st2, topo.psum_all(nc), scanned, aux
 
     return step
 

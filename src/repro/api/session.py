@@ -13,9 +13,18 @@ axis) and returns batched outputs.  Executables are AOT-compiled with
 `jit(...).lower().compile()` and cached on the DistGraph keyed by
 (engine key = codec/direction/..., graph array shapes, batch size), so a
 Graph500-style 64-root sweep traces the level loop exactly once.
+
+Host spans (DESIGN.md sec. 13.4): every `GraphSession.bfs` runs under the
+profiler span `repro/session/bfs`, with children `repro/session/dispatch`
+(checks, placement, executable lookup, the call), `repro/session/compile`
+(an AOT miss only) and `repro/session/assemble` (the host waits for the
+outputs and builds the answer); planning runs under `repro/plan/<phase>`.
+With no profiler running each span is one inert `TraceMe`.
 """
 from __future__ import annotations
 
+import contextlib
+import time
 import warnings
 from collections import OrderedDict
 
@@ -23,6 +32,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.algos import (
     BFSLevelsProgram, CCOutput, ConnectedComponentsProgram, DirectionProgram,
@@ -70,7 +80,9 @@ class AOTCache:
     the per-DistGraph executable cache without limit.  Eviction recompiles
     on next use, so the bound trades compile time for memory, never
     correctness.  `hits` / `misses` / `evictions` feed `repro.serve`
-    accounting.
+    accounting; `compiles` / `compile_s` count the misses that lowered and
+    compiled, and their seconds (loads from JAX's persistent compilation
+    cache included).
     """
 
     def __init__(self, maxsize: int = 32):
@@ -81,6 +93,8 @@ class AOTCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        self.compiles = 0
+        self.compile_s = 0.0
 
     def get(self, key, default=None):
         try:
@@ -108,7 +122,20 @@ class AOTCache:
     def stats(self) -> dict:
         return {"size": len(self._entries), "maxsize": self.maxsize,
                 "hits": self.hits, "misses": self.misses,
-                "evictions": self.evictions}
+                "evictions": self.evictions, "compiles": self.compiles,
+                "compile_s": self.compile_s}
+
+
+@contextlib.contextmanager
+def _planning(plan: dict, phase: str):
+    """Time one host planning phase into `plan[phase]` (seconds), under the
+    profiler span `repro/plan/<phase>`."""
+    with TraceAnnotation(f"repro/plan/{phase}"):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            plan[phase] = plan.get(phase, 0.0) + time.perf_counter() - t0
 
 
 def build_engine(topology: Topology, config: BFSConfig) -> DistBFSEngine:
@@ -159,6 +186,9 @@ class DistGraph:
         # many batch sizes / engine configs cannot grow without limit (the
         # deprecated driver shims may swap in a plain shared dict)
         self._compiled = AOTCache(aot_cache_size)
+        # host planning seconds by phase: "csc" (partition), "place"
+        # (device placement calls), "csr" (the twin, planned lazily)
+        self._plan_s = {}
 
     @classmethod
     def from_edges(cls, edges, config: BFSConfig = None, *, mesh=None,
@@ -180,25 +210,29 @@ class DistGraph:
         grid = config.resolve_grid(n, mesh)
         topology = Topology.for_grid(grid, mesh, config.row_axes,
                                      config.col_axes)
-        lg = partition_2d(edges_np, grid)
+        plan = {}
+        w_host = None if weights is None else np.asarray(weights)
+        with _planning(plan, "csc"):
+            lg = partition_2d(edges_np, grid)
+            w_part = None if w_host is None \
+                else partition_edge_vals(edges_np, w_host, grid)
         # device placement: per-device (R, C, ...) arrays land sharded over
         # the grid axes, each device holding only its own block (in a
         # process group every process materialises only its addressable
         # shards; repro.dist.multihost)
         place = cls._placer(topology)
-        csc = LocalGraph2D(place(lg.col_off), place(lg.row_idx),
-                           place(lg.nnz))
-        w = None
-        w_host = None
-        if weights is not None:
-            w_host = np.asarray(weights)
-            w = place(partition_edge_vals(edges_np, w_host, grid))
+        with _planning(plan, "place"):
+            csc = LocalGraph2D(place(lg.col_off), place(lg.row_idx),
+                               place(lg.nnz))
+            w = None if w_part is None else place(w_part)
         # the CSR twin is planned LAZILY on the first query that needs it
         # (a direction-enabled session/algo call -> ensure_csr), so planning
         # with direction on costs nothing until bottom-up actually runs
-        return cls(topology, csc, weights=w, edges=edges_np, n=n,
-                   config=config, weights_host=w_host,
-                   aot_cache_size=aot_cache_size)
+        graph = cls(topology, csc, weights=w, edges=edges_np, n=n,
+                    config=config, weights_host=w_host,
+                    aot_cache_size=aot_cache_size)
+        graph._plan_s.update(plan)
+        return graph
 
     @staticmethod
     def _placer(topology: Topology):
@@ -217,12 +251,13 @@ class DistGraph:
                     "direction=True needs the CSR twin, but this DistGraph "
                     "was built without edges; pass csr= or use from_edges")
             place = self._placer(self.topology)
-            self.csr = {k: place(v)
-                        for k, v in partition_2d_csr(self._edges,
-                                                     self.grid).items()}
-            if self._weights_host is not None:
-                self.csr_weights = place(partition_edge_vals_csr(
-                    self._edges, self._weights_host, self.grid))
+            with _planning(self._plan_s, "csr"):
+                self.csr = {k: place(v)
+                            for k, v in partition_2d_csr(self._edges,
+                                                         self.grid).items()}
+                if self._weights_host is not None:
+                    self.csr_weights = place(partition_edge_vals_csr(
+                        self._edges, self._weights_host, self.grid))
             self._edges = None       # both layouts resident -> edges done
             self._weights_host = None
         return self.csr
@@ -242,7 +277,29 @@ class DistGraph:
         if isinstance(cache, AOTCache):
             return cache.stats()
         return {"size": len(cache), "maxsize": None, "hits": None,
-                "misses": None, "evictions": None}
+                "misses": None, "evictions": None, "compiles": None,
+                "compile_s": None}
+
+    def stats(self) -> dict:
+        """The graph's own counters: host planning seconds by phase
+        (`plan`: "csc", "place", "csr" once the twin is planned) and the
+        AOT cache's (`aot`, as `cache_stats()`)."""
+        return {"plan": dict(self._plan_s), "aot": self.cache_stats()}
+
+    def executable(self, key, lower):
+        """The cached executable for `key`; on a miss `lower()` is compiled
+        (under the span `repro/session/compile`), cached and counted."""
+        compiled = self._compiled.get(key)
+        if compiled is None:
+            with TraceAnnotation("repro/session/compile"):
+                t0 = time.perf_counter()
+                compiled = lower().compile()
+                seconds = time.perf_counter() - t0
+            self._compiled[key] = compiled
+            if isinstance(self._compiled, AOTCache):
+                self._compiled.compiles += 1
+                self._compiled.compile_s += seconds
+        return compiled
 
     def aot_cache_stats(self) -> dict:
         """Deprecated spelling of `cache_stats()` (same dict)."""
@@ -320,15 +377,10 @@ class GraphSession:
             raise ValueError(f"batch capacity B must be >= 1, got {B}")
         g = self.graph.csc
         key = (self.config.engine_key, g.col_off.shape, g.row_idx.shape, B)
-        compiled = self.graph._compiled.get(key)
-        if compiled is None:
-            roots_aval = multihost.arg_aval((B,), jnp.int32,
-                                            self.graph.mesh)
-            compiled = self.engine._run_batch.lower(
+        return self.graph.executable(
+            key, lambda: self.engine._run_batch.lower(
                 g.col_off, g.row_idx, g.nnz, *self._extra,
-                roots_aval).compile()
-            self.graph._compiled[key] = compiled
-        return compiled
+                multihost.arg_aval((B,), jnp.int32, self.graph.mesh)))
 
     def _run_recoverable(self, eng, arg, *extra, B=None, recovery=None):
         """Fault-tolerant query path: the segmented engine loop under the
@@ -365,33 +417,42 @@ class GraphSession:
         loss injector / retry policy) for a fault_tolerance=True session;
         the query then runs the segmented level loop and can resume.
         """
+        with TraceAnnotation("repro/session/bfs"):
+            return self._bfs(roots, validate, recovery)
+
+    def _bfs(self, roots, validate, recovery) -> BFSOutput:
         scalar = np.ndim(roots) == 0
-        check_vertex_ids(roots, self.graph.n, "roots")
-        roots_np = np.atleast_1d(np.asarray(roots, np.int32))
-        if roots_np.ndim != 1:
-            raise ValueError(f"roots must be a scalar or 1D batch, got "
-                             f"shape {roots_np.shape}")
-        roots_arr = multihost.put_replicated(roots_np, self.graph.mesh)
-        B = roots_np.shape[0]
-        g = self.graph.csc
-        if self._check_recovery(recovery):
-            out = self._run_recoverable(self.engine, roots_arr,
-                                        *self._extra, B=B,
-                                        recovery=recovery)
-        else:
-            outs = self.compiled_for(B)(
-                g.col_off, g.row_idx, g.nnz, *self._extra, roots_arr)
-            out = self.engine.assemble_batch(outs, B)
-        if validate is not False and validate is not None:
-            self._validate(out, roots_np, validate)
-        if scalar:
-            out = BFSOutput(level=out.level[0], pred=out.pred[0],
-                            n_levels=out.n_levels[0],
-                            edges_scanned=out.edges_scanned[0],
-                            directions=None if out.directions is None
-                            else out.directions[0],
-                            trace=None if out.trace is None
-                            else out.trace[0])
+        with TraceAnnotation("repro/session/dispatch"):
+            check_vertex_ids(roots, self.graph.n, "roots")
+            roots_np = np.atleast_1d(np.asarray(roots, np.int32))
+            if roots_np.ndim != 1:
+                raise ValueError(f"roots must be a scalar or 1D batch, got "
+                                 f"shape {roots_np.shape}")
+            roots_arr = multihost.put_replicated(roots_np, self.graph.mesh)
+            B = roots_np.shape[0]
+            g = self.graph.csc
+            recoverable = self._check_recovery(recovery)
+            if recoverable:
+                # the segmented loop assembles its own output
+                out = self._run_recoverable(self.engine, roots_arr,
+                                            *self._extra, B=B,
+                                            recovery=recovery)
+            else:
+                outs = self.compiled_for(B)(
+                    g.col_off, g.row_idx, g.nnz, *self._extra, roots_arr)
+        with TraceAnnotation("repro/session/assemble"):
+            if not recoverable:
+                out = self.engine.assemble_batch(outs, B)
+            if validate is not False and validate is not None:
+                self._validate(out, roots_np, validate)
+            if scalar:
+                out = BFSOutput(level=out.level[0], pred=out.pred[0],
+                                n_levels=out.n_levels[0],
+                                edges_scanned=out.edges_scanned[0],
+                                directions=None if out.directions is None
+                                else out.directions[0],
+                                trace=None if out.trace is None
+                                else out.trace[0])
         if out.trace is not None:
             self._last_trace = out.trace
         return out
@@ -468,13 +529,10 @@ class GraphSession:
         g = self.graph.csc
         ckey = (key, g.col_off.shape, g.row_idx.shape, batched,
                 arg_aval.shape)
-        compiled = self.graph._compiled.get(ckey)
-        if compiled is None:
-            fn = eng._run_batch if batched else eng._run
-            compiled = fn.lower(g.col_off, g.row_idx, g.nnz, *extra,
-                                arg_aval).compile()
-            self.graph._compiled[ckey] = compiled
-        return compiled
+        fn = eng._run_batch if batched else eng._run
+        return self.graph.executable(
+            ckey, lambda: fn.lower(g.col_off, g.row_idx, g.nnz, *extra,
+                                   arg_aval))
 
     def connected_components(self, fold_codec=None,
                              recovery=None) -> CCOutput:

@@ -367,36 +367,47 @@ def expand_frontier(col_off, row_idx, visited, level, pred, all_front,
     words = pack_bitmap(visited) if use_words \
         else jnp.zeros((1,), jnp.uint32)               # pytree placeholder
 
+    # The chunk body's three parts run under their own scopes, nested in
+    # the caller's layer scope (DESIGN.md sec. 13.4): `map` (edge slot ->
+    # frontier column -> row id), `filter` (visited test and first-claimant
+    # selection), `mark` (visited / pred / level scatters, bucket append).
     def chunk_body(state):
         start, visited, words, level, pred, dst, dst_cnt = state
         gids = start + jnp.arange(edge_chunk, dtype=jnp.int32)
-        if expand_fn is None:
-            v, u, _, _, valid = reference_expand_chunk(
-                gids, cumul, all_front, front_total, col_off, row_idx)
-            unvis = valid & ~visited[v]
-        elif use_words:
-            v, unvis, u = expand_fn(gids, cumul, all_front, front_total,
-                                    col_off, row_idx, visited, words=words)
-        else:
-            v, unvis, u = expand_fn(gids, cumul, all_front, front_total,
-                                    col_off, row_idx, visited)
-        win = winner_dedup(v, unvis, n_rows, method=dedup)
-        # mark visited (paper: atomicOr on the full-local-row bitmap -- this
-        # is what makes every remote vertex fold at most once per search)
-        visited = visited.at[jnp.where(win, v, n_rows)].set(True, mode="drop")
-        if use_words:
-            words = set_bits(words, v, win)
-        # predecessor: global parent id, stored also for remote rows
-        # (deferred resolution, paper sec. 3.5 / [2])
-        pg = (j * ncl + u).astype(jnp.int32)
-        pred = pred.at[jnp.where(win, v, n_rows)].set(
-            jnp.where(win, pg, 0), mode="drop")
-        # local rows get their level here (Alg. 3 line 15)
-        m = v // S
-        is_local = win & (m == j)
-        level = level.at[jnp.where(is_local, v, n_rows)].set(
-            jnp.where(is_local, lvl, 0), mode="drop")
-        dst, dst_cnt = bucket_append(dst, dst_cnt, v, m, win, C)
+        with jax.named_scope("map"):
+            if expand_fn is None:
+                v, u, _, _, valid = reference_expand_chunk(
+                    gids, cumul, all_front, front_total, col_off, row_idx)
+            elif use_words:
+                v, unvis, u = expand_fn(gids, cumul, all_front, front_total,
+                                        col_off, row_idx, visited,
+                                        words=words)
+            else:
+                v, unvis, u = expand_fn(gids, cumul, all_front, front_total,
+                                        col_off, row_idx, visited)
+        with jax.named_scope("filter"):
+            if expand_fn is None:
+                unvis = valid & ~visited[v]
+            win = winner_dedup(v, unvis, n_rows, method=dedup)
+        with jax.named_scope("mark"):
+            # mark visited (paper: atomicOr on the full-local-row bitmap --
+            # this is what makes every remote vertex fold at most once per
+            # search)
+            visited = visited.at[jnp.where(win, v, n_rows)].set(
+                True, mode="drop")
+            if use_words:
+                words = set_bits(words, v, win)
+            # predecessor: global parent id, stored also for remote rows
+            # (deferred resolution, paper sec. 3.5 / [2])
+            pg = (j * ncl + u).astype(jnp.int32)
+            pred = pred.at[jnp.where(win, v, n_rows)].set(
+                jnp.where(win, pg, 0), mode="drop")
+            # local rows get their level here (Alg. 3 line 15)
+            m = v // S
+            is_local = win & (m == j)
+            level = level.at[jnp.where(is_local, v, n_rows)].set(
+                jnp.where(is_local, lvl, 0), mode="drop")
+            dst, dst_cnt = bucket_append(dst, dst_cnt, v, m, win, C)
         return start + edge_chunk, visited, words, level, pred, dst, dst_cnt
 
     def chunk_cond(state):

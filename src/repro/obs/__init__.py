@@ -10,14 +10,15 @@
 
 2. The metrics registry: thread-safe labeled counters / gauges /
    histograms (`MetricsRegistry`), JSON + Prometheus-text exposition
-   (`to_prometheus`, `to_json`) and the JSONL `EventLog`.  Every
-   `GraphServer` owns one registry, so counters reset with the server.
+   (`MetricsRegistry.snapshot`, `to_prometheus`) and the JSONL
+   `EventLog`.  Every `GraphServer` owns one registry, so counters reset
+   with the server.
 
 3. Request tracing in `repro.serve`: span-per-request lifecycle
    (admit -> queue -> coalesce -> execute -> demux) on each
    `QueryResult.trace`, feeding the registry's latency histograms.
 """
-from repro.obs.export import EventLog, to_json, to_prometheus, write_json
+from repro.obs.export import EventLog, to_prometheus
 from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                                MetricsRegistry)
 from repro.obs.spans import PHASES, RequestTrace, Span, request_trace
@@ -27,7 +28,7 @@ from repro.obs.trace import (N_TRACE_OUTS, TRACE_CHANNELS, LevelTrace,
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
-    "EventLog", "to_prometheus", "to_json", "write_json",
+    "EventLog", "to_prometheus",
     "LevelTrace", "assemble_traces", "init_trace", "normalize_aux",
     "record_level", "trace_outputs", "TRACE_CHANNELS", "N_TRACE_OUTS",
     "RequestTrace", "Span", "PHASES", "request_trace",

@@ -1,5 +1,5 @@
-"""Exposition: JSON + Prometheus text + the JSONL event log
-(DESIGN.md sec. 13).
+"""Exposition: Prometheus text + the JSONL event log (DESIGN.md sec. 13).
+The JSON snapshot is `MetricsRegistry.snapshot()`.
 
 `to_prometheus` renders a `MetricsRegistry` in the Prometheus text format
 (version 0.0.4): HELP/TYPE headers, one sample line per labeled series,
@@ -79,17 +79,6 @@ def to_prometheus(registry: MetricsRegistry) -> str:
                      f"{_labels_text([k for k, _ in items], [v for _, v in items])}"
                      f" {_num(value)}")
     return "\n".join(lines) + ("\n" if lines else "")
-
-
-def to_json(registry: MetricsRegistry) -> dict:
-    """JSON-able snapshot (metrics + collector samples)."""
-    return registry.snapshot()
-
-
-def write_json(registry: MetricsRegistry, path) -> None:
-    with open(path, "w") as f:
-        json.dump(to_json(registry), f, indent=2, sort_keys=True)
-        f.write("\n")
 
 
 class EventLog:
