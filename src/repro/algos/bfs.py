@@ -87,7 +87,7 @@ def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
             graph.col_off, graph.row_idx, st.visited, st.level, st.pred,
             all_front, front_total, st.lvl, grid=grid, i=i, j=j,
             edge_chunk=engine.edge_chunk, expand_fn=engine.expand_fn,
-            dedup=engine.dedup)
+            dedup=engine.dedup, count_passes=engine.telemetry)
 
     with jax.named_scope("repro/update"):
         # own-column vertices go straight to the frontier (lines 15-16)
@@ -124,7 +124,8 @@ def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
                "wire": jnp.uint32(ex_strat.wire_bytes(
                    engine.codec.wire_bytes(grid), grid.C)),
                "msgs": jnp.int32(ex_strat.msgs_per_exchange(grid.C)),
-               "dir": jnp.int32(0)}
+               "dir": jnp.int32(0),
+               "map_passes": ex.map_passes}
         total = topo.psum_all(nc)
     return st2, total, ex.edges_scanned, aux
 

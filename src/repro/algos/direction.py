@@ -248,7 +248,8 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
                        engine.codec.wire_bytes(grid), grid.C))
                    + ex_strat.value_extra_bytes(cnt, j, grid.C),
                    "msgs": jnp.int32(ex_strat.msgs_per_exchange(grid.C)),
-                   "dir": jnp.int32(1)}
+                   "dir": jnp.int32(1),
+                   "map_passes": jnp.int32(0)}
             return st2, topo.psum_all(nc), total.astype(jnp.uint32), aux
 
     return step

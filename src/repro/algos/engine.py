@@ -570,27 +570,37 @@ class FrontierEngine:
         Same grid: bit-exact reimport.  Shrunken grid: per-device work
         channels collapse onto device (0, 0) (sums -- global per-level
         figures survive exactly, per-device attribution does not) and the
-        psum-replicated channels broadcast from device (0, 0).
+        psum-replicated channels broadcast from device (0, 0).  A channel
+        newer than the snapshot starts blank, as after a snapshot taken
+        without telemetry.
         """
         from repro.obs import trace as T
+
+        def blank(c, shape):
+            if c == "dir":
+                return np.full(shape, -1, np.int32)
+            return np.zeros(shape, np.uint32 if c in ("scanned", "wire")
+                            else np.int32)
+
         R, C = self.grid.R, self.grid.C
         shp = (R, C) + (() if B is None else (B,))
         L = int(self.max_levels)
         if traw is None:
             # resuming a snapshot taken without telemetry: blank history,
             # k advanced so post-resume levels land in the right slots
-            tr = {c: np.zeros(shp + (L,),
-                              np.uint32 if c in ("scanned", "wire")
-                              else np.int32)
-                  for c in T.TRACE_CHANNELS}
-            tr["dir"] = np.full(shp + (L,), -1, np.int32)
+            tr = {c: blank(c, shp + (L,)) for c in T.TRACE_CHANNELS}
             tr["k"] = np.full(shp, levels_done, np.int32)
             return tr
+        traw = dict(traw)
+        for c in T.TRACE_CHANNELS:
+            if c not in traw:
+                traw[c] = blank(c, np.shape(traw["frontier"]))
         src_grid = traw["k"].shape[:2]
         if src_grid == (R, C):
             return {k: np.asarray(v) for k, v in traw.items()}
         tr = {}
-        for c in ("front_dev", "scanned", "folded", "wire", "msgs"):
+        for c in ("front_dev", "scanned", "folded", "wire", "msgs",
+                  "map_passes"):
             a = np.asarray(traw[c])
             out = np.zeros(shp + (L,), a.dtype)
             out[0, 0] = a.sum(axis=(0, 1), dtype=np.int64).astype(a.dtype)

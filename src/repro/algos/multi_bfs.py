@@ -125,7 +125,7 @@ class MultiSourceBFSProgram(FrontierProgram):
                     cand, scanned = PR.scan_relax(
                         graph.col_off, graph.row_idx, None, all_front,
                         all_pay, ftot, lambda p, w: p, n_rows=nrl,
-                        grid=grid, edge_chunk=engine.edge_chunk,
+                        edge_chunk=engine.edge_chunk,
                         expand_fn=engine.value_expand_fn)
             with jax.named_scope("repro/update"):
                 # first fold per vertex per device (the BFS visited

@@ -181,7 +181,7 @@ class ValueState:
 # ----------------------------------------------------------------------------
 
 def scan_relax(col_off, row_idx, edge_vals, all_front, all_payload,
-               front_total, relax, *, n_rows: int, grid: Grid2D,
+               front_total, relax, *, n_rows: int,
                edge_chunk: int = 8192, expand_fn=None):
     """Chunked CSC scan of the gathered frontier, min-combining relaxed
     payloads into a dense per-local-row candidate array.
@@ -202,20 +202,15 @@ def scan_relax(col_off, row_idx, edge_vals, all_front, all_payload,
 
     Returns (cand (n_rows,) int32, edges_scanned uint32).
     """
-    ncl = grid.n_cols_local
-
-    u_safe = jnp.clip(all_front, 0, ncl - 1)
-    deg = (col_off[u_safe + 1] - col_off[u_safe])
-    deg = jnp.where(jnp.arange(ncl) < front_total, deg, 0)
-    cumul = F.exclusive_cumsum(deg)                    # (ncl + 1,)
-    total = cumul[front_total]
+    cumul, total, table = F.frontier_workload(all_front, front_total,
+                                              col_off)
 
     def chunk_body(state):
         start, cand = state
         gids = start + jnp.arange(edge_chunk, dtype=jnp.int32)
         if expand_fn is None:
             v, _, k, addr, valid = F.reference_expand_chunk(
-                gids, cumul, all_front, front_total, col_off, row_idx)
+                gids, total, table, row_idx)
             pay = all_payload[k]
         else:
             v, pay, addr, valid = expand_fn(gids, cumul, all_front,
@@ -318,7 +313,7 @@ def make_value_step(engine, graph, i, j, *, relax, edge_vals=None,
                         fill=expand_fill, ops=fold_ops)
                 cand, scanned = scan_relax(
                     graph.col_off, graph.row_idx, edge_vals, all_front,
-                    all_pay, ftot, relax, n_rows=nrl, grid=grid,
+                    all_pay, ftot, relax, n_rows=nrl,
                     edge_chunk=engine.edge_chunk,
                     expand_fn=engine.value_expand_fn)
         with jax.named_scope("repro/update"):

@@ -24,6 +24,11 @@ import jax.numpy as jnp
 from repro.core.types import Grid2D
 
 I32_MAX = jnp.int32(jnp.iinfo(jnp.int32).max)
+# `edge_slots`' tile of consecutive edge ids and cumul window
+TILE, WINDOW = 512, 256
+# values the top-down map picks per frontier slot: its column, its address
+# base (`frontier_workload`)
+SLOT_VALS = 2
 
 
 def exclusive_cumsum(x):
@@ -44,21 +49,33 @@ def pick_tile(e: int, tile: int) -> int:
     return t
 
 
-def map_workload_tile(gid, cumul, *, window: int, n_cumul: int):
+def map_workload_tile(gid, cumul, *, window: int, n_cumul: int,
+                      n_vals: int = 0):
     """k[t] = max { l : cumul[l] <= gid[t] } for ONE tile of consecutive edge
     ids, as dense vector work (the paper's thread->edge mapping, sec. 3.4).
 
     The ids of a tile are consecutive, so their k form a non-decreasing run
     [k0, k_last]: one scalar binary search finds k0, then W-wide windowed
     broadcast-compares count, per lane, the entries in (k0, ...] that are
-    <= gid.  The window loop runs ceil((k_last - k0 + 1) / W) times, so
+    <= gid.  The window loop runs ceil((k_last - k0) / W) times, so
     cumul must be CLIPPED by the caller (entries no live gid can reach set
     to I32_MAX) for the loop to stop after the live entries.
+
+    n_vals > 0: cumul is a `slot_table`, each entry followed by its n_vals
+    per-slot values.  A window pass then slices entries and values at once,
+    and the compares that count k also pick each lane's values at k.  The
+    result is (k, vals (n_vals, tile)).
 
     Operates on values (not refs): the body of the Pallas mapping kernels
     (`repro.kernels`) and, vmapped over tiles, of `edge_slots`."""
     g0 = gid[0]
     gmax = gid[-1]
+    stride = 1 + n_vals
+
+    def key(i):                         # cumul[i], a scalar slice
+        if n_vals:
+            i = i * stride
+        return jax.lax.dynamic_slice(cumul, (i,), (1,))[0]
 
     # --- 1. scalar binary search for k0 = max { l : cumul[l] <= g0 } ------
     def bcond(s):
@@ -68,7 +85,7 @@ def map_workload_tile(gid, cumul, *, window: int, n_cumul: int):
     def bbody(s):
         lo, hi = s
         mid = (lo + hi) // 2
-        cm = jax.lax.dynamic_slice(cumul, (mid,), (1,))[0]
+        cm = key(mid)
         lo2 = jnp.where(cm <= g0, mid, lo)
         hi2 = jnp.where(cm <= g0, hi, mid)
         return lo2, hi2
@@ -77,23 +94,48 @@ def map_workload_tile(gid, cumul, *, window: int, n_cumul: int):
         bcond, bbody, (jnp.int32(0), jnp.int32(n_cumul)))
 
     # --- 2. windowed broadcast-compare count over (k0, ...] ---------------
+    if n_vals:
+        vals = jnp.broadcast_to(jax.lax.dynamic_slice(
+            cumul, (k0 * stride + 1,), (n_vals,))[:, None],
+            (n_vals, gid.shape[0]))
+    else:
+        vals = ()
+
     def wcond(s):
-        start, _ = s
-        probe = jax.lax.dynamic_slice(
-            cumul, (jnp.minimum(start, n_cumul - 1),), (1,))[0]
+        start = s[0]
+        probe = key(jnp.minimum(start, n_cumul - 1))
         return (start < n_cumul) & (probe <= gmax)
 
     def wbody(s):
-        start, count = s
+        start, count, vals = s
         base = jnp.minimum(start, n_cumul - window)
-        win = jax.lax.dynamic_slice(cumul, (base,), (window,))
-        idx_ok = base + jax.lax.iota(jnp.int32, window) >= start
+        if n_vals:
+            wins = jax.lax.dynamic_slice(
+                cumul, (base * stride,), (window * stride,)).reshape(
+                    window, stride)
+            win = wins[:, 0]
+        else:
+            win = jax.lax.dynamic_slice(cumul, (base,), (window,))
+        pos = jax.lax.iota(jnp.int32, window)
+        idx_ok = base + pos >= start
         hits = (win[None, :] <= gid[:, None]) & idx_ok[None, :]
-        return start + window, count + jnp.sum(
-            hits, axis=1, dtype=jnp.int32)
+        nxt = start + window
+        n = jnp.sum(hits, axis=1, dtype=jnp.int32)
+        if n_vals:
+            # a lane's hits are one run from the window's first entry
+            # >= start (cumul is non-decreasing), so its last hit, the
+            # lane's slot so far, sits at window position first + n - 1
+            last = jnp.maximum(start - base, 0) + n - 1
+            pick = pos[None, :] == last[:, None]
+            got = jnp.sum(jnp.where(pick[None], wins.T[1:, None, :], 0),
+                          axis=2, dtype=jnp.int32)
+            vals = jnp.where(n[None, :] > 0, got, vals)
+        return nxt, count + n, vals
 
-    _, count = jax.lax.while_loop(
-        wcond, wbody, (k0 + 1, jnp.zeros_like(gid)))
+    _, count, vals = jax.lax.while_loop(
+        wcond, wbody, (k0 + 1, jnp.zeros_like(gid), vals))
+    if n_vals:
+        return k0 + count, vals
     return k0 + count
 
 
@@ -106,7 +148,19 @@ def clip_by_value(cumul, total):
     return jnp.where(cumul < total, cumul, I32_MAX)
 
 
-def edge_slots(cumul, gids, total, *, tile: int = 512, window: int = 256):
+def slot_table(cumul, total, payload):
+    """What `edge_slots` searches when it also picks per-slot values: each
+    entry of `clip_by_value(cumul, total)` followed by its P values
+    (payload (P, len(cumul))), flat, so a window pass slices entries and
+    values with one contiguous read.  Built once per level, outside the
+    chunk loop: a 2-D table costs the chip a relayout of all of it per
+    chunk, to lay each slot's values side by side for the window slices."""
+    rows = jnp.concatenate([clip_by_value(cumul, total)[None], payload])
+    return rows.T.reshape(-1)
+
+
+def edge_slots(cumul, gids, total, *, n_vals: int = 0, tile: int = TILE,
+               window: int = WINDOW):
     """k[t] = max { l : cumul[l] <= gids[t] } on every live lane
     (gids[t] < total) of a chunk of consecutive edge ids -- what
     `searchsorted(cumul, gids, side="right") - 1` gives there.  Dead lanes
@@ -118,19 +172,46 @@ def edge_slots(cumul, gids, total, *, tile: int = 512, window: int = 256):
     level's time (DESIGN.md sec. 9.4).  cumul is clipped with
     `clip_by_value`.
 
+    n_vals > 0: cumul is a `slot_table` (clipped already) of n_vals values
+    per slot, and the result is (k, vals) with vals[:, t] the values of
+    slot k[t], picked by the window loop's own compares.  On a TPU a
+    per-lane gather costs about the same per lane whatever its source's
+    size, so a caller that needs a slot's values takes them here
+    (DESIGN.md sec. 9.4).
+
     The window loop of a tile runs once per `window` cumul entries its ids
-    span, and under vmap every tile pays the widest tile's count.  A run of
-    zero-width entries inside a tile (zero-degree or visited rows in the
-    bottom-up masked cumsum) widens that span beyond the tile's length."""
+    span, and under vmap every tile pays the widest tile's count
+    (`slot_passes`).  A run of zero-width entries inside a tile
+    (zero-degree or visited rows in the bottom-up masked cumsum) widens
+    that span beyond the tile's length."""
     e = gids.shape[0]
-    n_cumul = cumul.shape[0]
+    n_cumul = cumul.shape[0] // (1 + n_vals)
     tile = pick_tile(e, tile)
     window = min(window, n_cumul)
+    if n_vals:
+        k, vals = jax.vmap(
+            lambda g: map_workload_tile(g, cumul, window=window,
+                                        n_cumul=n_cumul, n_vals=n_vals),
+            out_axes=(0, 1))(gids.reshape(e // tile, tile))
+        return k.reshape(e), vals.reshape(n_vals, e)
     cc = clip_by_value(cumul, total)
     k = jax.vmap(lambda g: map_workload_tile(g, cc, window=window,
                                              n_cumul=n_cumul))(
         gids.reshape(e // tile, tile))
     return k.reshape(e)
+
+
+def slot_passes(k, n_cumul: int, *, tile: int = TILE,
+                window: int = WINDOW):
+    """The window passes `edge_slots` ran for one chunk whose slots are k:
+    a tile's loop runs ceil((k_last - k_first) / window) times and vmap
+    runs the widest tile's count for every tile.  The cost of a chunk's
+    slot search, and of each value it picks, scales with it."""
+    e = k.shape[0]
+    tile = pick_tile(e, tile)
+    window = min(window, n_cumul)
+    kt = k.reshape(e // tile, tile)
+    return jnp.max((kt[:, -1] - kt[:, 0] + window - 1) // window)
 
 
 def compact_blocks(vals, cnts, fill=-1, ops=None):
@@ -234,8 +315,30 @@ def unpack_bitmap(words, S: int):
     return bits.reshape(words.shape[:-1] + (-1,))[..., :S].astype(bool)
 
 
-def reference_expand_chunk(gids, cumul, all_front, front_total, col_off,
-                           row_idx):
+def frontier_workload(all_front, front_total, col_off):
+    """Per level, dense over the ncl gathered frontier slots: the edge
+    workload the chunk scan walks and what each slot gives its edges.
+
+    Returns (cumul, total, table): cumul the exclusive cumsum of the
+    frontier columns' degrees (ncl + 1), total = cumul[front_total] the
+    level's edge count, and table the `slot_table` that
+    `reference_expand_chunk` searches: beside each clipped cumul entry k,
+    the SLOT_VALS values of slot k, its column u and its address base
+    col_off[u] - cumul[k] (at k = ncl the last slot's again: slots are
+    clipped to ncl - 1)."""
+    ncl = all_front.shape[0]
+    u_safe = jnp.clip(all_front, 0, ncl - 1)
+    off = col_off[u_safe]
+    deg = col_off[u_safe + 1] - off
+    deg = jnp.where(jnp.arange(ncl) < front_total, deg, 0)
+    cumul = exclusive_cumsum(deg)                      # (ncl + 1,)
+    total = cumul[front_total]
+    vals = jnp.stack([u_safe, off - cumul[:-1]])
+    vals = jnp.concatenate([vals, vals[:, -1:]], axis=1)
+    return cumul, total, slot_table(cumul, total, vals)
+
+
+def reference_expand_chunk(gids, total, table, row_idx):
     """One chunk of the paper's column scan in plain jnp -- THE reference
     map/gather formulas, single source of truth.  Shared by
     `expand_frontier`'s inline path, `repro.algos.program.scan_relax` and
@@ -243,16 +346,21 @@ def reference_expand_chunk(gids, cumul, all_front, front_total, col_off,
     kernel mirrors these formulas lane for lane (the bit-identity contract,
     DESIGN.md sec. 9) -- edit them HERE or the paths diverge.
 
+    total, table: the level's `frontier_workload`.  The slot search picks
+    each lane's frontier column u and address base along with its slot k,
+    so the CSC row id v = row_idx[base + gid] is the one per-lane gather:
+    u = front[k] and addr = col_off[u] + gid - cumul[k], as the kernel
+    computes them.
+
     Returns (v, u, k, addr, valid): candidate local rows (masked lanes
     -> 0), parent frontier cols, frontier slot index, clipped CSC edge
     address, live-lane mask.
     """
-    ncl = all_front.shape[0]
+    ncl = table.shape[0] // (1 + SLOT_VALS) - 1
     nnz_cap = row_idx.shape[0]
-    total = cumul[front_total]
-    k = jnp.clip(edge_slots(cumul, gids, total), 0, ncl - 1)
-    u = jnp.clip(all_front, 0, ncl - 1)[k]
-    addr = jnp.clip(col_off[u] + gids - cumul[k], 0, nnz_cap - 1)
+    k, (u, base) = edge_slots(table, gids, total, n_vals=SLOT_VALS)
+    k = jnp.clip(k, 0, ncl - 1)
+    addr = jnp.clip(base + gids, 0, nnz_cap - 1)
     valid = gids < total
     v = jnp.where(valid, row_idx[addr], 0)
     return v, u, k, addr, valid
@@ -334,12 +442,16 @@ class ExpandResult(NamedTuple):
     dst_cnt: jax.Array    # (C,)
     edges_scanned: jax.Array  # uint32 -- callers accumulate across levels
                               # with engine.wide_add (int32 wraps at scale 26)
+    map_passes: jax.Array     # int32 slot-search window passes of the level
+                              # (`slot_passes` summed over chunks), 0 unless
+                              # counted
 
 
 def expand_frontier(col_off, row_idx, visited, level, pred, all_front,
                     front_total, lvl, *, grid: Grid2D, i, j,
                     edge_chunk: int = 8192, expand_fn=None,
-                    dedup: str = "scatter") -> ExpandResult:
+                    dedup: str = "scatter",
+                    count_passes: bool = False) -> ExpandResult:
     """Scan the CSC columns of the gathered frontier (paper Alg. 3).
 
     all_front: (n_cols_local,) local col indices (valid first `front_total`).
@@ -350,34 +462,37 @@ def expand_frontier(col_off, row_idx, visited, level, pred, all_front,
         closure carrying `accepts_words = True` additionally receives
         `words=` -- the packed visited bitmap this loop then maintains
         INCREMENTALLY (one O(n_rows) pack per level instead of per chunk).
+    count_passes: static; count the reference map's window passes into
+        `map_passes` (the telemetry channel).  Off, the program holds no
+        counter; a kernel's passes (expand_fn) are not counted.
     """
     n_rows = visited.shape[0]
     S, C = grid.S, grid.C
     ncl = grid.n_cols_local
 
-    u_safe = jnp.clip(all_front, 0, ncl - 1)
-    deg = (col_off[u_safe + 1] - col_off[u_safe])
-    deg = jnp.where(jnp.arange(ncl) < front_total, deg, 0)
-    cumul = exclusive_cumsum(deg)                      # (ncl + 1,)
-    total = cumul[front_total]
+    cumul, total, table = frontier_workload(all_front, front_total,
+                                            col_off)
 
     dst = jnp.full((C, S), -1, jnp.int32)
     dst_cnt = jnp.zeros((C,), jnp.int32)
     use_words = bool(getattr(expand_fn, "accepts_words", False))
     words = pack_bitmap(visited) if use_words \
         else jnp.zeros((1,), jnp.uint32)               # pytree placeholder
+    count_passes = count_passes and expand_fn is None
 
     # The chunk body's three parts run under their own scopes, nested in
     # the caller's layer scope (DESIGN.md sec. 13.4): `map` (edge slot ->
     # frontier column -> row id), `filter` (visited test and first-claimant
     # selection), `mark` (visited / pred / level scatters, bucket append).
     def chunk_body(state):
-        start, visited, words, level, pred, dst, dst_cnt = state
+        start, visited, words, level, pred, dst, dst_cnt = state[:7]
         gids = start + jnp.arange(edge_chunk, dtype=jnp.int32)
         with jax.named_scope("map"):
             if expand_fn is None:
-                v, u, _, _, valid = reference_expand_chunk(
-                    gids, cumul, all_front, front_total, col_off, row_idx)
+                v, u, k, _, valid = reference_expand_chunk(
+                    gids, total, table, row_idx)
+                if count_passes:
+                    passes = state[7] + slot_passes(k, cumul.shape[0])
             elif use_words:
                 v, unvis, u = expand_fn(gids, cumul, all_front, front_total,
                                         col_off, row_idx, visited,
@@ -408,18 +523,22 @@ def expand_frontier(col_off, row_idx, visited, level, pred, all_front,
             level = level.at[jnp.where(is_local, v, n_rows)].set(
                 jnp.where(is_local, lvl, 0), mode="drop")
             dst, dst_cnt = bucket_append(dst, dst_cnt, v, m, win, C)
-        return start + edge_chunk, visited, words, level, pred, dst, dst_cnt
+        out = (start + edge_chunk, visited, words, level, pred, dst, dst_cnt)
+        return out + (passes,) if count_passes else out
 
     def chunk_cond(state):
         return state[0] < total
 
     init = (jnp.int32(0), visited, words, level, pred, dst, dst_cnt)
-    _, visited, _, level, pred, dst, dst_cnt = jax.lax.while_loop(
-        chunk_cond, chunk_body, init)
+    if count_passes:
+        init += (jnp.int32(0),)
+    out = jax.lax.while_loop(chunk_cond, chunk_body, init)
+    _, visited, _, level, pred, dst, dst_cnt = out[:7]
     # per-level count reported unsigned: one level's local scan is bounded by
     # the int32-indexable local nnz, but the SUM across levels/devices is not
     return ExpandResult(visited, level, pred, dst, dst_cnt,
-                        total.astype(jnp.uint32))
+                        total.astype(jnp.uint32),
+                        out[7] if count_passes else jnp.int32(0))
 
 
 class UpdateResult(NamedTuple):

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.frontier import (I32_MAX, exclusive_cumsum,
+from repro.core.frontier import (I32_MAX, frontier_workload,
                                  map_workload_tile, pack_bitmap, pick_tile,
                                  reference_expand_chunk, set_bits,
                                  winner_dedup)
@@ -275,12 +275,7 @@ def _local_expand(front, front_total, col_off, row_idx, visited, *,
                   path: str, edge_chunk: int, tile: int, window: int,
                   dedup: str) -> LocalExpandOut:
     n_rows = visited.shape[0]
-    ncl = col_off.shape[0] - 1
-    u_safe = jnp.clip(front, 0, ncl - 1)
-    deg = col_off[u_safe + 1] - col_off[u_safe]
-    deg = jnp.where(jnp.arange(ncl) < front_total, deg, 0)
-    cumul = exclusive_cumsum(deg)
-    total = cumul[front_total]
+    cumul, total, table = frontier_workload(front, front_total, col_off)
     words = pack_bitmap(visited) if path != "reference" \
         else jnp.zeros((1,), jnp.uint32)               # pytree placeholder
 
@@ -291,7 +286,7 @@ def _local_expand(front, front_total, col_off, row_idx, visited, *,
             # exactly expand_frontier's inline jnp formulas (one source of
             # truth: repro.core.frontier.reference_expand_chunk)
             v, u, _, _, valid = reference_expand_chunk(
-                gids, cumul, front, front_total, col_off, row_idx)
+                gids, total, table, row_idx)
             elig = valid & ~visited[v]
         else:
             v, elig, u = expand_chunk(

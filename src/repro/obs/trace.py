@@ -23,6 +23,10 @@ Per level, per device, the carry records:
   msgs        point-to-point fold messages this device sent (the exchange
               strategy's `msgs_per_exchange`: C-1 flat, log2(C) butterfly)
   dir         direction the level ran (0 top-down / 1 bottom-up)
+  map_passes  window passes of the top-down map's slot search this level
+              on this device (`frontier.slot_passes` summed over chunks;
+              0 on bottom-up levels, in value programs and on a kernel
+              path): what picking slot values in the search costs
 
 The stamps are work counters, not wall times: inside one compiled program
 there is no host clock, and counters are what the paper's Fig. 5/6 plot
@@ -40,7 +44,7 @@ import numpy as np
 # Channel order of the trace arrays the engine appends after (hi, lo);
 # plus one trailing per-device level counter `k`.
 TRACE_CHANNELS = ("frontier", "front_dev", "scanned", "folded", "wire",
-                  "msgs", "dir")
+                  "msgs", "dir", "map_passes")
 N_TRACE_OUTS = len(TRACE_CHANNELS) + 1
 
 
@@ -61,6 +65,7 @@ def init_trace(max_levels: int) -> dict:
         "wire": jnp.zeros((L,), jnp.uint32),
         "msgs": jnp.zeros((L,), jnp.int32),
         "dir": jnp.full((L,), -1, jnp.int32),
+        "map_passes": jnp.zeros((L,), jnp.int32),
         "k": jnp.int32(0),
     }
 
@@ -74,6 +79,7 @@ def normalize_aux(aux: "dict | None") -> dict:
         "wire": jnp.asarray(aux.get("wire", 0), jnp.uint32),
         "msgs": jnp.asarray(aux.get("msgs", 0), jnp.int32),
         "dir": jnp.asarray(aux.get("dir", 0), jnp.int32),
+        "map_passes": jnp.asarray(aux.get("map_passes", 0), jnp.int32),
     }
 
 
@@ -93,6 +99,7 @@ def record_level(tr: dict, *, frontier, front_dev, scanned, aux) -> dict:
         "wire": tr["wire"].at[k].set(aux["wire"]),
         "msgs": tr["msgs"].at[k].set(aux["msgs"]),
         "dir": tr["dir"].at[k].set(aux["dir"]),
+        "map_passes": tr["map_passes"].at[k].set(aux["map_passes"]),
         "k": tr["k"] + 1,
     }
 
@@ -128,6 +135,8 @@ class LevelTrace:
     msgs: np.ndarray            # (n_levels,) int64 global fold messages sent
     msgs_dev: np.ndarray
     direction: np.ndarray       # (n_levels,) int32: 0 top-down / 1 bottom-up
+    map_passes: np.ndarray      # (n_levels,) int64 global map window passes
+    map_passes_dev: np.ndarray
 
     @property
     def total_wire_bytes(self) -> int:
@@ -149,7 +158,8 @@ class LevelTrace:
              "folded": int(self.folded[k]),
              "wire_bytes": int(self.wire_bytes[k]),
              "msgs": int(self.msgs[k]),
-             "dir": int(self.direction[k])}
+             "dir": int(self.direction[k]),
+             "map_passes": int(self.map_passes[k])}
             for k in range(self.n_levels)]
 
     def to_dict(self) -> dict:
@@ -167,6 +177,7 @@ def _one_trace(chans, k, *, grid, program, codec) -> LevelTrace:
     c_dev = chans["folded"][:, :n].astype(i64)
     w_dev = chans["wire"][:, :n].astype(i64)
     m_dev = chans["msgs"][:, :n].astype(i64)
+    p_dev = chans["map_passes"][:, :n].astype(i64)
     return LevelTrace(
         program=program, codec=codec, grid=(grid.R, grid.C), n_levels=n,
         frontier=chans["frontier"][0, :n].astype(i64), frontier_dev=f_dev,
@@ -174,7 +185,8 @@ def _one_trace(chans, k, *, grid, program, codec) -> LevelTrace:
         folded=c_dev.sum(axis=0), folded_dev=c_dev,
         wire_bytes=w_dev.sum(axis=0), wire_dev=w_dev,
         msgs=m_dev.sum(axis=0), msgs_dev=m_dev,
-        direction=np.asarray(chans["dir"][0, :n], np.int32))
+        direction=np.asarray(chans["dir"][0, :n], np.int32),
+        map_passes=p_dev.sum(axis=0), map_passes_dev=p_dev)
 
 
 def assemble_traces(traw, B, *, grid, program: str, codec: str):

@@ -13,6 +13,7 @@
   * `import repro.kernels` stays lazy (no Pallas modules loaded until a
     kernel symbol is touched).
 """
+import functools
 import os
 import subprocess
 import sys
@@ -274,14 +275,20 @@ def test_pick_tile_always_divides_chunk():
     assert pick_tile(100_000, 512) == 500
 
 
-@pytest.mark.parametrize("e", [100, 512, 8192])
-def test_edge_slots_match_searchsorted(e, rng):
+@pytest.mark.parametrize("n_vals", [0, 2], ids=["search", "picks"])
+@pytest.mark.parametrize("e,tile,window", [(100, 512, 256), (512, 512, 256),
+                                           (8192, 512, 256), (96, 32, 24)])
+def test_edge_slots_match_searchsorted(e, tile, window, n_vals, rng):
     """The reference path's tiled slot search gives exactly the per-lane
     `searchsorted` slot on every live lane, through zero-degree runs (2D
-    blocks, visited rows) and a dead tail (the last chunk of a level)."""
-    from repro.core.frontier import edge_slots, exclusive_cumsum
+    blocks, visited rows) and a dead tail (the last chunk of a level).
+    Searching a `slot_table`, it also picks each live lane's payload at
+    that slot; the small tile and window put spans wider than the tile
+    and windows that start anywhere, the last one clipped to cumul's end."""
+    from repro.core.frontier import edge_slots, exclusive_cumsum, slot_table
 
-    slots = jax.jit(edge_slots)
+    slots = jax.jit(functools.partial(edge_slots, n_vals=n_vals, tile=tile,
+                                      window=window))
     for _ in range(20):
         n = int(rng.integers(1, 3000))
         deg = rng.integers(0, 6, n) * (rng.random(n) < rng.random())
@@ -290,12 +297,109 @@ def test_edge_slots_match_searchsorted(e, rng):
         total = int(cumul[-1])
         gids = int(rng.integers(0, max(total, 1))) + np.arange(
             e, dtype=np.int32)
-        k = np.asarray(slots(jnp.asarray(cumul), jnp.asarray(gids),
-                             jnp.int32(total)))
         live = gids < total
         want = np.searchsorted(cumul, gids, side="right") - 1
+        if n_vals:
+            payload = rng.integers(-2**31, 2**31, (n_vals, n + 1),
+                                   dtype=np.int64).astype(np.int32)
+            k, vals = slots(slot_table(jnp.asarray(cumul), jnp.int32(total),
+                                       jnp.asarray(payload)),
+                            jnp.asarray(gids), jnp.int32(total))
+            np.testing.assert_array_equal(np.asarray(vals)[:, live],
+                                          payload[:, want[live]])
+        else:
+            k = slots(jnp.asarray(cumul), jnp.asarray(gids),
+                      jnp.int32(total))
+        k = np.asarray(k)
         np.testing.assert_array_equal(k[live], want[live])
         assert ((k >= 0) & (k < cumul.shape[0])).all()
+
+
+def four_gather_chunk(gids, cumul, all_front, front_total, col_off,
+                      row_idx):
+    """The oracle: the chunk map as four per-lane gathers (front[k],
+    col_off[u], cumul[k], row_idx[addr]) after a bare slot search."""
+    from repro.core.frontier import edge_slots
+
+    ncl = all_front.shape[0]
+    nnz_cap = row_idx.shape[0]
+    total = cumul[front_total]
+    k = jnp.clip(edge_slots(cumul, gids, total), 0, ncl - 1)
+    u = jnp.clip(all_front, 0, ncl - 1)[k]
+    addr = jnp.clip(col_off[u] + gids - cumul[k], 0, nnz_cap - 1)
+    valid = gids < total
+    v = jnp.where(valid, row_idx[addr], 0)
+    return v, u, k, addr, valid
+
+
+def _random_block(rng, ncl, n_rows):
+    """A CSC block with runs of zero-degree columns and a random frontier
+    (ascending, as the engines keep it, padded -1)."""
+    deg = rng.integers(0, 9, ncl) * (rng.random(ncl) < 0.6)
+    deg[rng.integers(0, ncl, 3)] = 0
+    col_off = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    row_idx = rng.integers(0, n_rows, max(int(col_off[-1]), 1)) \
+        .astype(np.int32)
+    cnt = int(rng.integers(1, ncl + 1))
+    front = np.full(ncl, -1, np.int32)
+    front[:cnt] = np.sort(rng.choice(ncl, cnt, replace=False))
+    return col_off, row_idx, front, cnt
+
+
+def test_expand_chunk_matches_four_gathers(rng):
+    """`reference_expand_chunk`, its slot values picked in the slot search,
+    is bit-identical to the four-gather oracle on every lane of every
+    chunk of a level, the dead tail included."""
+    from repro.core import frontier as F
+
+    ref = jax.jit(F.reference_expand_chunk)
+    oracle = jax.jit(four_gather_chunk)
+    for ncl in (40, 700, 3000):
+        col_off, row_idx, front, cnt = _random_block(rng, ncl, 500)
+        cumul, total, table = F.frontier_workload(
+            jnp.asarray(front), jnp.int32(cnt), jnp.asarray(col_off))
+        for start in range(0, int(total), 512):
+            gids = start + jnp.arange(512, dtype=jnp.int32)
+            got = ref(gids, total, table, jnp.asarray(row_idx))
+            want = oracle(gids, cumul, jnp.asarray(front), jnp.int32(cnt),
+                          jnp.asarray(col_off), jnp.asarray(row_idx))
+            for name, a, b in zip(("v", "u", "k", "addr", "valid"), got,
+                                  want):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=name)
+
+
+def test_expand_frontier_level_matches_four_gathers(rng, monkeypatch):
+    """One `expand_frontier` level gives the same visited / level / pred /
+    dst as the same level with the four-gather oracle as its map."""
+    from repro.core import frontier as F
+    from repro.core.types import Grid2D
+
+    grid = Grid2D.for_vertices(4096, 2, 2)
+    ncl, n_rows = grid.n_cols_local, grid.n_rows_local
+    col_off, row_idx, front, cnt = _random_block(rng, ncl, n_rows)
+    visited = rng.random(n_rows) < 0.2
+    level = np.where(visited, 1, -1).astype(np.int32)
+    pred = np.where(visited, 7, -1).astype(np.int32)
+    args = (jnp.asarray(col_off), jnp.asarray(row_idx), jnp.asarray(visited),
+            jnp.asarray(level), jnp.asarray(pred), jnp.asarray(front),
+            jnp.int32(cnt), jnp.int32(2))
+
+    def level_out():
+        return F.expand_frontier(*args, grid=grid, i=1, j=0, edge_chunk=256)
+
+    got = level_out()
+    cumul = F.frontier_workload(args[5], args[6], args[0])[0]
+    monkeypatch.setattr(
+        F, "reference_expand_chunk",
+        lambda gids, total, table, row_idx: four_gather_chunk(
+            gids, cumul, args[5], args[6], args[0], row_idx))
+    want = level_out()
+    assert int(got.edges_scanned) == int(cumul[cnt]) > 0
+    for name in ("visited", "level", "pred", "dst", "dst_cnt"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                      np.asarray(getattr(want, name)),
+                                      err_msg=name)
 
 
 def test_algo_engines_honor_custom_expand_fn(graphs):

@@ -17,6 +17,7 @@ import json
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -211,6 +212,106 @@ def test_direction_trace_matches_directions_output(graph_data):
     dirs = np.asarray(out.directions)
     assert [int(d) for d in tr.direction] == \
         [int(d) for d in dirs[:tr.n_levels]]
+
+
+def _np_map_passes(front, col_off, ncl, chunk):
+    """One top-down level's slot-search window passes, counted by running
+    the window loop of each tile in numpy: per chunk the widest tile's
+    count (what the vmapped loop runs), summed over the level's chunks."""
+    from repro.core.frontier import TILE, WINDOW, pick_tile
+
+    deg = col_off[front + 1] - col_off[front]
+    cumul = np.zeros(ncl + 1, np.int64)
+    cumul[1:len(front) + 1] = np.cumsum(deg)
+    cumul[len(front) + 1:] = cumul[len(front)]
+    total = int(cumul[-1])
+    cc = np.where(cumul < total, cumul, np.iinfo(np.int32).max)
+    tile, window = pick_tile(chunk, TILE), min(WINDOW, ncl + 1)
+    passes = 0
+    for start in range(0, total, chunk):
+        widest = 0
+        for g0 in range(start, start + chunk, tile):
+            k0 = max(int(np.searchsorted(cc, g0, side="right")) - 1, 0)
+            at, n = k0 + 1, 0
+            while at < ncl + 1 and cc[at] <= g0 + tile - 1:
+                at, n = at + window, n + 1
+            widest = max(widest, n)
+        passes += widest
+    return passes
+
+
+@pytest.mark.parametrize("direction", [False, True],
+                         ids=["topdown", "direction"])
+def test_map_passes_counts_the_slot_search(direction):
+    """`map_passes` is the top-down map's window passes per level, as a
+    numpy run of the window loop counts them, 0 on bottom-up levels; with
+    telemetry off the level's chunk loop carries no counter."""
+    from repro.core import frontier as F
+    from repro.core.types import Grid2D
+    from repro.graphgen import rmat_edges
+
+    scale, chunk = 10, 512
+    edges = np.asarray(rmat_edges(jax.random.key(4), scale, 4))
+    cfg = BFSConfig(grid=(1, 1), edge_chunk=chunk, telemetry=True,
+                    direction=direction)
+    g = DistGraph.from_edges(edges, cfg, n=1 << scale)
+    root = int(np.flatnonzero(np.bincount(edges[0]) > 0)[0])
+    out = g.session(cfg).bfs(root)
+    tr = out.trace
+    col_off = np.asarray(g.csc.col_off)[0, 0]
+    ncl = g.grid.n_cols_local
+    level = np.asarray(out.level)
+    want = [0 if d else _np_map_passes(np.flatnonzero(level == k), col_off,
+                                       ncl, chunk)
+            for k, d in enumerate(tr.direction)]
+    assert [int(p) for p in tr.map_passes] == want
+    if direction:
+        assert tr.direction.any()           # some levels ran bottom-up
+    else:
+        assert max(want) >= 2               # a chunk took two passes
+    assert tr.levels()[1]["map_passes"] == want[1]
+
+    grid = Grid2D.for_vertices(64, 1, 1)
+    args = (jnp.zeros(65, jnp.int32), jnp.zeros(8, jnp.int32),
+            jnp.zeros(64, bool), jnp.zeros(64, jnp.int32),
+            jnp.zeros(64, jnp.int32), jnp.zeros(64, jnp.int32),
+            jnp.int32(1), jnp.int32(1))
+
+    def carry(count):
+        jaxpr = jax.make_jaxpr(lambda *a: F.expand_frontier(
+            *a, grid=grid, i=0, j=0, edge_chunk=64,
+            count_passes=count))(*args)
+        loop, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "while"]
+        return len(loop.outvars)
+
+    assert carry(True) == carry(False) + 1
+
+
+@pytest.mark.parametrize("src_grid", [(1, 1), (2, 2)],
+                         ids=["same_grid", "regrid"])
+def test_import_trace_blanks_channels_the_snapshot_lacks(graph_data,
+                                                         src_grid):
+    """A telemetry snapshot written before `map_passes` existed resumes:
+    the channel starts at zero, the others import as before (same grid:
+    as saved; a smaller grid: work sums on device (0, 0))."""
+    from repro.obs import TRACE_CHANNELS
+
+    g, _ = graph_data
+    eng = g.engine_for(_cfg())
+    L = int(eng.max_levels)
+    rng = np.random.default_rng(0)
+    old = {c: rng.integers(0, 9, src_grid + (L,)).astype(
+               np.uint32 if c in ("scanned", "wire") else np.int32)
+           for c in TRACE_CHANNELS if c != "map_passes"}
+    old["k"] = np.full(src_grid, 3, np.int32)
+    tr = eng._import_trace(old, None, 3)
+    assert set(tr) == set(TRACE_CHANNELS) | {"k"}
+    assert tr["map_passes"].shape == (1, 1, L)
+    assert tr["map_passes"].dtype == np.int32 and not tr["map_passes"].any()
+    np.testing.assert_array_equal(tr["frontier"][0, 0], old["frontier"][0, 0])
+    np.testing.assert_array_equal(
+        tr["scanned"][0, 0], old["scanned"].sum(axis=(0, 1), dtype=np.int64))
+    assert int(tr["k"][0, 0]) == 3
 
 
 def test_value_fold_traces_for_algos(graph_data):

@@ -14,6 +14,7 @@ around these compiles: a compile for a described chip is written to it but
 cannot be read back without one.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -82,16 +83,45 @@ def compile_batch(topo, R, C, *, scale, e_max, direction=False,
     return config, engine._run_batch.lower(*args, roots).compile()
 
 
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """{direction: compiled 1x1 program at the chip smoke's shapes}, each
+    compiled once for the tests below."""
+    return {d: compile_batch(topo, 1, 1, scale=SMOKE_SCALE,
+                             e_max=SMOKE_EDGES, direction=d)[1]
+            for d in (False, True)}
+
+
 @pytest.mark.parametrize("direction", [False, True],
                          ids=["topdown", "direction"])
-def test_one_chip_smoke_shapes_compile(topo, direction):
-    _, compiled = compile_batch(topo, 1, 1, scale=SMOKE_SCALE,
-                                e_max=SMOKE_EDGES, direction=direction)
+def test_one_chip_smoke_shapes_compile(one_chip, direction):
+    compiled = one_chip[direction]
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert need < V5E_HBM, mem
     assert "tpu_custom_call" not in compiled.as_text()   # no Pallas kernel
+
+
+_GATHER = re.compile(r"=\s*\w+\[(\d+)\][^=]*\sgather\(.*op_name=\"([^\"]*)\"")
+
+
+def lane_gathers(hlo: str, scope: str) -> int:
+    """Gathers of one value per lane of a chunk under `scope`, outside the
+    slot search's vmapped tile loop."""
+    return sum(1 for m in map(_GATHER.search, hlo.splitlines())
+               if m and int(m.group(1)) == EDGE_CHUNK
+               and scope in m.group(2) and "vmap()" not in m.group(2))
+
+
+def test_one_chip_map_gathers_one_value_per_lane(one_chip):
+    """The top-down map picks each lane's frontier column and address base
+    in the slot search: row_idx[addr] is its one per-lane gather.  The
+    bottom-up scan's four (row_off[r], cumul[r], col_idx[addr], the
+    frontier bit) are untouched."""
+    topdown, direction = (one_chip[d].as_text() for d in (False, True))
+    assert lane_gathers(topdown, "repro/expand/while/body/map/") == 1
+    assert lane_gathers(direction, "repro/expand/bottomup/") == 4
 
 
 def test_2x2_flat_folds_with_all_to_all(topo):
