@@ -44,9 +44,8 @@ import jax.numpy as jnp
 from repro.algos import program as PR
 from repro.algos.program import FrontierProgram, I32_MAX
 from repro.core import frontier as F
-
-
 from repro.core.types import _dc
+from repro.obs.trace import RECORDED_LEVELS
 
 
 # ----------------------------------------------------------------------------
@@ -59,7 +58,8 @@ class DirState:
     """Wrapped program state + per-level direction bookkeeping."""
     inner: Any            # the wrapped program's state pytree
     dir: jax.Array        # () int32: 1 while running bottom-up (hysteresis)
-    dirs: jax.Array       # (max_levels,) int32: -1 unused / 0 TD / 1 BU
+    dirs: jax.Array       # (RECORDED_LEVELS,) int32: -1 unused / 0 TD /
+                          # 1 BU; levels past it fold into the last slot
     k: jax.Array          # () int32 0-based level counter
 
 
@@ -268,8 +268,9 @@ class DirectionProgram(FrontierProgram):
     beta:  leave it once the frontier falls below n / beta (beta > alpha).
 
     Outputs are the wrapped program's, bit-identical to its pure top-down
-    run, plus a `directions` trace ((max_levels,) int32 per search: -1
-    unused level / 0 top-down / 1 bottom-up).
+    run, plus a `directions` trace ((RECORDED_LEVELS,) int32 per search:
+    -1 unused level / 0 top-down / 1 bottom-up; whatever the loop's bound,
+    levels past the record fold into its last slot).
     """
     uses_bottomup = True
 
@@ -295,7 +296,7 @@ class DirectionProgram(FrontierProgram):
     def init(self, engine, graph, extra, arg, i, j):
         inner_st = self.inner.init(engine, graph,
                                    extra[:self.inner.n_extra], arg, i, j)
-        dirs = jnp.full((engine.max_levels,), -1, jnp.int32)
+        dirs = jnp.full((RECORDED_LEVELS,), -1, jnp.int32)
         return DirState(inner=inner_st, dir=jnp.int32(0), dirs=dirs,
                         k=jnp.int32(0))
 
@@ -304,7 +305,7 @@ class DirectionProgram(FrontierProgram):
                                   extra[:self.inner.n_extra], i, j)
         bu = self.inner.make_bottomup_step(engine, graph, extra, i, j)
         n = engine.grid.n
-        L = engine.max_levels
+        L = RECORDED_LEVELS
         hi_thr = jnp.int32(n // self.alpha)   # enter bottom-up above this
         lo_thr = jnp.int32(n // self.beta)    # leave it below this
 
@@ -367,7 +368,7 @@ class DirectionProgram(FrontierProgram):
         import numpy as np
 
         grid = engine.grid
-        R, C, L = grid.R, grid.C, engine.max_levels
+        R, C, L = grid.R, grid.C, RECORDED_LEVELS
         dirs = np.full((L,), -1, np.int32)
         src = np.asarray(snap["dirs"], np.int32)
         m = min(L, src.shape[0])
@@ -383,7 +384,7 @@ class DirectionProgram(FrontierProgram):
         # trace sits third from the end
         inner_outs = tuple(outs[:-3]) + tuple(outs[-2:])
         out = self.inner.assemble(engine, inner_outs, B)
-        L = engine.max_levels
+        L = RECORDED_LEVELS
         dirs = outs[-3]
         # every device records the identical (psum-replicated) decision
         directions = dirs.reshape(-1, L)[0] if B is None \

@@ -63,7 +63,11 @@ class FrontierEngine:
     fold_codec: "list" | "bitmap" | "delta" | FoldCodec instance | None
                 (None defers to `program.codec_hint`).
     edge_chunk: CSC scan chunk size of the expand phase.
-    max_levels: loop bound fed to `program.keep_going`.
+    max_levels: loop bound fed to `program.keep_going`; None bounds it by
+                the grid's n + 1, which no search reaches, so the loop
+                runs to completion.  The per-level records (telemetry,
+                directions) hold `repro.obs.trace.RECORDED_LEVELS` levels
+                whatever the bound.
     expand:     local-expand implementation: "reference" | "pallas" |
                 "pallas-interpret" | "auto" (DESIGN.md sec. 9).  "auto"
                 picks Pallas on GPU/TPU, reference on CPU, and honors
@@ -113,7 +117,7 @@ class FrontierEngine:
     """
 
     def __init__(self, topo, program, *, fold_codec=None,
-                 edge_chunk: int = 8192, max_levels: int = 64,
+                 edge_chunk: int = 8192, max_levels: int | None = None,
                  expand: str = "auto", expand_fn=None, fold: str = "auto",
                  dedup: str = "scatter", bottomup: str = "auto",
                  exchange="flat", telemetry: bool = False,
@@ -134,7 +138,8 @@ class FrontierEngine:
         self.grid = topo.grid
         self.program = program
         self.edge_chunk = edge_chunk
-        self.max_levels = max_levels
+        self.max_levels = self.grid.n + 1 if max_levels is None \
+            else int(max_levels)
         self.expand = expand
         self.fold = fold
         self.fold_path = resolve_fold_path(fold)
@@ -257,7 +262,7 @@ class FrontierEngine:
                 with jax.named_scope("repro/loop"):
                     carry = (st, init_total, jnp.uint32(0), jnp.uint32(0))
                     if telemetry:
-                        carry += (T.init_trace(self.max_levels),)
+                        carry += (T.init_trace(),)
                 carry = jax.lax.while_loop(cond, body, carry)
                 st, hi, lo = carry[0], carry[2], carry[3]
                 with jax.named_scope("repro/finalize"):
@@ -375,7 +380,7 @@ class FrontierEngine:
                          "hi": jnp.uint32(0), "lo": jnp.uint32(0),
                          "active": prog.keep_going(self, st, total)}
                 if telemetry:
-                    carry["trace"] = T.init_trace(self.max_levels)
+                    carry["trace"] = T.init_trace()
                 return carry
 
             carry = jax.lax.map(one, arg) if batched else one(arg)
@@ -584,7 +589,7 @@ class FrontierEngine:
 
         R, C = self.grid.R, self.grid.C
         shp = (R, C) + (() if B is None else (B,))
-        L = int(self.max_levels)
+        L = T.RECORDED_LEVELS
         if traw is None:
             # resuming a snapshot taken without telemetry: blank history,
             # k advanced so post-resume levels land in the right slots

@@ -40,7 +40,15 @@ class BFSConfig:
                  wire format (DESIGN.md sec. 4).
     edge_chunk:  CSC scan chunk size of the expand phase.
     dedup:       winner-selection method ("scatter" | "sort").
-    max_levels:  level-loop bound.
+    max_levels:  level-loop bound.  None (the default) searches to
+                 completion: the engine bounds the loop by the planned
+                 grid's n + 1, which no search reaches, so every level and
+                 parent is exact on a graph of any depth.  An int stops
+                 the search after that many levels (deeper vertices stay
+                 at level -1).  Either way the per-level records -- the
+                 `LevelTrace` channels and the direction program's
+                 `directions` -- hold `repro.obs.trace.RECORDED_LEVELS`
+                 (64) levels; levels past it fold into the last slot.
     direction:   Beamer direction optimisation.  False = pure top-down;
                  True or "adaptive" = per-level alpha/beta switch inside the
                  compiled loop; "bottomup" = every level bottom-up (the
@@ -105,7 +113,7 @@ class BFSConfig:
     fold_codec: Any = "list"
     edge_chunk: int = 8192
     dedup: str = "scatter"
-    max_levels: int = 64
+    max_levels: int | None = None
     direction: Any = False
     alpha: int = 24
     beta: int = 64
@@ -209,7 +217,7 @@ class BFSConfig:
                 self.fault_tolerance, self.ckpt_every)
 
     def algo_engine_key(self, program_key: tuple, codec_name: str,
-                        max_levels: int) -> tuple:
+                        max_levels: int | None) -> tuple:
         """Cache key for a non-BFS frontier-program engine (DESIGN.md
         sec. 8): the program's identity plus the config knobs the engine
         bakes in.  `codec_name`/`max_levels` are per-call (the program's

@@ -34,7 +34,7 @@ class BFS1D:
     """
 
     def __init__(self, n: int, mesh, axes=("p",), edge_chunk: int = 8192,
-                 max_levels: int = 64, fold_codec="list"):
+                 max_levels: int | None = None, fold_codec="list"):
         import warnings
 
         from repro.api.config import BFSConfig

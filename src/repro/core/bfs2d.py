@@ -37,7 +37,7 @@ class BFS2D:
 
     def __init__(self, grid: Grid2D, mesh, row_axes=("r",), col_axes=("c",),
                  edge_chunk: int = 8192, expand_fn=None,
-                 fold_bitmap: bool = None, max_levels: int = 64,
+                 fold_bitmap: bool = None, max_levels: int | None = None,
                  dedup: str = "scatter", fold_codec=None):
         import warnings
 

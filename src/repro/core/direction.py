@@ -26,7 +26,7 @@ class BFS2DDirection:
 
     def __init__(self, grid: Grid2D, mesh, row_axes=("r",), col_axes=("c",),
                  edge_chunk: int = 8192, alpha: int = 24,
-                 max_levels: int = 64, fold_codec="list"):
+                 max_levels: int | None = None, fold_codec="list"):
         import warnings
 
         from repro.api.config import BFSConfig

@@ -325,17 +325,22 @@ def frontier_workload(all_front, front_total, col_off):
     `reference_expand_chunk` searches: beside each clipped cumul entry k,
     the SLOT_VALS values of slot k, its column u and its address base
     col_off[u] - cumul[k] (at k = ncl the last slot's again: slots are
-    clipped to ncl - 1)."""
+    clipped to ncl - 1).
+
+    It runs under the sub-scope `workload` of the caller's layer scope
+    (`repro/expand/workload`): work dense over ncl that every level pays
+    before its first chunk, whatever its frontier."""
     ncl = all_front.shape[0]
-    u_safe = jnp.clip(all_front, 0, ncl - 1)
-    off = col_off[u_safe]
-    deg = col_off[u_safe + 1] - off
-    deg = jnp.where(jnp.arange(ncl) < front_total, deg, 0)
-    cumul = exclusive_cumsum(deg)                      # (ncl + 1,)
-    total = cumul[front_total]
-    vals = jnp.stack([u_safe, off - cumul[:-1]])
-    vals = jnp.concatenate([vals, vals[:, -1:]], axis=1)
-    return cumul, total, slot_table(cumul, total, vals)
+    with jax.named_scope("workload"):
+        u_safe = jnp.clip(all_front, 0, ncl - 1)
+        off = col_off[u_safe]
+        deg = col_off[u_safe + 1] - off
+        deg = jnp.where(jnp.arange(ncl) < front_total, deg, 0)
+        cumul = exclusive_cumsum(deg)                  # (ncl + 1,)
+        total = cumul[front_total]
+        vals = jnp.stack([u_safe, off - cumul[:-1]])
+        vals = jnp.concatenate([vals, vals[:, -1:]], axis=1)
+        return cumul, total, slot_table(cumul, total, vals)
 
 
 def reference_expand_chunk(gids, total, table, row_idx):

@@ -84,7 +84,7 @@ class DistBFSEngine(FrontierEngine):
     """
 
     def __init__(self, topo: Topology, *, fold_codec="list",
-                 edge_chunk: int = 8192, max_levels: int = 64,
+                 edge_chunk: int = 8192, max_levels: int | None = None,
                  expand: str = "auto", expand_fn=None, fold: str = "auto",
                  dedup: str = "scatter", bottomup: str = "auto",
                  exchange="flat", step_factory=None, n_extra: int = 0,

@@ -22,14 +22,15 @@ from repro.obs.export import EventLog, to_prometheus
 from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                                MetricsRegistry)
 from repro.obs.spans import PHASES, RequestTrace, Span, request_trace
-from repro.obs.trace import (N_TRACE_OUTS, TRACE_CHANNELS, LevelTrace,
-                             assemble_traces, init_trace, normalize_aux,
-                             record_level, trace_outputs)
+from repro.obs.trace import (N_TRACE_OUTS, RECORDED_LEVELS, TRACE_CHANNELS,
+                             LevelTrace, assemble_traces, init_trace,
+                             normalize_aux, record_level, trace_outputs)
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
     "EventLog", "to_prometheus",
     "LevelTrace", "assemble_traces", "init_trace", "normalize_aux",
     "record_level", "trace_outputs", "TRACE_CHANNELS", "N_TRACE_OUTS",
+    "RECORDED_LEVELS",
     "RequestTrace", "Span", "PHASES", "request_trace",
 ]

@@ -41,6 +41,14 @@ import dataclasses
 
 import numpy as np
 
+# Levels every per-level record holds: each trace channel here and the
+# direction program's `dirs`.  The record's length is fixed, whatever the
+# level loop's bound, so a search run to completion on a deep graph carries
+# no n-long arrays: levels past the record fold into its last slot (each
+# writes slot min(k, RECORDED_LEVELS - 1)), while the counter `k` keeps
+# counting every level.
+RECORDED_LEVELS = 64
+
 # Channel order of the trace arrays the engine appends after (hi, lo);
 # plus one trailing per-device level counter `k`.
 TRACE_CHANNELS = ("frontier", "front_dev", "scanned", "folded", "wire",
@@ -53,10 +61,10 @@ N_TRACE_OUTS = len(TRACE_CHANNELS) + 1
 # importable by host-only tooling)
 # ----------------------------------------------------------------------------
 
-def init_trace(max_levels: int) -> dict:
+def init_trace() -> dict:
     """Fresh per-search trace carry (one per device, inside shard_map)."""
     import jax.numpy as jnp
-    L = int(max_levels)
+    L = RECORDED_LEVELS
     return {
         "frontier": jnp.zeros((L,), jnp.int32),
         "front_dev": jnp.zeros((L,), jnp.int32),
@@ -117,8 +125,10 @@ def trace_outputs(tr: dict) -> tuple:
 class LevelTrace:
     """One search's per-level telemetry, global + per-device.
 
-    Arrays are truncated to the levels actually run; `*_dev` arrays carry a
-    leading P = R*C device axis in vertex-block device order.
+    Arrays are truncated to the levels actually run, at most
+    RECORDED_LEVELS (the last entry then holds the deepest level run);
+    `*_dev` arrays carry a leading P = R*C device axis in vertex-block
+    device order.
     """
     program: str
     codec: str
@@ -193,7 +203,7 @@ def assemble_traces(traw, B, *, grid, program: str, codec: str):
     """Gathered trace outputs -> LevelTrace (B=None) or a tuple of B.
 
     `traw` is the engine's trailing N_TRACE_OUTS device outputs; every
-    channel gathers to (R, C, [B,] max_levels) and `k` to (R, C[, B]).
+    channel gathers to (R, C, [B,] RECORDED_LEVELS) and `k` to (R, C[, B]).
     `frontier`/`dir` are psum-replicated so device 0's row is global truth;
     the work channels are per-device and sum to the global figures.
     """

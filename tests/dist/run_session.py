@@ -25,6 +25,7 @@ from repro.api import BFSConfig, DistGraph
 from repro.core import bfs_reference_py
 from repro.dist.compat import make_mesh
 from repro.graphgen import rmat_edges, build_csc
+from repro.obs.trace import RECORDED_LEVELS
 
 SCALE, EF = 9, 8
 n = 1 << SCALE
@@ -67,7 +68,7 @@ dsess = graph.session(BFSConfig(grid=(R, C), edge_chunk=2048,
 assert graph.csr is not None
 dout = check_batch(dsess, "direction")
 dirs = np.asarray(dout.directions)
-assert dirs.shape == (len(roots), dsess.config.max_levels), "directions shape"
+assert dirs.shape == (len(roots), RECORDED_LEVELS), "directions shape"
 live = dirs[0][dirs[0] >= 0]
 assert live.size == int(dout.n_levels[0]) - 1, "one decision per level"
 assert (live == 0).any() and (live == 1).any(), \

@@ -35,6 +35,7 @@ from repro.dist.topology import Topology
 from repro.graphgen import rmat_edges, build_csc
 from repro.kernels import bottomup_chunk, bottomup_chunk_values
 from repro.kernels.select import BOTTOMUP_ENV, resolve_bottomup_path
+from repro.obs.trace import RECORDED_LEVELS
 
 SCALE, EF = 8, 8
 N = 1 << SCALE
@@ -111,7 +112,7 @@ def test_adaptive_switch_in_loop_one_trace(graph_data):
     sess.bfs(roots[::-1].copy())
     assert sess.engine.trace_count == 1, "second sweep must hit the cache"
     dirs = np.asarray(out.directions)
-    assert dirs.shape == (64, sess.config.max_levels)
+    assert dirs.shape == (64, RECORDED_LEVELS)
     d0 = dirs[0][dirs[0] >= 0]
     assert (d0 == 0).any() and (d0 == 1).any(), \
         f"adaptive must use both directions on RMAT, got {d0}"

@@ -22,8 +22,8 @@ import numpy as np
 import pytest
 
 from repro.api import BFSConfig, DistGraph
-from repro.obs import (PHASES, EventLog, LevelTrace, MetricsRegistry,
-                       request_trace, to_prometheus)
+from repro.obs import (PHASES, RECORDED_LEVELS, EventLog, LevelTrace,
+                       MetricsRegistry, request_trace, to_prometheus)
 from repro.runtime.fault import FaultInjector, StepRunner
 from repro.serve import GraphServer, ServeConfig
 
@@ -298,7 +298,7 @@ def test_import_trace_blanks_channels_the_snapshot_lacks(graph_data,
 
     g, _ = graph_data
     eng = g.engine_for(_cfg())
-    L = int(eng.max_levels)
+    L = RECORDED_LEVELS
     rng = np.random.default_rng(0)
     old = {c: rng.integers(0, 9, src_grid + (L,)).astype(
                np.uint32 if c in ("scanned", "wire") else np.int32)

@@ -76,7 +76,7 @@ def test_direction_program_scopes(edges):
 
 def test_expand_sub_scopes(edges):
     hlo = program_text(edges, False)
-    for sub in ("exchange", "map", "filter", "mark"):
+    for sub in ("exchange", "workload", "map", "filter", "mark"):
         assert re.search(rf"repro/expand/(while/body/)?{sub}/", hlo), sub
 
 
