@@ -5,8 +5,13 @@ update, deferred-predecessor resolution -- expressed as ONE instance of the
 generalized driver.  The monoid is first-visit-wins (the visited bitmap is
 the suppression cache, the fold payload is the vertex set itself), which is
 why plain set codecs suffice on the wire.  `repro.dist.engine.DistBFSEngine`
-wraps this program to keep the historical constructor; outputs are
-bit-identical to the pre-subsystem engine (same ops, same order).
+wraps this program to keep the historical constructor.
+
+The frontier a level hands on is every owned row whose level equals that
+level, as local cols, ascending (`frontier.compact_mask`).  Its order fixes
+the next level's scan order, and so which parent wins each first-visit
+race: levels and predecessors are bit-identical across fold codecs (whose
+delivery orders differ) and direction mixes.
 """
 from __future__ import annotations
 
@@ -52,18 +57,6 @@ def owned_level(level, *, grid: Grid2D, j):
     return jax.lax.dynamic_slice_in_dim(level, j * grid.S, grid.S)
 
 
-def canonical_front(front, cnt):
-    """Sort the padded frontier ascending (pad -1 stays at the back).
-
-    The frontier's order fixes the edge-scan order of the NEXT level, which
-    fixes which parent wins each first-visit race -- so keeping it canonical
-    makes levels AND predecessors bit-identical across fold codecs (whose
-    natural delivery orders differ)."""
-    key = jnp.where(front < 0, F.I32_MAX, front)
-    s = jnp.sort(key)
-    return jnp.where(s == F.I32_MAX, -1, s), cnt
-
-
 def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
     """One top-down level (paper Alg. 2 lines 12-18).
 
@@ -89,13 +82,6 @@ def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
             edge_chunk=engine.edge_chunk, expand_fn=engine.expand_fn,
             dedup=engine.dedup, count_passes=engine.telemetry)
 
-    with jax.named_scope("repro/update"):
-        # own-column vertices go straight to the frontier (lines 15-16)
-        own_rows = jnp.take(ex.dst, j, axis=0)      # (S,) local rows, block j
-        own_cnt = jnp.take(ex.dst_cnt, j)
-        own_cols = (i * S + (own_rows - j * S)).astype(jnp.int32)  # ROW2COL
-        own_valid = jnp.arange(S, dtype=jnp.int32) < own_cnt
-
     with jax.named_scope("repro/fold"):
         # the own column never travels; the rest route to their owners
         dst = ex.dst.at[j].set(-1)
@@ -104,16 +90,13 @@ def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
         int_verts, int_cnt = engine.codec.fold(dst, dst_cnt, topo=topo, j=j)
 
     with jax.named_scope("repro/update"):
-        # frontier update (paper sec. 3.5)
+        # frontier update (paper sec. 3.5): the fold's winners join the
+        # own column's, already marked in the scan; the next frontier is
+        # every owned row at this level, ascending
         up = F.update_frontier(int_verts, int_cnt, ex.visited, ex.level,
-                               ex.pred, st.lvl, grid=grid, i=i, j=j)
-
-        nf = jnp.full((S,), -1, jnp.int32)
-        nc = jnp.int32(0)
-        nf, nc = F.append_padded(nf, nc, own_cols, own_valid)
-        up_valid = jnp.arange(S, dtype=jnp.int32) < up.new_cnt
-        nf, nc = F.append_padded(nf, nc, up.new_front, up_valid)
-        nf, nc = canonical_front(nf, nc)
+                               ex.pred, st.lvl)
+        nf, nc = F.compact_mask(
+            owned_level(up.level, grid=grid, j=j) == st.lvl, i * S)
         st2 = BFSState(level=up.level, pred=up.pred, visited=up.visited,
                        front=nf, front_cnt=nc, lvl=st.lvl + 1)
     with jax.named_scope("repro/loop"):
@@ -227,8 +210,7 @@ class BFSLevelsProgram(FrontierProgram):
         trajectory (same grid) is bit-identical, predecessors included.
         `pred` is authoritative at the owned block only (resolve_preds is
         idempotent on resolved entries); the frontier re-derives from
-        level == lvl-1, ascending -- the canonical-sort order the organic
-        frontier carries.
+        level == lvl-1, ascending -- as every step builds it.
         """
         grid = engine.grid
         R, C, S, nrl = grid.R, grid.C, grid.S, grid.n_rows_local

@@ -19,13 +19,15 @@ pins every level bottom-up instead (the benchmark sweep's fixed arm).
 Bit-identity (the repo-wide contract): for BFS the bottom-up merge gives the
 owner's own column block priority and otherwise takes the minimum sender
 column, each contributing its minimum frontier-neighbour column -- exactly
-the winner the top-down visited-suppression + canonical-ascending scan order
-elects, so levels, preds and n_levels match top-down bit for bit at ANY
-per-level direction mix.  For the value programs the pull scan proposes the
-same relaxed-value multiset per row (CSR and CSC hold the same local edges),
-and the min-monoid combine is order-independent.  `edges_scanned` is the
-honest per-direction work (bottom-up scans unvisited rows' in-edges), so it
-legitimately differs from top-down -- Graph500 TEPS stays input-edge-based.
+the winner the top-down visited-suppression + ascending scan order elects,
+so levels, preds and n_levels match top-down bit for bit at ANY per-level
+direction mix.  Both steps hand on the same frontier: the owned rows with
+level == lvl, as local cols, ascending.  For the value programs the pull
+scan proposes the same relaxed-value multiset per row (CSR and CSC hold the
+same local edges), and the min-monoid combine is order-independent.
+`edges_scanned` is the honest per-direction work (bottom-up scans unvisited
+rows' in-edges), so it legitimately differs from top-down -- Graph500 TEPS
+stays input-edge-based.
 
 The frontier travels to the bottom-up scan as the BITMAP the fold codecs
 already know how to pack (`frontier.pack_bitmap`), row-gathered in a blocked
@@ -152,7 +154,6 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
     own-column priority then minimum sender -- exactly the parent top-down's
     visited suppression + min-slot dedup elects (see module docstring).
     """
-    from repro.algos.bfs import canonical_front
     from repro.core.types import BFSState
 
     row_off, col_idx = extra[-2], extra[-1]
@@ -233,10 +234,8 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
             pred2 = st.pred.at[tgt].set(jnp.where(new, parent, 0),
                                         mode="drop")
 
-            lc = i * S + jnp.arange(S, dtype=jnp.int32)  # ROW2COL of owned
-            nf, nc = F.append_padded(jnp.full((S,), -1, jnp.int32),
-                                     jnp.int32(0), lc, new)
-            nf, nc = canonical_front(nf, nc)
+            # the next frontier: this level's owned discoveries, ascending
+            nf, nc = F.compact_mask(new, i * S)
             st2 = BFSState(level=level2, pred=pred2, visited=visited2,
                            front=nf, front_cnt=nc, lvl=st.lvl + 1)
         with jax.named_scope("repro/loop"):

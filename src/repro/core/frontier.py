@@ -289,6 +289,21 @@ def bucket_append(dst, dst_cnt, v, tgt, take, n_buckets: int):
     return dst, dst_cnt + jnp.minimum(add, cap - dst_cnt)
 
 
+def compact_mask(mask, offset=0):
+    """Ascending positions of the set lanes of an (S,) bool mask, plus
+    `offset`, padded with -1 to (S,), and their count.
+
+    One prefix sum and one scatter (each set lane lands at its rank), no
+    sort: the frontier update's compaction of a level's owned discoveries.
+    """
+    S = mask.shape[0]
+    lanes = jnp.arange(S, dtype=jnp.int32)
+    rank = jnp.cumsum(mask, dtype=jnp.int32)
+    out = jnp.full((S,), -1, jnp.int32).at[
+        jnp.where(mask, rank - 1, S)].set(lanes + offset, mode="drop")
+    return out, rank[-1]
+
+
 def append_padded(buf, cnt, vals, valid):
     """Append vals[valid] to a padded (cap,) buffer at position cnt."""
     b, c = bucket_append(buf[None, :], cnt[None], vals,
@@ -550,18 +565,17 @@ class UpdateResult(NamedTuple):
     visited: jax.Array
     level: jax.Array
     pred: jax.Array
-    new_front: jax.Array   # (S,) local col ids of newly frontier vertices
-    new_cnt: jax.Array
 
 
-def update_frontier(int_verts, int_cnt, visited, level, pred, lvl, *,
-                    grid: Grid2D, i, j) -> UpdateResult:
+def update_frontier(int_verts, int_cnt, visited, level,
+                    pred, lvl) -> UpdateResult:
     """Process fold-received vertices (paper sec. 3.5).
 
     int_verts: (C, S) local-row ids received from each processor-column
     (sender m in slot m).  Received vertices are OWNED here; unvisited ones
-    get level/visited set, pred <- -(sender_col + 2) (deferred), and are
-    appended to the next frontier as local COL indices.
+    get level/visited set and pred <- -(sender_col + 2) (deferred).  They
+    join the next frontier through `level == lvl` (the caller's
+    `compact_mask`).
     """
     n_rows = visited.shape[0]
     C, S = int_verts.shape
@@ -576,9 +590,4 @@ def update_frontier(int_verts, int_cnt, visited, level, pred, lvl, *,
         jnp.where(win, lvl, 0), mode="drop")
     pred = pred.at[jnp.where(win, v, n_rows)].set(
         jnp.where(win, -(snd + 2), 0), mode="drop")
-    # new frontier = winners, converted row -> col index (ROW2COL)
-    lc = (i * S + (v - j * S)).astype(jnp.int32)
-    nf = jnp.full((C * S,), -1, jnp.int32)
-    nf_cnt0 = jnp.zeros((1,), jnp.int32)
-    nf, cnt = bucket_append(nf[None, :], nf_cnt0, lc, jnp.zeros_like(lc), win, 1)
-    return UpdateResult(visited, level, pred, nf[0, :S], cnt[0])
+    return UpdateResult(visited, level, pred)

@@ -36,9 +36,9 @@ from repro.dist.topology import Topology
 # The BFS building blocks now live in repro.algos.bfs, which imports
 # repro.dist.exchange -- so pulling them in at module scope would re-enter
 # this package's own __init__ mid-import.  PEP 562 keeps
-# `from repro.dist.engine import canonical_front` (etc.) working lazily.
-_BFS_REEXPORTS = ("BFSLevelsProgram", "canonical_front", "init_state",
-                  "owned_level", "topdown_step")
+# `from repro.dist.engine import topdown_step` (etc.) working lazily.
+_BFS_REEXPORTS = ("BFSLevelsProgram", "init_state", "owned_level",
+                  "topdown_step")
 
 
 def __getattr__(name):

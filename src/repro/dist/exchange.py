@@ -31,8 +31,8 @@ Delivery order per sender differs by codec (`list` keeps discovery order,
 `bitmap`/`delta` deliver ascending) -- outputs are nonetheless bit-identical
 across codecs because (a) a vertex appears at most once per sender, and the
 update winner is the MINIMUM sender regardless of position within a message,
-and (b) the engine keeps frontiers in canonical ascending order
-(`engine.canonical_front`), fixing the next level's scan order.  Do not rely
+and (b) the next frontier is the owned rows with level == lvl, ascending
+(`frontier.compact_mask`), fixing the next level's scan order.  Do not rely
 on per-sender ordering in a decoder.  `delta` requires S <= 65536 so every
 gap fits in a uint16; larger blocks would need an escape word, which this
 repro does not implement (`get_fold_codec` names the codecs that DO work).

@@ -1,5 +1,7 @@
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from _hypothesis_compat import given, settings, st
 
@@ -80,3 +82,24 @@ def test_compact_blocks():
 def test_exclusive_cumsum():
     x = jnp.asarray([3, 0, 2], jnp.int32)
     assert np.asarray(F.exclusive_cumsum(x)).tolist() == [0, 3, 3, 5]
+
+
+@pytest.mark.parametrize("S,density,offset", [
+    (1024, 0.1, 0),        # random, a power of two
+    (1000, 0.5, 0),        # random, not a power of two
+    (37, 0.3, 2 * 37),     # offset i * S of the grid's row i = 2
+    (130, 0.0, 130),       # all false
+    (130, 1.0, 3 * 130),   # all true
+    (1, 1.0, 5),
+    (1, 0.0, 0),
+])
+def test_compact_mask_matches_flatnonzero(S, density, offset):
+    mask = np.random.default_rng(S).random(S) < density
+    # traced, as the steps call it with the device's row i
+    out, cnt = jax.jit(F.compact_mask)(jnp.asarray(mask), jnp.int32(offset))
+    t = np.flatnonzero(mask)
+    want = np.full(S, -1, np.int32)
+    want[:t.size] = t + offset
+    assert out.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(out), want)
+    assert int(cnt) == t.size

@@ -106,11 +106,11 @@ def test_one_chip_smoke_shapes_compile(one_chip, direction):
 _GATHER = re.compile(r"=\s*\w+\[(\d+)\][^=]*\sgather\(.*op_name=\"([^\"]*)\"")
 
 
-def lane_gathers(hlo: str, scope: str) -> int:
-    """Gathers of one value per lane of a chunk under `scope`, outside the
-    slot search's vmapped tile loop."""
+def lane_gathers(hlo: str, scope: str, lanes: int = EDGE_CHUNK) -> int:
+    """Gathers of one value per lane (of a chunk, by default) under
+    `scope`, outside the slot search's vmapped tile loop."""
     return sum(1 for m in map(_GATHER.search, hlo.splitlines())
-               if m and int(m.group(1)) == EDGE_CHUNK
+               if m and int(m.group(1)) == lanes
                and scope in m.group(2) and "vmap()" not in m.group(2))
 
 
@@ -122,6 +122,25 @@ def test_one_chip_map_gathers_one_value_per_lane(one_chip):
     topdown, direction = (one_chip[d].as_text() for d in (False, True))
     assert lane_gathers(topdown, "repro/expand/while/body/map/") == 1
     assert lane_gathers(direction, "repro/expand/bottomup/") == 4
+
+
+_SORT = re.compile(r"\ssort\(.*op_name=\"([^\"]*)\"")
+
+
+@pytest.mark.parametrize("direction", [False, True],
+                         ids=["topdown", "direction"])
+def test_one_chip_update_builds_frontier_without_sort(one_chip, direction):
+    """The next frontier is one prefix-sum compaction of the owned
+    `level == lvl`: no sort or argsort under `repro/update` (the sort XLA
+    makes of a scatter's indices carries the scatter's name), and of the
+    update's n-lane gathers at most the two of `update_frontier`'s
+    visited test and claim."""
+    hlo = one_chip[direction].as_text()
+    sorts = [m.group(1) for m in map(_SORT.search, hlo.splitlines())
+             if m and "repro/update/" in m.group(1)]
+    assert not [o for o in sorts if not o.endswith("/scatter")], sorts
+    if not direction:
+        assert lane_gathers(hlo, "repro/update/", 1 << SMOKE_SCALE) <= 2
 
 
 def test_2x2_flat_folds_with_all_to_all(topo):
