@@ -154,9 +154,7 @@ def main() -> int:
     deg = np.bincount(edges[0], minlength=n)
     roots = np.random.default_rng(SEED).choice(
         np.flatnonzero(deg > 0), N_ROOTS, replace=False).astype(np.int32)
-    config = BFSConfig(grid=(1, 1), edge_chunk=EDGE_CHUNK,
-                       expand="reference", fold="reference",
-                       bottomup="reference")
+    config = BFSConfig(grid=(1, 1), edge_chunk=EDGE_CHUNK)
     graph = DistGraph.from_edges(edges, config, n=n)
     _, check, level = programs(jax, np, graph, roots, n)
     has_slots = hasattr(frontier, "edge_slots")

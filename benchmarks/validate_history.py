@@ -7,9 +7,9 @@ the correctness surface:
   * the schema may only move FORWARD ("BENCH_bfs/v8" -> v9 is fine, -> v7
     is a regression);
   * every agreement flag that was true in the baseline stays true
-    (codecs_agree / expand_paths_agree / direction_agree /
-    exchange_agree, BENCH_algos per-algo codecs_agree) -- a suite that
-    silently stopped running reads as null and FAILS the gate;
+    (codecs_agree / direction_agree / exchange_agree, BENCH_algos
+    per-algo codecs_agree) -- a suite that silently stopped running
+    reads as null and FAILS the gate;
   * every fold codec / algo / exchange strategy covered by the baseline
     is still covered;
   * when the fresh run used the same graph scale and grid as the
@@ -30,8 +30,7 @@ import json
 import os
 import sys
 
-AGREE_FLAGS = ("codecs_agree", "expand_paths_agree", "direction_agree",
-               "exchange_agree")
+AGREE_FLAGS = ("codecs_agree", "direction_agree", "exchange_agree")
 
 
 def _load(d, name, errors):

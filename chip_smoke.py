@@ -182,9 +182,6 @@ def one_chip(jax, np, scale, phases: Phases) -> None:
     n = 1 << scale
     edges, roots = phases.run("generate", generate, jax, np, scale)
     graph = phases.run("plan 1x1", plan, jax, edges, n, (1, 1))
-    config = graph.config
-    log(f"resolved paths: expand={config.expand_path} "
-        f"fold={config.fold_path} bottomup={config.bottomup_path}")
     memory_report(jax)
     session = graph.session()
     direct = {}

@@ -73,14 +73,14 @@ def topdown_step(engine, graph: LocalGraph2D, st: BFSState, *, i, j):
         # expand exchange: gather frontiers within the processor-column
         with jax.named_scope("exchange"):
             all_front, front_total = X.expand_exchange(
-                st.front, st.front_cnt, topo=topo, ops=engine.fold_ops)
+                st.front, st.front_cnt, topo=topo)
 
         # frontier expansion (local CSC column scan)
         ex = F.expand_frontier(
             graph.col_off, graph.row_idx, st.visited, st.level, st.pred,
             all_front, front_total, st.lvl, grid=grid, i=i, j=j,
-            edge_chunk=engine.edge_chunk, expand_fn=engine.expand_fn,
-            dedup=engine.dedup, count_passes=engine.telemetry)
+            edge_chunk=engine.edge_chunk, dedup=engine.dedup,
+            count_passes=engine.telemetry)
 
     with jax.named_scope("repro/fold"):
         # the own column never travels; the rest route to their owners

@@ -102,7 +102,6 @@ def make_pull_scan(engine, row_off, col_idx, i, j, *, relax,
     S = grid.S
     nrl, ncl = grid.n_rows_local, grid.n_cols_local
     chunk = engine.edge_chunk
-    bu_fn = engine.value_bottomup_fn
 
     def scan(st):
         fvalid = st.front >= 0
@@ -120,14 +119,9 @@ def make_pull_scan(engine, row_off, col_idx, i, j, *, relax,
         def chunk_body(state):
             start, cand = state
             gids = start + jnp.arange(chunk, dtype=jnp.int32)
-            if bu_fn is None:
-                r, pay, addr, hit = F.reference_bottomup_values_chunk(
-                    gids, cumul, total, row_off, col_idx, all_words,
-                    dense_pay, block=S)
-            else:
-                r, pay, addr, hit = bu_fn(gids, cumul, total, row_off,
-                                          col_idx, all_words, dense_pay,
-                                          block=S)
+            r, pay, addr, hit = F.reference_bottomup_values_chunk(
+                gids, cumul, total, row_off, col_idx, all_words, dense_pay,
+                block=S)
             w = None if csr_edge_vals is None else csr_edge_vals[addr]
             val = jnp.where(hit, relax(pay, w), I32_MAX)
             cand = cand.at[jnp.where(hit, r, nrl)].min(val, mode="drop")
@@ -161,8 +155,6 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
     S, C = grid.S, grid.C
     nrl, ncl = grid.n_rows_local, grid.n_cols_local
     chunk = engine.edge_chunk
-    fold_ops = engine.fold_ops
-    bu_fn = engine.bottomup_fn
     snd = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[:, None], (C, S))
 
     def step(st: BFSState, prev_total):
@@ -179,13 +171,8 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
             def chunk_body(state):
                 start, best = state
                 gids = start + jnp.arange(chunk, dtype=jnp.int32)
-                if bu_fn is None:
-                    r, c, hit = F.reference_bottomup_chunk(
-                        gids, cumul, total, row_off, col_idx, all_words,
-                        block=S)
-                else:
-                    r, c, hit = bu_fn(gids, cumul, total, row_off, col_idx,
-                                      all_words, block=S)
+                r, c, hit = F.reference_bottomup_chunk(
+                    gids, cumul, total, row_off, col_idx, all_words, block=S)
                 best = best.at[jnp.where(hit, r, nrl)].min(
                     jnp.where(hit, c, I32_MAX), mode="drop")
                 return start + chunk, best
@@ -202,8 +189,7 @@ def make_bfs_bottomup_step(engine, graph, extra, i, j):
         # value-fold (vertex, encoded parent) to the owners -- the same
         # exchange the value programs use, so every codec works here
         with jax.named_scope("repro/fold"):
-            ids, cnt, vals = PR.pack_blocks(found, parent_g, grid,
-                                            ops=fold_ops)
+            ids, cnt, vals = PR.pack_blocks(found, parent_g, grid)
             ri, rc, rv = engine.codec.fold_values(ids, cnt, vals, topo=topo,
                                                   j=j)
 
@@ -271,7 +257,6 @@ class DirectionProgram(FrontierProgram):
     -1 unused level / 0 top-down / 1 bottom-up; whatever the loop's bound,
     levels past the record fold into its last slot).
     """
-    uses_bottomup = True
 
     def __init__(self, inner: FrontierProgram, *, mode: str = "adaptive",
                  alpha: int = 24, beta: int = 64):

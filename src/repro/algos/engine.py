@@ -68,20 +68,6 @@ class FrontierEngine:
                 runs to completion.  The per-level records (telemetry,
                 directions) hold `repro.obs.trace.RECORDED_LEVELS` levels
                 whatever the bound.
-    expand:     local-expand implementation: "reference" | "pallas" |
-                "pallas-interpret" | "auto" (DESIGN.md sec. 9).  "auto"
-                picks Pallas on GPU/TPU, reference on CPU, and honors
-                REPRO_EXPAND=pallas-interpret for interpret-mode testing.
-                All paths are bit-identical.
-    expand_fn:  explicit chunk-expansion override for the CSC scan; when
-                given it wins over `expand` (and value-carrying scans fall
-                back to the reference path).
-    fold:       fold-pipeline implementation: "reference" | "pallas" |
-                "pallas-interpret" | "auto" (DESIGN.md sec. 10).  Selects
-                the codec encode/decode kernels and the prefix-sum
-                compaction that replaces the per-level argsorts; "auto"
-                honors REPRO_FOLD and otherwise mirrors the expand rules.
-                All paths are bit-identical.
     dedup:      winner-selection method for set-valued folds.
     exchange:   fold exchange strategy: "flat" (one all_to_all per fold) |
                 "butterfly" (log2(C) pairwise ppermute stages over the XOR
@@ -91,12 +77,6 @@ class FrontierEngine:
                 The resolved strategy is bound into the engine's topology,
                 so every codec and the predecessor resolution route through
                 it; outputs are bit-identical across strategies.
-    bottomup:   bottom-up parent-search implementation: "reference" |
-                "pallas" | "pallas-interpret" | "auto" (DESIGN.md sec. 11).
-                "auto" honors REPRO_BOTTOMUP and otherwise mirrors the
-                expand rules.  Only consulted when the program declares
-                `uses_bottomup` (the direction-optimising driver); all
-                paths are bit-identical.
     telemetry:  when True, thread the per-level `repro.obs.trace` carry
                 through the while_loop and return a `LevelTrace` with every
                 search (DESIGN.md sec. 13).  Off by default; the flag is
@@ -118,15 +98,11 @@ class FrontierEngine:
 
     def __init__(self, topo, program, *, fold_codec=None,
                  edge_chunk: int = 8192, max_levels: int | None = None,
-                 expand: str = "auto", expand_fn=None, fold: str = "auto",
-                 dedup: str = "scatter", bottomup: str = "auto",
-                 exchange="flat", telemetry: bool = False,
-                 fault_tolerance: bool = False, ckpt_every: int = 1):
+                 dedup: str = "scatter", exchange="flat",
+                 telemetry: bool = False, fault_tolerance: bool = False,
+                 ckpt_every: int = 1):
         from repro.dist.exchange import get_fold_codec
         from repro.dist.strategy import get_exchange
-        from repro.kernels.select import (resolve_bottomup_path,
-                                          resolve_expand_path,
-                                          resolve_fold_path)
 
         # resolve + validate the exchange strategy and bind it into the
         # topology: codecs and resolve_preds call topo.col_all_to_all and
@@ -140,52 +116,9 @@ class FrontierEngine:
         self.edge_chunk = edge_chunk
         self.max_levels = self.grid.n + 1 if max_levels is None \
             else int(max_levels)
-        self.expand = expand
-        self.fold = fold
-        self.fold_path = resolve_fold_path(fold)
-        self.fold_ops = None
-        if self.fold_path != "reference":
-            # same import discipline as the expand kernels: through the
-            # package surface, outside any trace (Pallas-less installs get
-            # the guided ImportError naming fold='reference')
-            from repro.kernels import make_fold_ops
-            self.fold_ops = make_fold_ops(path=self.fold_path)
         spec = fold_codec if fold_codec is not None else program.codec_hint
-        self.codec = get_fold_codec(spec, topo.grid, ops=self.fold_ops)
-        # value_expand_fn is the value-carrying twin threaded into
-        # `repro.algos.program.scan_relax` (CC / SSSP / multi-source BFS)
-        self.value_expand_fn = None
-        if expand_fn is not None:
-            self.expand_path = "custom"
-        else:
-            self.expand_path = resolve_expand_path(expand)
-            if self.expand_path != "reference":
-                # import OUTSIDE any trace (the kernel modules cache jnp
-                # constants at import time; see repro.kernels.expand), and
-                # through the package surface so a Pallas-less install gets
-                # the guided ImportError (expand='reference' remedy)
-                from repro.kernels import (make_expand_fn,
-                                           make_value_expand_fn)
-                expand_fn = make_expand_fn(path=self.expand_path)
-                self.value_expand_fn = make_value_expand_fn(
-                    path=self.expand_path)
-        self.expand_fn = expand_fn
+        self.codec = get_fold_codec(spec, topo.grid)
         self.dedup = dedup
-        # bottom-up kernel hooks (the direction-optimised steps' chunk
-        # parent search); resolved for every engine so the path lands in
-        # cache keys, constructed only when the program can use them
-        self.bottomup = bottomup
-        self.bottomup_path = resolve_bottomup_path(bottomup)
-        self.bottomup_fn = None
-        self.value_bottomup_fn = None
-        if getattr(program, "uses_bottomup", False) \
-                and self.bottomup_path != "reference":
-            # same import discipline as the expand/fold kernels (package
-            # surface, outside any trace; bottomup='reference' remedy)
-            from repro.kernels import make_bottomup_fn, make_value_bottomup_fn
-            self.bottomup_fn = make_bottomup_fn(path=self.bottomup_path)
-            self.value_bottomup_fn = make_value_bottomup_fn(
-                path=self.bottomup_path)
         self.telemetry = bool(telemetry)
         self.fault_tolerance = bool(fault_tolerance)
         self.ckpt_every = max(1, int(ckpt_every))

@@ -86,8 +86,7 @@ class MultiSourceBFSProgram(FrontierProgram):
         level = jnp.where(claimed, 0, -1).astype(jnp.int32)
         owned_src = jax.lax.dynamic_slice_in_dim(src, j * S, S)
         front, payload, cnt = PR.owned_to_front(owned_src < I32_MAX,
-                                                owned_src, i, S,
-                                                ops=engine.fold_ops)
+                                                owned_src, i, S)
         return MultiBFSState(visited=claimed, level=level, src=src,
                              front=front, payload=payload, front_cnt=cnt,
                              lvl=jnp.int32(1))
@@ -108,7 +107,6 @@ class MultiSourceBFSProgram(FrontierProgram):
     def _make_step(self, engine, graph, i, j, scan=None):
         grid, topo = engine.grid, engine.topo
         S, nrl = grid.S, grid.n_rows_local
-        fold_ops = engine.fold_ops
         step_dir = jnp.int32(1 if scan is not None else 0)
         wire_base = jnp.uint32(engine.codec.wire_bytes(grid))
 
@@ -121,20 +119,18 @@ class MultiSourceBFSProgram(FrontierProgram):
                     with jax.named_scope("exchange"):
                         all_front, all_pay, ftot = X.expand_exchange_values(
                             st.front, st.front_cnt, st.payload, topo=topo,
-                            fill=I32_MAX, ops=fold_ops)
+                            fill=I32_MAX)
                     cand, scanned = PR.scan_relax(
                         graph.col_off, graph.row_idx, None, all_front,
                         all_pay, ftot, lambda p, w: p, n_rows=nrl,
-                        edge_chunk=engine.edge_chunk,
-                        expand_fn=engine.value_expand_fn)
+                        edge_chunk=engine.edge_chunk)
             with jax.named_scope("repro/update"):
                 # first fold per vertex per device (the BFS visited
                 # discipline)
                 improved = (cand < I32_MAX) & ~st.visited
                 vis1 = st.visited | improved
             with jax.named_scope("repro/fold"):
-                ids, cnt, vals = PR.pack_blocks(improved, cand, grid,
-                                                ops=fold_ops)
+                ids, cnt, vals = PR.pack_blocks(improved, cand, grid)
                 ri, rc, rv = engine.codec.fold_values(ids, cnt, vals,
                                                       topo=topo, j=j)
             with jax.named_scope("repro/update"):
@@ -157,8 +153,7 @@ class MultiSourceBFSProgram(FrontierProgram):
                 vis_owned = jax.lax.dynamic_slice_in_dim(vis1, j * S, S)
                 vis2 = jax.lax.dynamic_update_slice(
                     vis1, vis_owned | changed, (j * S,))
-                front, payload, nc = PR.owned_to_front(changed, new_src, i,
-                                                       S, ops=fold_ops)
+                front, payload, nc = PR.owned_to_front(changed, new_src, i, S)
                 st2 = MultiBFSState(visited=vis2, level=lvl2, src=src2,
                                     front=front, payload=payload,
                                     front_cnt=nc, lvl=st.lvl + 1)

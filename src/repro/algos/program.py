@@ -55,9 +55,6 @@ class FrontierProgram:
                  appended after the regular ones -- (row_off, col_idx) of
                  the CSR twin for everyone, plus the CSR-ordered weights
                  for SSSP.  Only consumed via `DirectionProgram`.
-    uses_bottomup: True when `make_step` may call into the bottom-up kernel
-                 hooks (`engine.bottomup_fn` / `engine.value_bottomup_fn`);
-                 the engine only constructs those hooks when set.
 
     The engine calls, in order: `init` (per search), `make_step` (once per
     trace), the loop (`keep_going` / the step), then `finalize`; host-side
@@ -69,7 +66,6 @@ class FrontierProgram:
     codec_hint = "list"
     n_extra = 0
     n_csr_extra = 2
-    uses_bottomup = False
 
     @property
     def key(self) -> tuple:
@@ -182,7 +178,7 @@ class ValueState:
 
 def scan_relax(col_off, row_idx, edge_vals, all_front, all_payload,
                front_total, relax, *, n_rows: int,
-               edge_chunk: int = 8192, expand_fn=None):
+               edge_chunk: int = 8192):
     """Chunked CSC scan of the gathered frontier, min-combining relaxed
     payloads into a dense per-local-row candidate array.
 
@@ -193,29 +189,16 @@ def scan_relax(col_off, row_idx, edge_vals, all_front, all_payload,
     slots by `frontier.edge_slots`), same O(frontier edges + chunk) cost
     per level.
 
-    expand_fn: optional value-carrying kernel override for one chunk (the
-    fused Pallas path, `repro.kernels.expand.make_value_expand_fn`):
-        (gids, cumul, all_front, all_payload, front_total, col_off, row_idx)
-            -> (v, payload, addr, valid)
-    Bit-identical to the inline scan: the kernel maps/gathers, the relax
-    monoid and the scatter-min combine stay here.
-
     Returns (cand (n_rows,) int32, edges_scanned uint32).
     """
-    cumul, total, table = F.frontier_workload(all_front, front_total,
-                                              col_off)
+    _, total, table = F.frontier_workload(all_front, front_total, col_off)
 
     def chunk_body(state):
         start, cand = state
         gids = start + jnp.arange(edge_chunk, dtype=jnp.int32)
-        if expand_fn is None:
-            v, _, k, addr, valid = F.reference_expand_chunk(
-                gids, total, table, row_idx)
-            pay = all_payload[k]
-        else:
-            v, pay, addr, valid = expand_fn(gids, cumul, all_front,
-                                            all_payload, front_total,
-                                            col_off, row_idx)
+        v, _, k, addr, valid = F.reference_expand_chunk(
+            gids, total, table, row_idx)
+        pay = all_payload[k]
         w = None if edge_vals is None else edge_vals[addr]
         val = jnp.where(valid, relax(pay, w), I32_MAX)
         cand = cand.at[jnp.where(valid, v, n_rows)].min(val, mode="drop")
@@ -226,7 +209,7 @@ def scan_relax(col_off, row_idx, edge_vals, all_front, all_payload,
     return cand, total.astype(jnp.uint32)
 
 
-def pack_blocks(improved, vals, grid: Grid2D, fill_val=I32_MAX, ops=None):
+def pack_blocks(improved, vals, grid: Grid2D, fill_val=I32_MAX):
     """Dense (n_rows_local,) improvements -> canonical fold buckets.
 
     Local row m*S + t of block m maps to bucket row m, so the dense array IS
@@ -234,21 +217,12 @@ def pack_blocks(improved, vals, grid: Grid2D, fill_val=I32_MAX, ops=None):
     front-packed ascending (the canonical form `FoldCodec.fold_values`
     requires).  Returns (ids (C, S) local-row ids pad -1, cnt (C,),
     vals (C, S) aligned, pad `fill_val`).
-
-    ops: optional fold-kernel bundle (`repro.kernels.fold`) whose prefix-sum
-    compaction replaces the per-level argsort; bit-identical either way.
     """
     C, S = grid.C, grid.S
     imp = improved.reshape(C, S)
     vv = vals.reshape(C, S)
     t = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (C, S))
     m = jnp.arange(C, dtype=jnp.int32)[:, None]
-    if ops is not None:
-        # pads are -1 (not I32_MAX as on the reference path), so m*S + ts
-        # cannot overflow and a single mask suffices
-        (ts, vs), cnt = ops.compact_rows(imp, (t, vv), (-1, fill_val))
-        ids = jnp.where(ts >= 0, m * S + ts, -1)
-        return ids, cnt, vs
     key = jnp.where(imp, t, I32_MAX)
     order = jnp.argsort(key, axis=1)
     ts = jnp.take_along_axis(key, order, axis=1)
@@ -289,7 +263,6 @@ def make_value_step(engine, graph, i, j, *, relax, edge_vals=None,
 
     grid, topo = engine.grid, engine.topo
     S, nrl = grid.S, grid.n_rows_local
-    fold_ops = engine.fold_ops
 
     # telemetry channel constants: pull scans are the bottom-up direction,
     # and a value fold's wire bytes are count-proportional (on the flat
@@ -310,18 +283,17 @@ def make_value_step(engine, graph, i, j, *, relax, edge_vals=None,
                 with jax.named_scope("exchange"):
                     all_front, all_pay, ftot = X.expand_exchange_values(
                         st.front, st.front_cnt, st.payload, topo=topo,
-                        fill=expand_fill, ops=fold_ops)
+                        fill=expand_fill)
                 cand, scanned = scan_relax(
                     graph.col_off, graph.row_idx, edge_vals, all_front,
                     all_pay, ftot, relax, n_rows=nrl,
-                    edge_chunk=engine.edge_chunk,
-                    expand_fn=engine.value_expand_fn)
+                    edge_chunk=engine.edge_chunk)
         with jax.named_scope("repro/update"):
             # propose only strict improvements over what we already know
             improved = cand < st.val
             val1 = jnp.minimum(st.val, cand)
         with jax.named_scope("repro/fold"):
-            ids, cnt, vals = pack_blocks(improved, cand, grid, ops=fold_ops)
+            ids, cnt, vals = pack_blocks(improved, cand, grid)
             ri, rc, rv = engine.codec.fold_values(ids, cnt, vals, topo=topo,
                                                   j=j)
         with jax.named_scope("repro/update"):
@@ -333,8 +305,7 @@ def make_value_step(engine, graph, i, j, *, relax, edge_vals=None,
             new_owned = jnp.minimum(owned_prev, inc)
             changed = new_owned < owned_prev
             val2 = jax.lax.dynamic_update_slice(val1, new_owned, (j * S,))
-            front, payload, nc = owned_to_front(changed, new_owned, i, S,
-                                                ops=fold_ops)
+            front, payload, nc = owned_to_front(changed, new_owned, i, S)
             st2 = ValueState(val=val2, front=front, payload=payload,
                              front_cnt=nc, it=st.it + 1)
         with jax.named_scope("repro/loop"):
@@ -349,22 +320,13 @@ def make_value_step(engine, graph, i, j, *, relax, edge_vals=None,
     return step
 
 
-def owned_to_front(changed, vals, i, S: int, fill_val=I32_MAX, ops=None):
+def owned_to_front(changed, vals, i, S: int, fill_val=I32_MAX):
     """Changed owned rows -> next frontier, canonical ascending.
 
     Owned local row j*S + t converts to local col i*S + t (paper ROW2COL).
     Returns (front (S,) col ids pad -1, payload (S,) aligned, cnt).
-
-    ops: optional fold-kernel bundle replacing the argsort (bit-identical).
     """
     t = jnp.arange(S, dtype=jnp.int32)
-    if ops is not None:
-        (ts, vs), cnt = ops.compact_rows(changed[None, :],
-                                         (t[None, :], vals[None, :]),
-                                         (-1, fill_val))
-        ts, vs = ts[0], vs[0]
-        front = jnp.where(ts >= 0, i * S + ts, -1)      # pads are -1
-        return front, vs, cnt[0]
     key = jnp.where(changed, t, I32_MAX)
     order = jnp.argsort(key)
     ts = key[order]

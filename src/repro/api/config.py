@@ -60,25 +60,8 @@ class BFSConfig:
     beta:        adaptive switch EXIT threshold (back to top-down once the
                  frontier falls below n / beta; beta > alpha gives the
                  hysteresis band that stops boundary thrash).
-    bottomup:    bottom-up kernel implementation (DESIGN.md sec. 11): same
-                 spellings and rules as `expand`, with REPRO_BOTTOMUP as
-                 the environment override.  Every path is bit-identical.
     row_axes /
     col_axes:    mesh axes the processor grid's rows/columns span.
-    expand_fn:   explicit chunk-expansion override for the CSC scan (wins
-                 over `expand` when given).
-    expand:      local-expand implementation (DESIGN.md sec. 9):
-                 "pallas" (the fused kernel, compiled), "pallas-interpret"
-                 (the same kernel body in interpret mode, for CPU testing),
-                 "reference" (the inline jnp scan), or "auto" (Pallas on
-                 GPU/TPU, reference on CPU; the REPRO_EXPAND environment
-                 variable overrides, so CI can force pallas-interpret).
-                 Every path is bit-identical.
-    fold:        fold-pipeline implementation (DESIGN.md sec. 10): the
-                 codec encode/decode kernels and the prefix-sum compaction
-                 that replaces the per-level argsorts.  Same spellings and
-                 rules as `expand`, with REPRO_FOLD as the environment
-                 override.  Every path is bit-identical.
     exchange:    fold exchange strategy (DESIGN.md sec. 14): "flat" (one
                  all_to_all per fold -- every column sends C-1 direct
                  messages), "butterfly" (log2(C) pairwise ppermute stages
@@ -89,7 +72,7 @@ class BFSConfig:
                  is normalised to the resolved name when a session binds
                  the config to a planned grid, so the AOT caches key on the
                  concrete strategy.  Outputs are bit-identical across
-                 strategies for every codec, program and expand/fold path.
+                 strategies for every codec and program.
     telemetry:   per-level trace channel (DESIGN.md sec. 13).  When True,
                  every search also returns a `repro.obs.LevelTrace` (per
                  level: global + per-device frontier counts, scanned edges,
@@ -119,10 +102,6 @@ class BFSConfig:
     beta: int = 64
     row_axes: tuple = ("r",)
     col_axes: tuple = ("c",)
-    expand_fn: Any = None
-    expand: str = "auto"
-    fold: str = "auto"
-    bottomup: str = "auto"
     exchange: str = "flat"
     telemetry: bool = False
     fault_tolerance: bool = False
@@ -156,27 +135,15 @@ class BFSConfig:
 
     @property
     def expand_path(self) -> str:
-        """The concrete expand implementation this config selects NOW
-        ("auto" resolves against REPRO_EXPAND and the default backend)."""
-        from repro.kernels.select import resolve_expand_path
-
-        return resolve_expand_path(self.expand)
+        """Always "reference": the scan has one implementation.  Kept, with
+        `fold_path`, only because the benchmark's planning log line
+        (`bench/harness.py`) prints both."""
+        return "reference"
 
     @property
     def fold_path(self) -> str:
-        """The concrete fold implementation this config selects NOW
-        ("auto" resolves against REPRO_FOLD and the default backend)."""
-        from repro.kernels.select import resolve_fold_path
-
-        return resolve_fold_path(self.fold)
-
-    @property
-    def bottomup_path(self) -> str:
-        """The concrete bottom-up implementation this config selects NOW
-        ("auto" resolves against REPRO_BOTTOMUP and the default backend)."""
-        from repro.kernels.select import resolve_bottomup_path
-
-        return resolve_bottomup_path(self.bottomup)
+        """Always "reference" (see `expand_path`)."""
+        return "reference"
 
     @property
     def exchange_name(self) -> str:
@@ -203,18 +170,14 @@ class BFSConfig:
         """What makes two configs share one DistBFSEngine (and hence one
         AOT-compile cache line, together with graph shape and batch size).
 
-        Uses the RESOLVED expand/fold/bottomup paths and direction MODE, so
-        "auto" configs re-key correctly if REPRO_EXPAND / REPRO_FOLD /
-        REPRO_BOTTOMUP changes between engine builds in one process.
+        Uses the direction MODE, so the spellings of one mode share a key.
         `exchange` keys by name; exchange="auto" needs the planned grid to
         resolve, so `GraphSession` normalises it (via `resolve_exchange`)
         before any cache is keyed."""
         return (self.codec_name, self.direction_mode, self.edge_chunk,
                 self.dedup, self.max_levels, self.alpha, self.beta,
-                self.row_axes, self.col_axes, self.expand_fn,
-                self.expand_path, self.fold_path, self.bottomup_path,
-                self.exchange_name, self.telemetry,
-                self.fault_tolerance, self.ckpt_every)
+                self.row_axes, self.col_axes, self.exchange_name,
+                self.telemetry, self.fault_tolerance, self.ckpt_every)
 
     def algo_engine_key(self, program_key: tuple, codec_name: str,
                         max_levels: int | None) -> tuple:
@@ -223,13 +186,10 @@ class BFSConfig:
         bakes in.  `codec_name`/`max_levels` are per-call (the program's
         codec hint / iteration bound may override the BFS spellings).
         Direction mode / alpha / beta ride in via `program_key` (the
-        DirectionProgram wrapper's key); the resolved bottom-up kernel path
-        is an engine knob, so it keys here."""
+        DirectionProgram wrapper's key)."""
         return ("algo", program_key, codec_name, self.edge_chunk, self.dedup,
-                max_levels, self.row_axes, self.col_axes, self.expand_fn,
-                self.expand_path, self.fold_path, self.bottomup_path,
-                self.exchange_name, self.telemetry,
-                self.fault_tolerance, self.ckpt_every)
+                max_levels, self.row_axes, self.col_axes, self.exchange_name,
+                self.telemetry, self.fault_tolerance, self.ckpt_every)
 
     def resolve_grid(self, n: int, mesh=None) -> Grid2D:
         """Concretise the `grid` spelling against n vertices (padding up)."""

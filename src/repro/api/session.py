@@ -148,9 +148,8 @@ def build_engine(topology: Topology, config: BFSConfig) -> DistBFSEngine:
                                    alpha=config.alpha, beta=config.beta)
     return DistBFSEngine(
         topology, fold_codec=config.fold_codec, edge_chunk=config.edge_chunk,
-        max_levels=config.max_levels, expand=config.expand,
-        expand_fn=config.expand_fn, fold=config.fold, dedup=config.dedup,
-        bottomup=config.bottomup, exchange=config.exchange, program=program,
+        max_levels=config.max_levels, dedup=config.dedup,
+        exchange=config.exchange, program=program,
         telemetry=config.telemetry, fault_tolerance=config.fault_tolerance,
         ckpt_every=config.ckpt_every)
 
@@ -498,10 +497,7 @@ class GraphSession:
             eng = FrontierEngine(
                 self.graph.topology, program, fold_codec=codec,
                 edge_chunk=self.config.edge_chunk, max_levels=max_levels,
-                expand=self.config.expand, expand_fn=self.config.expand_fn,
-                fold=self.config.fold, dedup=self.config.dedup,
-                bottomup=self.config.bottomup,
-                exchange=self.config.exchange,
+                dedup=self.config.dedup, exchange=self.config.exchange,
                 telemetry=self.config.telemetry,
                 fault_tolerance=self.config.fault_tolerance,
                 ckpt_every=self.config.ckpt_every)

@@ -36,8 +36,8 @@ class BFS2D:
     """
 
     def __init__(self, grid: Grid2D, mesh, row_axes=("r",), col_axes=("c",),
-                 edge_chunk: int = 8192, expand_fn=None,
-                 fold_bitmap: bool = None, max_levels: int | None = None,
+                 edge_chunk: int = 8192, fold_bitmap: bool = None,
+                 max_levels: int | None = None,
                  dedup: str = "scatter", fold_codec=None):
         import warnings
 
@@ -50,8 +50,8 @@ class BFS2D:
         fold_codec = resolve_fold_codec(fold_codec, fold_bitmap)
         self.config = BFSConfig(
             grid=grid, fold_codec=fold_codec, edge_chunk=edge_chunk,
-            dedup=dedup, max_levels=max_levels, expand_fn=expand_fn,
-            row_axes=tuple(row_axes), col_axes=tuple(col_axes))
+            dedup=dedup, max_levels=max_levels, row_axes=tuple(row_axes),
+            col_axes=tuple(col_axes))
         self.grid = grid
         self.mesh = mesh
         self.topology = Topology(grid, mesh, row_axes=row_axes,

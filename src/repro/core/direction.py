@@ -3,9 +3,9 @@
 Direction optimisation (Beamer et al. [7] + [20]) is now a first-class mode
 of the frontier engine: `BFSConfig(direction=True | "adaptive" | "bottomup")`
 routes BFS -- and CC / SSSP / multi-source BFS -- through the
-`repro.algos.direction.DirectionProgram` wrapper, whose fused bottom-up
-kernels live in `repro.kernels.bottomup` (DESIGN.md sec. 11).  Nothing on
-the hot path imports this module any more.
+`repro.algos.direction.DirectionProgram` wrapper, whose bottom-up parent
+search lives in `repro.core.frontier` (DESIGN.md sec. 11).  Nothing on the
+hot path imports this module any more.
 
 `BFS2DDirection` remains as a deprecated drop-in for pre-session callers; it
 is a thin veneer over `BFSConfig(direction=True)` on a `GraphSession`.

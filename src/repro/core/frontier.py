@@ -1,9 +1,8 @@
 """Device-local frontier expansion / update (paper sec. 3.4, 3.5).
 
-Everything here is pure jnp with static shapes and is the REFERENCE path; the
-fused Pallas pipeline in `repro.kernels.expand` implements the same contracts
-for the hot tiles (`make_expand_fn` is the drop-in switch; engines select it
-via `BFSConfig(expand=...)`, DESIGN.md sec. 9).
+Everything here is pure jnp with static shapes: the one implementation of
+the scan, the filter and the bottom-up parent search, on every backend
+(DESIGN.md sec. 9-11).
 
 Adaptation notes (DESIGN.md sec. 3):
   * `atomicOr` visited dedup      -> scatter-min "winner" selection (the first
@@ -39,10 +38,9 @@ def exclusive_cumsum(x):
 
 def pick_tile(e: int, tile: int) -> int:
     """Largest DIVISOR of the chunk length <= tile.  Never rounds UP to e:
-    a tile is a dense (tile, window) or (tile, tile) compare, so one e-wide
-    tile on a big odd chunk would be quadratic in the chunk.  Both
-    arguments are static (e is the engine's edge_chunk), so this runs at
-    trace time."""
+    a tile is a dense (tile, window) compare, so one e-wide tile on a big
+    odd chunk would be as wide as the chunk.  Both arguments are static
+    (e is the engine's edge_chunk), so this runs at trace time."""
     t = min(tile, e)
     while e % t:
         t -= 1
@@ -66,8 +64,7 @@ def map_workload_tile(gid, cumul, *, window: int, n_cumul: int,
     and the compares that count k also pick each lane's values at k.  The
     result is (k, vals (n_vals, tile)).
 
-    Operates on values (not refs): the body of the Pallas mapping kernels
-    (`repro.kernels`) and, vmapped over tiles, of `edge_slots`."""
+    Vmapped over tiles, the body of `edge_slots`."""
     g0 = gid[0]
     gmax = gid[-1]
     stride = 1 + n_vals
@@ -169,7 +166,7 @@ def edge_slots(cumul, gids, total, *, n_vals: int = 0, tile: int = TILE,
     `map_workload_tile` per tile of consecutive ids: one binary search per
     tile instead of one per lane.  A per-lane search is log2(len(cumul))
     dependent gathers for every edge, and on a TPU it took most of a
-    level's time (DESIGN.md sec. 9.4).  cumul is clipped with
+    level's time (DESIGN.md sec. 9.2).  cumul is clipped with
     `clip_by_value`.
 
     n_vals > 0: cumul is a `slot_table` (clipped already) of n_vals values
@@ -177,7 +174,7 @@ def edge_slots(cumul, gids, total, *, n_vals: int = 0, tile: int = TILE,
     slot k[t], picked by the window loop's own compares.  On a TPU a
     per-lane gather costs about the same per lane whatever its source's
     size, so a caller that needs a slot's values takes them here
-    (DESIGN.md sec. 9.4).
+    (DESIGN.md sec. 9.2).
 
     The window loop of a tile runs once per `window` cumul entries its ids
     span, and under vmap every tile pays the widest tile's count
@@ -214,20 +211,12 @@ def slot_passes(k, n_cumul: int, *, tile: int = TILE,
     return jnp.max((kt[:, -1] - kt[:, 0] + window - 1) // window)
 
 
-def compact_blocks(vals, cnts, fill=-1, ops=None):
+def compact_blocks(vals, cnts, fill=-1):
     """Concatenate R padded blocks (R, S) with per-block counts into one
-    padded (R*S,) array (valid entries first, order preserved).
-
-    ops: optional fold-kernel bundle (`repro.kernels.fold`) whose prefix-sum
-    compaction replaces the argsort; None = the reference path.  Both are
-    bit-identical (the output is fully determined by the mask)."""
+    padded (R*S,) array (valid entries first, order preserved)."""
     R, S = vals.shape
     mask = jnp.arange(S, dtype=jnp.int32)[None, :] < cnts[:, None]
     total = jnp.sum(cnts, dtype=jnp.int32)
-    if ops is not None:
-        (out,), _ = ops.compact_rows(mask.reshape(1, -1),
-                                     (vals.reshape(1, -1),), (fill,))
-        return out[0], total
     flat_v = vals.reshape(-1)
     flat_m = mask.reshape(-1)
     order = jnp.argsort(~flat_m, stable=True)
@@ -359,18 +348,13 @@ def frontier_workload(all_front, front_total, col_off):
 
 
 def reference_expand_chunk(gids, total, table, row_idx):
-    """One chunk of the paper's column scan in plain jnp -- THE reference
-    map/gather formulas, single source of truth.  Shared by
-    `expand_frontier`'s inline path, `repro.algos.program.scan_relax` and
-    `repro.kernels.expand.local_expand(path="reference")`; the fused Pallas
-    kernel mirrors these formulas lane for lane (the bit-identity contract,
-    DESIGN.md sec. 9) -- edit them HERE or the paths diverge.
+    """One chunk of the paper's column scan: the map/gather formulas that
+    `expand_frontier` and `repro.algos.program.scan_relax` share.
 
     total, table: the level's `frontier_workload`.  The slot search picks
     each lane's frontier column u and address base along with its slot k,
     so the CSC row id v = row_idx[base + gid] is the one per-lane gather:
-    u = front[k] and addr = col_off[u] + gid - cumul[k], as the kernel
-    computes them.
+    u = front[k] and addr = col_off[u] + gid - cumul[k].
 
     Returns (v, u, k, addr, valid): candidate local rows (masked lanes
     -> 0), parent frontier cols, frontier slot index, clipped CSC edge
@@ -403,12 +387,9 @@ def test_bit_blocks(words, c, block: int):
 
 def reference_bottomup_chunk(gids, cumul, total, row_off, col_idx, words, *,
                              block: int):
-    """One chunk of the bottom-up parent search in plain jnp -- THE
-    reference formulas, single source of truth (the CSR mirror of
-    `reference_expand_chunk`).  Shared by the bottom-up step's inline path
-    and `repro.kernels.bottomup`; the fused Pallas kernel mirrors these
-    formulas lane for lane (the bit-identity contract, DESIGN.md sec. 11)
-    -- edit them HERE or the paths diverge.
+    """One chunk of the bottom-up parent search (the CSR mirror of
+    `reference_expand_chunk`), shared by the BFS bottom-up step and the
+    value programs' pull scan (`reference_bottomup_values_chunk`).
 
     gids index the masked-degree workload: `cumul` is the exclusive cumsum
     of per-row degrees with VISITED rows zeroed, so the scan walks only
@@ -443,17 +424,6 @@ def reference_bottomup_values_chunk(gids, cumul, total, row_off, col_idx,
     return r, pay, addr, hit
 
 
-def set_bits(words, v, take):
-    """Set bit v[take] in the packed uint32 bitmap (the incremental twin of
-    `pack_bitmap`): callers guarantee the taken v are DISTINCT and their
-    bits currently unset (winner_dedup output on unvisited candidates), so
-    a scatter-add of single-bit values is an exact atomicOr."""
-    nw = words.shape[0]
-    bit = jnp.uint32(1) << (v & 31).astype(jnp.uint32)
-    return words.at[jnp.where(take, v >> 5, nw)].add(
-        jnp.where(take, bit, jnp.uint32(0)), mode="drop")
-
-
 class ExpandResult(NamedTuple):
     visited: jax.Array
     level: jax.Array
@@ -469,22 +439,14 @@ class ExpandResult(NamedTuple):
 
 def expand_frontier(col_off, row_idx, visited, level, pred, all_front,
                     front_total, lvl, *, grid: Grid2D, i, j,
-                    edge_chunk: int = 8192, expand_fn=None,
-                    dedup: str = "scatter",
+                    edge_chunk: int = 8192, dedup: str = "scatter",
                     count_passes: bool = False) -> ExpandResult:
     """Scan the CSC columns of the gathered frontier (paper Alg. 3).
 
     all_front: (n_cols_local,) local col indices (valid first `front_total`).
     i, j: this device's grid coordinates (traced or static).
-    expand_fn: optional kernel override mapping
-        (gids, cumul, all_front, front_total, col_off, row_idx, visited)
-        -> (v, unvisited_mask, u) for one chunk (the Pallas path).  A
-        closure carrying `accepts_words = True` additionally receives
-        `words=` -- the packed visited bitmap this loop then maintains
-        INCREMENTALLY (one O(n_rows) pack per level instead of per chunk).
-    count_passes: static; count the reference map's window passes into
-        `map_passes` (the telemetry channel).  Off, the program holds no
-        counter; a kernel's passes (expand_fn) are not counted.
+    count_passes: static; count the map's window passes into `map_passes`
+        (the telemetry channel).  Off, the program holds no counter.
     """
     n_rows = visited.shape[0]
     S, C = grid.S, grid.C
@@ -495,34 +457,21 @@ def expand_frontier(col_off, row_idx, visited, level, pred, all_front,
 
     dst = jnp.full((C, S), -1, jnp.int32)
     dst_cnt = jnp.zeros((C,), jnp.int32)
-    use_words = bool(getattr(expand_fn, "accepts_words", False))
-    words = pack_bitmap(visited) if use_words \
-        else jnp.zeros((1,), jnp.uint32)               # pytree placeholder
-    count_passes = count_passes and expand_fn is None
 
     # The chunk body's three parts run under their own scopes, nested in
     # the caller's layer scope (DESIGN.md sec. 13.4): `map` (edge slot ->
     # frontier column -> row id), `filter` (visited test and first-claimant
     # selection), `mark` (visited / pred / level scatters, bucket append).
     def chunk_body(state):
-        start, visited, words, level, pred, dst, dst_cnt = state[:7]
+        start, visited, level, pred, dst, dst_cnt = state[:6]
         gids = start + jnp.arange(edge_chunk, dtype=jnp.int32)
         with jax.named_scope("map"):
-            if expand_fn is None:
-                v, u, k, _, valid = reference_expand_chunk(
-                    gids, total, table, row_idx)
-                if count_passes:
-                    passes = state[7] + slot_passes(k, cumul.shape[0])
-            elif use_words:
-                v, unvis, u = expand_fn(gids, cumul, all_front, front_total,
-                                        col_off, row_idx, visited,
-                                        words=words)
-            else:
-                v, unvis, u = expand_fn(gids, cumul, all_front, front_total,
-                                        col_off, row_idx, visited)
+            v, u, k, _, valid = reference_expand_chunk(
+                gids, total, table, row_idx)
+            if count_passes:
+                passes = state[6] + slot_passes(k, cumul.shape[0])
         with jax.named_scope("filter"):
-            if expand_fn is None:
-                unvis = valid & ~visited[v]
+            unvis = valid & ~visited[v]
             win = winner_dedup(v, unvis, n_rows, method=dedup)
         with jax.named_scope("mark"):
             # mark visited (paper: atomicOr on the full-local-row bitmap --
@@ -530,8 +479,6 @@ def expand_frontier(col_off, row_idx, visited, level, pred, all_front,
             # search)
             visited = visited.at[jnp.where(win, v, n_rows)].set(
                 True, mode="drop")
-            if use_words:
-                words = set_bits(words, v, win)
             # predecessor: global parent id, stored also for remote rows
             # (deferred resolution, paper sec. 3.5 / [2])
             pg = (j * ncl + u).astype(jnp.int32)
@@ -543,22 +490,22 @@ def expand_frontier(col_off, row_idx, visited, level, pred, all_front,
             level = level.at[jnp.where(is_local, v, n_rows)].set(
                 jnp.where(is_local, lvl, 0), mode="drop")
             dst, dst_cnt = bucket_append(dst, dst_cnt, v, m, win, C)
-        out = (start + edge_chunk, visited, words, level, pred, dst, dst_cnt)
+        out = (start + edge_chunk, visited, level, pred, dst, dst_cnt)
         return out + (passes,) if count_passes else out
 
     def chunk_cond(state):
         return state[0] < total
 
-    init = (jnp.int32(0), visited, words, level, pred, dst, dst_cnt)
+    init = (jnp.int32(0), visited, level, pred, dst, dst_cnt)
     if count_passes:
         init += (jnp.int32(0),)
     out = jax.lax.while_loop(chunk_cond, chunk_body, init)
-    _, visited, _, level, pred, dst, dst_cnt = out[:7]
+    _, visited, level, pred, dst, dst_cnt = out[:6]
     # per-level count reported unsigned: one level's local scan is bounded by
     # the int32-indexable local nnz, but the SUM across levels/devices is not
     return ExpandResult(visited, level, pred, dst, dst_cnt,
                         total.astype(jnp.uint32),
-                        out[7] if count_passes else jnp.int32(0))
+                        out[6] if count_passes else jnp.int32(0))
 
 
 class UpdateResult(NamedTuple):

@@ -56,23 +56,11 @@ class DistBFSEngine(FrontierEngine):
     ----------
     topo:         Topology binding the processor grid to mesh axes.
     fold_codec:   "list" | "bitmap" | "delta" | FoldCodec instance.
-    expand:       local-expand implementation ("reference" | "pallas" |
-                  "pallas-interpret" | "auto"; DESIGN.md sec. 9) -- the
-                  fused Pallas pipeline vs the inline jnp scan,
-                  bit-identical either way.
-    expand_fn:    explicit chunk-expansion override (wins over `expand`).
-    fold:         fold-pipeline implementation (same spellings; DESIGN.md
-                  sec. 10) -- codec encode/decode kernels + the prefix-sum
-                  compaction, REPRO_FOLD override, bit-identical paths.
     dedup:        winner-selection method ("scatter" | "sort").
     exchange:     fold exchange strategy ("flat" | "butterfly" | "auto" |
                   an ExchangeStrategy instance; DESIGN.md sec. 14) -- how
                   fold messages route within the processor-row,
                   bit-identical either way.
-    bottomup:     bottom-up kernel implementation for direction-optimised
-                  programs (same spellings; DESIGN.md sec. 11) -- the fused
-                  parent search, REPRO_BOTTOMUP override, bit-identical
-                  paths.
     step_factory: optional `(engine, graph, extra, i, j, topdown) -> step`
                   hook replacing the default top-down per-level step.
     n_extra:      number of extra per-device (R, C, ...) graph arrays the
@@ -85,9 +73,8 @@ class DistBFSEngine(FrontierEngine):
 
     def __init__(self, topo: Topology, *, fold_codec="list",
                  edge_chunk: int = 8192, max_levels: int | None = None,
-                 expand: str = "auto", expand_fn=None, fold: str = "auto",
-                 dedup: str = "scatter", bottomup: str = "auto",
-                 exchange="flat", step_factory=None, n_extra: int = 0,
+                 dedup: str = "scatter", exchange="flat",
+                 step_factory=None, n_extra: int = 0,
                  program=None, telemetry: bool = False,
                  fault_tolerance: bool = False, ckpt_every: int = 1):
         from repro.algos.bfs import BFSLevelsProgram
@@ -100,8 +87,7 @@ class DistBFSEngine(FrontierEngine):
         super().__init__(
             topo, program,
             fold_codec=fold_codec, edge_chunk=edge_chunk,
-            max_levels=max_levels, expand=expand, expand_fn=expand_fn,
-            fold=fold, dedup=dedup, bottomup=bottomup, exchange=exchange,
+            max_levels=max_levels, dedup=dedup, exchange=exchange,
             telemetry=telemetry, fault_tolerance=fault_tolerance,
             ckpt_every=ckpt_every)
 

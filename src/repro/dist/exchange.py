@@ -36,11 +36,6 @@ and (b) the next frontier is the owned rows with level == lvl, ascending
 on per-sender ordering in a decoder.  `delta` requires S <= 65536 so every
 gap fits in a uint16; larger blocks would need an escape word, which this
 repro does not implement (`get_fold_codec` names the codecs that DO work).
-
-The device-side encode/decode/compaction stages take an optional `ops`
-bundle -- `repro.kernels.fold.make_fold_ops` when the engine resolved a
-Pallas fold path (`BFSConfig(fold=...)`, DESIGN.md sec. 10), `None` for the
-reference jnp formulas.  Both are bit-identical.
 """
 from __future__ import annotations
 
@@ -51,21 +46,19 @@ from repro.core import frontier as F
 from repro.core.types import Grid2D
 
 
-def expand_exchange(front, front_cnt, *, topo, ops=None):
+def expand_exchange(front, front_cnt, *, topo):
     """Gather the frontiers of the processor-column (paper line 13).
 
     Returns (all_front (n_cols_local,), front_total) -- valid entries first,
-    grid-row order preserved.  ops: optional fold-kernel bundle for the
-    compaction (None = reference argsort).
+    grid-row order preserved.
     """
     R, S = topo.grid.R, topo.grid.S
     af = topo.row_gather(front).reshape(R, S)
     ac = topo.row_gather(front_cnt).reshape(R)
-    return F.compact_blocks(af, ac, ops=ops)
+    return F.compact_blocks(af, ac)
 
 
-def expand_exchange_values(front, front_cnt, payload, *, topo, fill=0,
-                           ops=None):
+def expand_exchange_values(front, front_cnt, payload, *, topo, fill=0):
     """`expand_exchange` with an aligned per-vertex payload channel
     (frontier programs: the vertex's label / distance / source id).
 
@@ -79,11 +72,6 @@ def expand_exchange_values(front, front_cnt, payload, *, topo, fill=0,
     ap = topo.row_gather(payload).reshape(R, S)
     mask = jnp.arange(S, dtype=jnp.int32)[None, :] < ac[:, None]
     total = jnp.sum(ac, dtype=jnp.int32)
-    if ops is not None:
-        (fr, pl), _ = ops.compact_rows(
-            mask.reshape(1, -1), (af.reshape(1, -1), ap.reshape(1, -1)),
-            (-1, fill))
-        return fr[0], pl[0], total
     flat_m = mask.reshape(-1)
     order = jnp.argsort(~flat_m, stable=True)
     valid = flat_m[order]
@@ -145,13 +133,13 @@ class FoldCodec:
 
     Every fold (set or value) is ONE `col_all_to_all` of one fused message
     (counts in a header word, values appended) -- see the byte table in the
-    module docstring.  `ops` is the optional fold-kernel bundle
-    (`repro.kernels.fold`); None = the reference jnp formulas.
+    module docstring.
     """
     name = "?"
 
-    def __init__(self, grid: Grid2D = None, ops=None):
-        self._ops = ops
+    def __init__(self, grid: Grid2D = None):
+        """A codec that cannot run at `grid`'s block size raises a
+        ValueError here (`get_fold_codec` names the ones that can)."""
 
     def wire_bytes(self, grid: Grid2D) -> int:
         """Bytes this device SENDS on one fused set-fold message."""
@@ -222,7 +210,7 @@ class BitmapFold(FoldCodec):
         return grid.C * 4 * ((grid.S + 31) // 32)
 
     @staticmethod
-    def encode(dst, dst_cnt, S: int, ops=None):
+    def encode(dst, dst_cnt, S: int):
         """(C, S) id buckets -> (C, ceil(S/32)) uint32 bit words."""
         C = dst.shape[0]
         valid = dst >= 0
@@ -230,20 +218,11 @@ class BitmapFold(FoldCodec):
         onehot = jnp.zeros((C, S), bool).at[
             rowsel.reshape(-1), jnp.where(valid, dst % S, 0).reshape(-1)
         ].set(True, mode="drop")
-        if ops is not None:
-            return ops.pack_bits(onehot)
         return F.pack_bitmap(onehot)
 
     @staticmethod
-    def decode(words, j, S: int, ops=None):
+    def decode(words, j, S: int):
         """(C, W) received words -> ascending owned rows j*S + t per sender."""
-        if ops is not None:
-            recv_mask = ops.unpack_bits(words, S)
-            C = recv_mask.shape[0]
-            rows = jnp.broadcast_to(
-                j * S + jnp.arange(S, dtype=jnp.int32)[None, :], (C, S))
-            (int_verts,), cnt = ops.compact_rows(recv_mask, (rows,), (-1,))
-            return int_verts, cnt
         recv_mask = F.unpack_bitmap(words, S)          # [m, t]: from sender m
         C = recv_mask.shape[0]
         rows = jnp.broadcast_to(
@@ -255,19 +234,19 @@ class BitmapFold(FoldCodec):
 
     def fold(self, dst, dst_cnt, *, topo, j):
         C, S = topo.grid.C, topo.grid.S
-        words = topo.col_all_to_all(self.encode(dst, dst_cnt, S, self._ops))
-        return self.decode(words.reshape(C, -1), j, S, self._ops)
+        words = topo.col_all_to_all(self.encode(dst, dst_cnt, S))
+        return self.decode(words.reshape(C, -1), j, S)
 
     def fold_values(self, ids, cnt, vals, *, topo, j):
         # decode delivers ascending front-packed rows -- exactly the
         # canonical order the ids (and hence the values channel) arrived in
         C, S = topo.grid.C, topo.grid.S
-        words = self.encode(ids, cnt, S, self._ops)
+        words = self.encode(ids, cnt, S)
         W = words.shape[1]
         msg = jnp.concatenate(
             [words, jax.lax.bitcast_convert_type(vals, jnp.uint32)], axis=1)
         recv = topo.col_all_to_all(msg).reshape(C, W + S)
-        ri, rc = self.decode(recv[:, :W], j, S, self._ops)
+        ri, rc = self.decode(recv[:, :W], j, S)
         rv = jax.lax.bitcast_convert_type(recv[:, W:], jnp.int32)
         return ri, rc, rv
 
@@ -281,36 +260,30 @@ class DeltaFold(FoldCodec):
     rides a two-uint16 header (one 32-bit word) ahead of the gaps."""
     name = "delta"
 
-    def __init__(self, grid: Grid2D = None, ops=None):
+    def __init__(self, grid: Grid2D = None):
         if grid is not None and grid.S > (1 << 16):
             raise ValueError(
                 f"delta fold needs S <= 65536 (16-bit gaps), got S={grid.S}")
-        super().__init__(grid, ops)
 
     def wire_bytes(self, grid: Grid2D) -> int:
         return grid.C * (2 * grid.S + 4)
 
     @staticmethod
-    def encode(dst, dst_cnt, S: int, ops=None):
+    def encode(dst, dst_cnt, S: int):
         """(C, S) id buckets -> (C, S) uint16 ascending first-order gaps
         (slot 0 is the absolute first offset)."""
         C = dst.shape[0]
         valid = jnp.arange(S, dtype=jnp.int32)[None, :] < dst_cnt[:, None]
         t = jnp.where(valid, dst % S, F.I32_MAX)
         ts = jnp.sort(t, axis=1)                  # valid entries sort first
-        if ops is not None:
-            return ops.delta_gaps(ts, valid)
         prev = jnp.concatenate(
             [jnp.zeros((C, 1), jnp.int32), ts[:, :-1]], axis=1)
         return jnp.where(valid, ts - prev, 0).astype(jnp.uint16)
 
     @staticmethod
-    def decode(gaps, cnt, j, S: int, ops=None):
+    def decode(gaps, cnt, j, S: int):
         """(C, S) uint16 gaps + (C,) counts -> owned rows j*S + t."""
-        if ops is not None:
-            vals = ops.delta_positions(gaps)
-        else:
-            vals = jnp.cumsum(gaps.astype(jnp.int32), axis=1)
+        vals = jnp.cumsum(gaps.astype(jnp.int32), axis=1)
         valid = jnp.arange(S, dtype=jnp.int32)[None, :] < cnt[:, None]
         return jnp.where(valid, j * S + vals, -1), cnt
 
@@ -327,22 +300,21 @@ class DeltaFold(FoldCodec):
     def fold(self, dst, dst_cnt, *, topo, j):
         C, S = topo.grid.C, topo.grid.S
         msg = jnp.concatenate(
-            [self._header(dst_cnt), self.encode(dst, dst_cnt, S, self._ops)],
-            axis=1)
+            [self._header(dst_cnt), self.encode(dst, dst_cnt, S)], axis=1)
         recv = topo.col_all_to_all(msg).reshape(C, S + 2)
         cnt = self._read_header(recv[:, :2])
-        return self.decode(recv[:, 2:], cnt, j, S, self._ops)
+        return self.decode(recv[:, 2:], cnt, j, S)
 
     def fold_values(self, ids, cnt, vals, *, topo, j):
         # encode sorts per bucket; canonical input is already sorted, so the
         # delivered order equals the sent order and the values align
         C, S = topo.grid.C, topo.grid.S
         msg = jnp.concatenate(
-            [self._header(cnt), self.encode(ids, cnt, S, self._ops),
+            [self._header(cnt), self.encode(ids, cnt, S),
              _i32_to_u16(vals)], axis=1)
         recv = topo.col_all_to_all(msg).reshape(C, 2 + 3 * S)
         rc = self._read_header(recv[:, :2])
-        ri, _ = self.decode(recv[:, 2:2 + S], rc, j, S, self._ops)
+        ri, _ = self.decode(recv[:, 2:2 + S], rc, j, S)
         rv = _u16_to_i32(recv[:, 2 + S:])
         return ri, rc, rv
 
@@ -350,15 +322,12 @@ class DeltaFold(FoldCodec):
 FOLD_CODECS = {"list": ListFold, "bitmap": BitmapFold, "delta": DeltaFold}
 
 
-def get_fold_codec(spec, grid: Grid2D, ops=None) -> FoldCodec:
+def get_fold_codec(spec, grid: Grid2D) -> FoldCodec:
     """Resolve "list" | "bitmap" | "delta" | FoldCodec instance.
 
-    ops: optional fold-kernel bundle (`repro.kernels.fold.make_fold_ops`)
-    threaded into the constructed codec's encode/decode stages; ignored for
-    pre-built FoldCodec instances.  A codec that cannot operate at this
-    grid's block size (delta needs S <= 65536) raises a ValueError naming
-    the codecs that DO work -- surfaced unchanged through
-    `GraphSession`/`BFSConfig`.
+    A codec that cannot operate at this grid's block size (delta needs
+    S <= 65536) raises a ValueError naming the codecs that DO work --
+    surfaced unchanged through `GraphSession`/`BFSConfig`.
     """
     if isinstance(spec, FoldCodec):
         return spec
@@ -368,14 +337,14 @@ def get_fold_codec(spec, grid: Grid2D, ops=None) -> FoldCodec:
         raise ValueError(
             f"unknown fold codec {spec!r}; choose from {sorted(FOLD_CODECS)}")
     try:
-        return cls(grid, ops)
+        return cls(grid)
     except ValueError as e:
         working = []
         for name, other in FOLD_CODECS.items():
             if name == spec:
                 continue
             try:
-                other(grid, ops)
+                other(grid)
             except ValueError:
                 continue
             working.append(name)

@@ -25,8 +25,8 @@ Per level, per device, the carry records:
   dir         direction the level ran (0 top-down / 1 bottom-up)
   map_passes  window passes of the top-down map's slot search this level
               on this device (`frontier.slot_passes` summed over chunks;
-              0 on bottom-up levels, in value programs and on a kernel
-              path): what picking slot values in the search costs
+              0 on bottom-up levels and in value programs): what picking
+              slot values in the search costs
 
 The stamps are work counters, not wall times: inside one compiled program
 there is no host clock, and counters are what the paper's Fig. 5/6 plot

@@ -3,7 +3,7 @@
 A query is one request for one search: a BFS/SSSP root, a CC labelling, or
 a multi-source BFS over a (K,) sources vector.  Requests are admitted into
 per-graph queues, coalesced by `BatchKey` -- same graph, program and config
-(codec, direction mode, kernel paths all ride in `BFSConfig`, which is
+(codec, direction mode and exchange all ride in `BFSConfig`, which is
 frozen/hashable exactly so it can key this) -- and executed through the
 resident graph's AOT-cached batched programs.  The caller holds a
 `QueryTicket` and blocks on `result()`; the scheduler demuxes each batch

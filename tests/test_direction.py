@@ -4,17 +4,16 @@
     between direction=False, "adaptive" and "bottomup" under every fold
     codec (levels, preds, labels, dists, sources and n_levels; NOT
     edges_scanned -- bottom-up legitimately scans a different edge set);
-  * the fused bottom-up chunk kernels (plain + value-carrying) agree
-    BIT-EXACTLY with the frontier.py references on random inputs, including
-    empty/full frontier bitmaps and a block size not divisible by 32;
+  * the bottom-up chunk formulas (plain + value-carrying) in frontier.py
+    equal a numpy parent search on random inputs, including empty/full
+    frontier bitmaps and a block size not divisible by 32;
   * a hypothesis property drives whole searches on random n=37 graphs
     (S % 32 != 0) through all three modes -- plus deterministic star /
     path / isolated-root versions so the gate holds without hypothesis;
   * the adaptive switch lives INSIDE the compiled loop: one trace for a
     64-root sweep, and the per-level direction trace shows both a top-down
     and a bottom-up level on RMAT (the alpha/beta crossover);
-  * the selection rules: "auto" resolution, the REPRO_BOTTOMUP override,
-    and engine-cache keying by the RESOLVED path + direction mode;
+  * engine-cache keying by direction mode, alpha and beta;
   * the deprecated `BFS2DDirection` shim warns and matches the session.
 """
 import numpy as np
@@ -33,8 +32,6 @@ from repro.core.partition import partition_2d, partition_2d_csr
 from repro.core.types import LocalGraph2D
 from repro.dist.topology import Topology
 from repro.graphgen import rmat_edges, build_csc
-from repro.kernels import bottomup_chunk, bottomup_chunk_values
-from repro.kernels.select import BOTTOMUP_ENV, resolve_bottomup_path
 from repro.obs.trace import RECORDED_LEVELS
 
 SCALE, EF = 8, 8
@@ -135,13 +132,14 @@ def test_directions_trace_per_mode(graph_data):
 
 
 # ----------------------------------------------------------------------------
-# Kernel-level: fused chunk vs frontier.py reference
+# Chunk-level: frontier.py's bottom-up formulas vs a numpy parent search
 # ----------------------------------------------------------------------------
 
 def _bottomup_inputs(rng, nrl, ncl, block, e_max, frontier_frac):
     """Random CSR + frontier bitmap + a MASKED-degree workload (some rows
     'visited', their degree zeroed -- so cumul genuinely diverges from
-    row_off and the addr arithmetic is exercised)."""
+    row_off and the addr arithmetic is exercised).  Also returns the
+    frontier mask the bitmap packs."""
     deg = rng.integers(0, 6, size=nrl)
     row_off = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
     col_idx = rng.integers(0, ncl, size=max(e_max, 1)).astype(np.int32)
@@ -156,51 +154,69 @@ def _bottomup_inputs(rng, nrl, ncl, block, e_max, frontier_frac):
         jnp.asarray(np.where(visited, 0, deg).astype(np.int32))))
     total = np.int32(cumul[-1])
     return (jnp.asarray(row_off), jnp.asarray(col_idx), jnp.asarray(words),
-            jnp.asarray(cumul), total)
+            jnp.asarray(cumul), total, mask)
+
+
+def _np_parent_search(gids, cumul, row_off, col_idx, mask):
+    """Per lane: the row whose masked-degree run holds the lane's edge id
+    (searchsorted), that edge's CSR address and column, and whether the
+    column is in the frontier."""
+    cumul, row_off, col_idx = (np.asarray(x) for x in (cumul, row_off,
+                                                       col_idx))
+    g = np.asarray(gids)
+    r = np.searchsorted(cumul, g, side="right") - 1
+    r = np.clip(r, 0, cumul.shape[0] - 2)
+    addr = np.clip(row_off[r] + g - cumul[r], 0, col_idx.shape[0] - 1)
+    c = col_idx[addr]
+    hit = (g < cumul[-1]) & mask[c]
+    return r, c, addr, hit
 
 
 @pytest.mark.parametrize("block", [37, 64])
 @pytest.mark.parametrize("frontier_frac", [0.0, 0.4, 1.0])
 def test_bottomup_chunk_paths_agree(block, frontier_frac):
-    """reference vs pallas-interpret bit-exact, incl. empty and full
-    bitmaps and S % 32 != 0 (the ragged last word of each block)."""
+    """`reference_bottomup_chunk` equals the numpy parent search, incl.
+    empty and full bitmaps and S % 32 != 0 (the ragged last word of each
+    block)."""
     rng = np.random.default_rng(block * 10 + int(frontier_frac * 10))
     nrl = ncl = 2 * block
-    row_off, col_idx, words, cumul, total = _bottomup_inputs(
+    row_off, col_idx, words, cumul, total, mask = _bottomup_inputs(
         rng, nrl, ncl, block, e_max=6 * nrl, frontier_frac=frontier_frac)
     gids = jnp.arange(128, dtype=jnp.int32)
-    a = reference_bottomup_chunk(gids, cumul, total, row_off, col_idx,
-                                 words, block=block)
-    b = bottomup_chunk(gids, cumul, jnp.int32(total), row_off, col_idx,
-                       words, block=block, interpret=True)
-    _assert_chunks_match(gids, total, a, b)
+    got = reference_bottomup_chunk(gids, cumul, total, row_off, col_idx,
+                                   words, block=block)
+    r, c, _, hit = _np_parent_search(gids, cumul, row_off, col_idx, mask)
+    _assert_chunks_match(gids, total, got, (r, c, hit))
 
 
-def _assert_chunks_match(gids, total, a, b):
+def _assert_chunks_match(gids, total, got, want):
     """hit must match lane-for-lane; the other outputs are only specified on
     live lanes (gid < total) -- out-of-workload lanes carry don't-care row
-    indices in both paths."""
+    indices."""
     live = np.asarray(gids) < int(total)
-    np.testing.assert_array_equal(np.asarray(a[-1]), np.asarray(b[-1]))
-    for x, y in zip(a[:-1], b[:-1]):
+    np.testing.assert_array_equal(np.asarray(got[-1]), np.asarray(want[-1]))
+    for name, x, y in zip(("r", "c/pay", "addr"), got[:-1], want[:-1]):
         np.testing.assert_array_equal(np.where(live, np.asarray(x), 0),
-                                      np.where(live, np.asarray(y), 0))
+                                      np.where(live, np.asarray(y), 0),
+                                      err_msg=name)
 
 
 def test_bottomup_values_chunk_paths_agree():
+    """`reference_bottomup_values_chunk` equals the numpy parent search,
+    with the payload of the lane's column and its CSR edge address."""
     rng = np.random.default_rng(11)
     block = 37
     nrl = ncl = 74
-    row_off, col_idx, words, cumul, total = _bottomup_inputs(
+    row_off, col_idx, words, cumul, total, mask = _bottomup_inputs(
         rng, nrl, ncl, block, e_max=6 * nrl, frontier_frac=0.5)
-    pay = jnp.asarray(rng.integers(0, 1000, size=ncl).astype(np.int32))
+    pay = rng.integers(0, 1000, size=ncl).astype(np.int32)
     gids = jnp.arange(96, dtype=jnp.int32)
-    a = reference_bottomup_values_chunk(gids, cumul, total, row_off, col_idx,
-                                        words, pay, block=block)
-    b = bottomup_chunk_values(gids, cumul, jnp.int32(total), row_off,
-                              col_idx, words, pay, block=block,
-                              interpret=True)
-    _assert_chunks_match(gids, total, a, b)
+    got = reference_bottomup_values_chunk(gids, cumul, total, row_off,
+                                          col_idx, words, jnp.asarray(pay),
+                                          block=block)
+    r, c, addr, hit = _np_parent_search(gids, cumul, row_off, col_idx, mask)
+    assert int(total) > 0 and hit.any()
+    _assert_chunks_match(gids, total, got, (r, pay[c], addr, hit))
 
 
 # ----------------------------------------------------------------------------
@@ -294,38 +310,10 @@ def test_modes_agree_edge_cases(mode_runner):
 
 
 # ----------------------------------------------------------------------------
-# Selection rules + cache keying + the deprecated shim
+# Cache keying + the deprecated shim
 # ----------------------------------------------------------------------------
 
-def test_resolve_bottomup_path_rules(monkeypatch):
-    monkeypatch.delenv(BOTTOMUP_ENV, raising=False)
-    assert resolve_bottomup_path("reference") == "reference"
-    assert resolve_bottomup_path("pallas-interpret") == "pallas-interpret"
-    assert resolve_bottomup_path("auto", platform="cpu") == "reference"
-    # the TPU compiler refuses the kernels: auto takes the jnp scan there,
-    # an explicit "pallas" is passed through to the compiler, and the
-    # interpreter is refused off CPU
-    assert resolve_bottomup_path("auto", platform="tpu") == "reference"
-    assert resolve_bottomup_path("pallas", platform="tpu") == "pallas"
-    with pytest.raises(ValueError, match="CPU only"):
-        resolve_bottomup_path("pallas-interpret", platform="tpu")
-    assert resolve_bottomup_path(None, platform="gpu") == "pallas"
-    monkeypatch.setenv(BOTTOMUP_ENV, "pallas-interpret")
-    assert resolve_bottomup_path("auto", platform="cpu") == "pallas-interpret"
-    with pytest.raises(ValueError, match=BOTTOMUP_ENV):
-        resolve_bottomup_path("auto", platform="tpu")
-    # explicit spellings are NOT overridden by the environment
-    assert resolve_bottomup_path("reference") == "reference"
-    monkeypatch.setenv(BOTTOMUP_ENV, "nonsense")
-    with pytest.raises(ValueError, match=BOTTOMUP_ENV):
-        resolve_bottomup_path("auto")
-    monkeypatch.delenv(BOTTOMUP_ENV)
-    with pytest.raises(ValueError, match="bottomup="):
-        resolve_bottomup_path("metal")
-
-
-def test_engine_keys_cover_direction_knobs(monkeypatch):
-    monkeypatch.delenv(BOTTOMUP_ENV, raising=False)
+def test_engine_keys_cover_direction_knobs():
     td = BFSConfig()
     ad = BFSConfig(direction=True)
     assert td.engine_key != ad.engine_key
@@ -333,18 +321,6 @@ def test_engine_keys_cover_direction_knobs(monkeypatch):
     assert ad.engine_key != BFSConfig(direction="bottomup").engine_key
     assert ad.engine_key != BFSConfig(direction=True, alpha=12).engine_key
     assert ad.engine_key != BFSConfig(direction=True, beta=128).engine_key
-    ref = BFSConfig(direction=True, bottomup="reference")
-    pal = BFSConfig(direction=True, bottomup="pallas-interpret")
-    assert ref.engine_key != pal.engine_key
-    # "auto" re-keys when the environment override changes
-    expected = resolve_bottomup_path("auto")
-    assert ad.bottomup_path == expected
-    monkeypatch.setenv(BOTTOMUP_ENV, "pallas-interpret")
-    assert ad.bottomup_path == "pallas-interpret"
-    assert ad.engine_key == pal.engine_key
-    k1 = ad.algo_engine_key(("dir",), "bitmap", 10)
-    monkeypatch.delenv(BOTTOMUP_ENV)
-    assert ad.algo_engine_key(("dir",), "bitmap", 10) != k1
     with pytest.raises(ValueError, match="direction="):
         BFSConfig(direction="sideways").direction_mode
 

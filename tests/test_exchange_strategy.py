@@ -175,12 +175,12 @@ def test_exchange_keys_aot_cache_no_cross_reuse():
     bit-identical across strategies."""
     edges = np.asarray(rmat_edges(jax.random.key(2), 8, 8))
     g = DistGraph.from_edges(
-        edges, BFSConfig(grid=(1, 1), edge_chunk=256, expand="reference"),
+        edges, BFSConfig(grid=(1, 1), edge_chunk=256),
         n=256)
     s_flat = g.session(BFSConfig(grid=(1, 1), edge_chunk=256,
-                                 expand="reference", exchange="flat"))
+                                 exchange="flat"))
     s_bfly = g.session(BFSConfig(grid=(1, 1), edge_chunk=256,
-                                 expand="reference", exchange="butterfly"))
+                                 exchange="butterfly"))
     assert s_flat.engine is not s_bfly.engine
     assert s_flat.engine.exchange.name == "flat"
     assert s_bfly.engine.exchange.name == "butterfly"

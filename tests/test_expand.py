@@ -1,22 +1,21 @@
-"""Fused local-expand pipeline coverage (DESIGN.md sec. 9).
+"""Top-down scan coverage (DESIGN.md sec. 9), against independent numpy
+oracles.
 
-  * `local_expand` reference vs pallas-interpret agree BIT-EXACTLY on random
-    CSC graphs (hypothesis), including empty frontiers, isolated vertices
-    and full-frontier levels -- plus deterministic versions of those edge
-    cases so the gate holds where hypothesis is not installed;
-  * the value-carrying chunk kernel matches `scan_relax`'s inline formulas;
-  * BFS / CC / SSSP / multi-source BFS through the session are bit-identical
-    between expand="reference" and expand="pallas-interpret" under every
-    fold codec (the acceptance gate of the pallas-interpret CI leg);
-  * the selection rules: "auto" resolution, the REPRO_EXPAND override, and
-    engine-cache keying by the RESOLVED path;
-  * `import repro.kernels` stays lazy (no Pallas modules loaded until a
-    kernel symbol is touched).
+  * one `expand_frontier` level on random CSC graphs (hypothesis) equals a
+    host scan: winners are the first unvisited occurrence in CSC scan
+    order, each with its frontier column as parent -- including empty
+    frontiers, isolated vertices and full-frontier levels, with
+    deterministic versions of those edge cases so the gate holds where
+    hypothesis is not installed;
+  * `scan_relax`'s chunk (map, payload pick, edge address) and its
+    min-combined candidates equal a numpy scan;
+  * BFS / CC / SSSP / multi-source BFS through the session equal the
+    host references (`algos/reference.py`, a level-synchronous BFS) under
+    every fold codec;
+  * the tiled slot search and the chunk map against `searchsorted` and
+    four per-lane gathers.
 """
 import functools
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,15 +24,17 @@ import pytest
 
 from _hypothesis_compat import given, settings, st
 
+from repro.algos import cc_reference, multi_bfs_reference, sssp_reference
+from repro.algos.program import scan_relax
 from repro.api import BFSConfig, DistGraph
+from repro.core import frontier as F
+from repro.core.types import Grid2D
 from repro.graphgen import rmat_edges
-from repro.kernels import expand_chunk_values, local_expand
-from repro.kernels.select import EXPAND_ENV, resolve_expand_path
 
 SCALE, EF = 7, 8
 N = 1 << SCALE
 CODECS = ("list", "bitmap", "delta")
-OUT_FIELDS = ("verts", "parents", "count", "visited", "edges_scanned")
+I32_MAX = int(np.iinfo(np.int32).max)
 
 
 def _random_csc(rng, n, max_deg):
@@ -44,18 +45,55 @@ def _random_csc(rng, n, max_deg):
     return col_off, row_idx
 
 
-def _assert_paths_agree(front, cnt, csc, visited, **kw):
-    a = local_expand((front, cnt), csc, visited, path="reference", **kw)
-    b = local_expand((front, cnt), csc, visited, path="pallas-interpret",
-                     **kw)
-    for f in OUT_FIELDS:
-        np.testing.assert_array_equal(np.asarray(getattr(a, f)),
-                                      np.asarray(getattr(b, f)), err_msg=f)
-    return a
+def _host_level(front, cnt, col_off, row_idx, visited):
+    """Winners of one level in CSC scan order, with their parents."""
+    seen, verts, parents = set(), [], []
+    for u in front[:cnt]:
+        for e in range(col_off[u], col_off[u + 1]):
+            v = int(row_idx[e])
+            if not visited[v] and v not in seen:
+                seen.add(v)
+                verts.append(v)
+                parents.append(int(u))
+    scanned = sum(int(col_off[u + 1] - col_off[u]) for u in front[:cnt])
+    return verts, parents, scanned
+
+
+def _assert_level_matches_host(front, cnt, csc, visited, *, edge_chunk,
+                               lvl=3):
+    """One `expand_frontier` level on a 1x1 grid against `_host_level`:
+    visited, level and pred of every row, the discovery bucket (in scan
+    order) and the scanned-edge count."""
+    col_off, row_idx = csc
+    n = visited.shape[0]
+    grid = Grid2D.for_vertices(n, 1, 1)
+    level = np.where(visited, 1, -1).astype(np.int32)
+    pred = np.where(visited, 0, -1).astype(np.int32)
+    out = F.expand_frontier(
+        jnp.asarray(col_off), jnp.asarray(row_idx), jnp.asarray(visited),
+        jnp.asarray(level), jnp.asarray(pred), jnp.asarray(front),
+        jnp.int32(cnt), jnp.int32(lvl), grid=grid, i=0, j=0,
+        edge_chunk=edge_chunk)
+    verts, parents, scanned = _host_level(front, cnt, col_off, row_idx,
+                                          visited)
+    vis_want, lvl_want, pred_want = visited.copy(), level.copy(), pred.copy()
+    vis_want[verts] = True
+    lvl_want[verts] = lvl
+    pred_want[verts] = parents
+    for name, want in (("visited", vis_want), ("level", lvl_want),
+                       ("pred", pred_want)):
+        np.testing.assert_array_equal(np.asarray(getattr(out, name)), want,
+                                      err_msg=name)
+    k = len(verts)
+    assert int(out.dst_cnt[0]) == k
+    np.testing.assert_array_equal(np.asarray(out.dst)[0, :k], verts)
+    assert (np.asarray(out.dst)[0, k:] == -1).all()
+    assert int(out.edges_scanned) == scanned
+    return k, scanned
 
 
 # ----------------------------------------------------------------------------
-# local_expand: reference vs pallas-interpret, property + deterministic
+# One expand_frontier level against the host scan, property + deterministic
 # ----------------------------------------------------------------------------
 
 @given(st.data())
@@ -80,8 +118,8 @@ def test_local_expand_paths_agree_property(data):
     visited = np.zeros(n, bool)
     visited[np.random.default_rng(
         data.draw(st.integers(0, 2**31))).random(n) < 0.3] = True
-    _assert_paths_agree(front, cnt, (col_off, row_idx), visited,
-                        edge_chunk=32, tile=16, window=8)
+    _assert_level_matches_host(front, cnt, (col_off, row_idx), visited,
+                               edge_chunk=32)
 
 
 @pytest.mark.parametrize("kind", ["empty", "isolated", "full"])
@@ -98,15 +136,17 @@ def test_local_expand_paths_agree_edges(kind, rng):
     if cnt:
         front[:] = np.arange(n, dtype=np.int32)
     visited = np.zeros(n, bool)
-    out = _assert_paths_agree(front, cnt, (col_off, row_idx), visited,
-                              edge_chunk=64, tile=32, window=16)
+    found, scanned = _assert_level_matches_host(
+        front, cnt, (col_off, row_idx), visited, edge_chunk=64)
     if kind in ("empty", "isolated"):
-        assert int(out.count) == 0 and int(out.edges_scanned) == 0
+        assert found == 0 and scanned == 0
+    else:
+        assert found > 0 and scanned == int(col_off[-1])
 
 
 def test_local_expand_against_host_reference(rng):
-    """Winners = first unvisited occurrence in CSC scan order, compacted
-    ascending: check against a plain-python scan."""
+    """A sparse level over several chunks: a partial frontier, some rows
+    already visited, chunks narrower than the level."""
     n = 96
     col_off, row_idx = _random_csc(rng, n, 5)
     cnt = 17
@@ -115,27 +155,16 @@ def test_local_expand_against_host_reference(rng):
     front[:cnt] = ids
     visited = np.zeros(n, bool)
     visited[rng.choice(n, 10, replace=False)] = True
-    out = _assert_paths_agree(front, cnt, (col_off, row_idx), visited,
-                              edge_chunk=32, tile=16, window=8)
-    seen, host = set(), {}
-    for u in ids:
-        for e in range(col_off[u], col_off[u + 1]):
-            v = int(row_idx[e])
-            if not visited[v] and v not in seen:
-                seen.add(v)
-                host[v] = int(u)
-    verts = sorted(host)
-    np.testing.assert_array_equal(np.asarray(out.verts)[:len(verts)], verts)
-    np.testing.assert_array_equal(
-        np.asarray(out.parents)[:len(verts)], [host[v] for v in verts])
-    assert int(out.count) == len(verts)
-    assert int(out.edges_scanned) == sum(
-        int(col_off[u + 1] - col_off[u]) for u in ids)
+    found, scanned = _assert_level_matches_host(
+        front, cnt, (col_off, row_idx), visited, edge_chunk=8)
+    assert found > 0 and scanned > 8
 
 
 def test_value_chunk_matches_inline(rng):
-    """The value-carrying kernel must reproduce scan_relax's inline
-    map/gather on every valid lane."""
+    """`scan_relax`'s chunk -- the map of `reference_expand_chunk` and the
+    payload of the lane's frontier slot -- against a numpy searchsorted
+    map on every live lane; and its min-combined candidates against a
+    numpy scan of the frontier's columns."""
     n = 80
     col_off, row_idx = _random_csc(rng, n, 6)
     cnt = 23
@@ -150,115 +179,96 @@ def test_value_chunk_matches_inline(rng):
     total = int(cumul[cnt])
     e = 128
     gids = jnp.arange(e, dtype=jnp.int32)
-    v, pay, addr, valid = expand_chunk_values(
-        gids, jnp.asarray(cumul), jnp.asarray(front), jnp.asarray(payload),
-        jnp.int32(cnt), jnp.asarray(col_off), jnp.asarray(row_idx),
-        tile=32, window=16, interpret=True)
-    k = np.clip(np.searchsorted(cumul, np.arange(e), side="right") - 1,
-                0, n - 1)
-    a_ref = np.clip(col_off[u_safe[k]] + np.arange(e) - cumul[k],
+    _, total_j, table = F.frontier_workload(
+        jnp.asarray(front), jnp.int32(cnt), jnp.asarray(col_off))
+    v, _, k, addr, valid = F.reference_expand_chunk(
+        gids, total_j, table, jnp.asarray(row_idx))
+    pay = jnp.asarray(payload)[k]
+    k_ref = np.clip(np.searchsorted(cumul, np.arange(e), side="right") - 1,
+                    0, n - 1)
+    a_ref = np.clip(col_off[u_safe[k_ref]] + np.arange(e) - cumul[k_ref],
                     0, row_idx.shape[0] - 1)
     ok = np.arange(e) < total
+    assert int(total_j) == total
     np.testing.assert_array_equal(np.asarray(valid), ok)
     np.testing.assert_array_equal(np.asarray(v)[ok], row_idx[a_ref][ok])
-    np.testing.assert_array_equal(np.asarray(pay)[ok], payload[k][ok])
+    np.testing.assert_array_equal(np.asarray(pay)[ok], payload[k_ref][ok])
     np.testing.assert_array_equal(np.asarray(addr)[ok], a_ref[ok])
 
+    w = rng.integers(1, 9, size=row_idx.shape[0]).astype(np.int32)
+    cand, scanned = scan_relax(
+        jnp.asarray(col_off), jnp.asarray(row_idx), jnp.asarray(w),
+        jnp.asarray(front), jnp.asarray(payload), jnp.int32(cnt),
+        lambda p, wt: p + wt, n_rows=n, edge_chunk=32)
+    want = np.full(n, I32_MAX, np.int64)
+    for slot, u in enumerate(ids):
+        for a in range(col_off[u], col_off[u + 1]):
+            x = int(row_idx[a])
+            want[x] = min(want[x], int(payload[slot]) + int(w[a]))
+    np.testing.assert_array_equal(np.asarray(cand), want)
+    assert int(scanned) == total
+
 
 # ----------------------------------------------------------------------------
-# Engine-level parity: every program, every codec (the CI-leg gate)
+# Every program through the session against the host references
 # ----------------------------------------------------------------------------
+
+def bfs_min_parent(edges, n, root):
+    """Level-synchronous BFS whose parent is the SMALLEST frontier vertex
+    with an edge to the child -- the parent the engine elects, since it
+    scans the frontier in ascending order and the first claimant wins."""
+    u, v = (np.asarray(x, np.int64) for x in edges)
+    level = np.full(n, -1, np.int32)
+    pred = np.full(n, -1, np.int32)
+    level[root], pred[root] = 0, root
+    front, lvl = np.asarray([root]), 1
+    while front.size:
+        m = np.isin(u, front) & (level[v] < 0)
+        best = np.full(n, I32_MAX, np.int64)
+        np.minimum.at(best, v[m], u[m])
+        new = np.flatnonzero(best < I32_MAX)
+        level[new], pred[new] = lvl, best[new]
+        front, lvl = new, lvl + 1
+    return level, pred
+
 
 @pytest.fixture(scope="module")
 def graphs():
     edges = np.asarray(rmat_edges(jax.random.key(3), SCALE, EF))
     w = np.random.default_rng(1).integers(1, 256, size=edges.shape[1]) \
         .astype(np.uint8)
-    out = {}
-    for path in ("reference", "pallas-interpret"):
-        out[path] = DistGraph.from_edges(
-            edges, BFSConfig(grid=(1, 1), edge_chunk=256, expand=path),
-            n=N, weights=w)
-    return edges, out
+    graph = DistGraph.from_edges(
+        edges, BFSConfig(grid=(1, 1), edge_chunk=256), n=N, weights=w)
+    return edges, w, graph
 
 
 @pytest.mark.parametrize("codec", CODECS)
 def test_engine_parity_all_programs(graphs, codec):
-    edges, gs = graphs
+    """BFS levels, parents and scanned edges; CC labels; SSSP distances;
+    multi-source levels and sources -- each against its host reference."""
+    edges, w, graph = graphs
     deg = np.bincount(edges[0], minlength=N)
     roots = np.flatnonzero(deg > 0)[[0, 3, 11]]
-    sr = gs["reference"].session(
-        BFSConfig(grid=(1, 1), edge_chunk=256, fold_codec=codec,
-                  expand="reference"))
-    sp = gs["pallas-interpret"].session(
-        BFSConfig(grid=(1, 1), edge_chunk=256, fold_codec=codec,
-                  expand="pallas-interpret"))
-    a, b = sr.bfs(roots), sp.bfs(roots)           # batched sweep parity
-    np.testing.assert_array_equal(np.asarray(a.level), np.asarray(b.level))
-    np.testing.assert_array_equal(np.asarray(a.pred), np.asarray(b.pred))
-    assert a.edges_scanned == b.edges_scanned
-    ca, cb = (s.connected_components(fold_codec=codec) for s in (sr, sp))
-    np.testing.assert_array_equal(np.asarray(ca.labels),
-                                  np.asarray(cb.labels))
-    assert ca.edges_scanned == cb.edges_scanned
-    da, db = (s.sssp(int(roots[1]), fold_codec=codec) for s in (sr, sp))
-    np.testing.assert_array_equal(np.asarray(da.dist), np.asarray(db.dist))
-    assert da.edges_scanned == db.edges_scanned
-    ma, mb = (s.multi_bfs(roots, fold_codec=codec) for s in (sr, sp))
-    np.testing.assert_array_equal(np.asarray(ma.level), np.asarray(mb.level))
-    np.testing.assert_array_equal(np.asarray(ma.src), np.asarray(mb.src))
-    assert ma.edges_scanned == mb.edges_scanned
-
-
-# ----------------------------------------------------------------------------
-# Selection rules + cache keying + lazy import
-# ----------------------------------------------------------------------------
-
-def test_resolve_expand_path_rules(monkeypatch):
-    monkeypatch.delenv(EXPAND_ENV, raising=False)
-    assert resolve_expand_path("reference") == "reference"
-    assert resolve_expand_path("pallas-interpret") == "pallas-interpret"
-    assert resolve_expand_path("auto", platform="cpu") == "reference"
-    # the TPU compiler refuses the kernels: auto takes the jnp scan there,
-    # an explicit "pallas" is passed through to the compiler, and the
-    # interpreter is refused off CPU
-    assert resolve_expand_path("auto", platform="tpu") == "reference"
-    assert resolve_expand_path("pallas", platform="tpu") == "pallas"
-    with pytest.raises(ValueError, match="CPU only"):
-        resolve_expand_path("pallas-interpret", platform="tpu")
-    assert resolve_expand_path(None, platform="gpu") == "pallas"
-    monkeypatch.setenv(EXPAND_ENV, "pallas-interpret")
-    assert resolve_expand_path("auto", platform="cpu") == "pallas-interpret"
-    with pytest.raises(ValueError, match=EXPAND_ENV):
-        resolve_expand_path("auto", platform="tpu")
-    # explicit spellings are NOT overridden by the environment
-    assert resolve_expand_path("reference") == "reference"
-    monkeypatch.setenv(EXPAND_ENV, "nonsense")
-    with pytest.raises(ValueError, match="REPRO_EXPAND"):
-        resolve_expand_path("auto")
-    monkeypatch.delenv(EXPAND_ENV)
-    with pytest.raises(ValueError, match="expand="):
-        resolve_expand_path("cuda-graphs")
-
-
-def test_config_keys_use_resolved_path(monkeypatch):
-    monkeypatch.delenv(EXPAND_ENV, raising=False)
-    ref = BFSConfig(expand="reference")
-    pal = BFSConfig(expand="pallas-interpret")
-    auto = BFSConfig()
-    assert ref.engine_key != pal.engine_key
-    # "auto" resolves against the ambient backend (cpu -> reference, an
-    # accelerator -> pallas); the key must equal the matching explicit one
-    expected = resolve_expand_path("auto")
-    assert auto.expand_path == expected
-    if expected == "reference":
-        assert auto.engine_key == ref.engine_key  # same resolved engine
-    monkeypatch.setenv(EXPAND_ENV, "pallas-interpret")
-    assert auto.expand_path == "pallas-interpret"
-    assert auto.engine_key == pal.engine_key      # env re-keys "auto"
-    k1 = auto.algo_engine_key(("cc",), "bitmap", 10)
-    monkeypatch.delenv(EXPAND_ENV)
-    assert auto.algo_engine_key(("cc",), "bitmap", 10) != k1
+    s = graph.session(BFSConfig(grid=(1, 1), edge_chunk=256,
+                                fold_codec=codec))
+    out = s.bfs(roots)                            # batched sweep
+    for b, root in enumerate(roots):
+        level, pred = bfs_min_parent(edges, N, int(root))
+        np.testing.assert_array_equal(np.asarray(out.level)[b, :N], level)
+        np.testing.assert_array_equal(np.asarray(out.pred)[b, :N], pred)
+        # top-down scans every out-edge of every reached vertex once
+        assert out.edges_scanned[b] == int(deg[level >= 0].sum())
+    cc = s.connected_components(fold_codec=codec)
+    np.testing.assert_array_equal(np.asarray(cc.labels)[:N],
+                                  cc_reference(edges, N))
+    d = s.sssp(int(roots[1]), fold_codec=codec)
+    np.testing.assert_array_equal(np.asarray(d.dist)[:N],
+                                  sssp_reference(edges, w, N, int(roots[1])))
+    m = s.multi_bfs(roots, fold_codec=codec)
+    lref, sref = multi_bfs_reference(edges, N, roots)
+    np.testing.assert_array_equal(np.asarray(m.level)[:N], lref)
+    np.testing.assert_array_equal(np.asarray(m.src)[:N], sref)
+    assert m.edges_scanned == int(deg[lref >= 0].sum())
 
 
 def test_pick_tile_always_divides_chunk():
@@ -400,57 +410,3 @@ def test_expand_frontier_level_matches_four_gathers(rng, monkeypatch):
         np.testing.assert_array_equal(np.asarray(getattr(got, name)),
                                       np.asarray(getattr(want, name)),
                                       err_msg=name)
-
-
-def test_algo_engines_honor_custom_expand_fn(graphs):
-    """config.expand_fn wins over `expand` for ALGO engines too (the
-    documented precedence); value scans then fall back to reference."""
-    from repro.algos import ConnectedComponentsProgram
-
-    _, gs = graphs
-
-    def marker(*a, **k):                          # never called
-        raise AssertionError
-
-    cfg = BFSConfig(grid=(1, 1), edge_chunk=256, expand_fn=marker)
-    sess = gs["reference"].session(cfg)
-    eng, key = sess._algo_engine(ConnectedComponentsProgram(), None, 10)
-    assert eng.expand_path == "custom" and eng.expand_fn is marker
-    assert eng.value_expand_fn is None
-    # and the cache key must distinguish custom-fn configs
-    k2 = BFSConfig(grid=(1, 1), edge_chunk=256) \
-        .algo_engine_key(("cc",), "bitmap", 10)
-    assert cfg.algo_engine_key(("cc",), "bitmap", 10) != k2
-
-
-def test_engine_uses_fused_path(graphs):
-    _, gs = graphs
-    eng_p = gs["pallas-interpret"].engine_for(
-        BFSConfig(grid=(1, 1), edge_chunk=256, expand="pallas-interpret"))
-    assert eng_p.expand_path == "pallas-interpret"
-    assert eng_p.expand_fn is not None and eng_p.value_expand_fn is not None
-    eng_r = gs["reference"].engine_for(
-        BFSConfig(grid=(1, 1), edge_chunk=256, expand="reference"))
-    assert eng_r.expand_path == "reference"
-    assert eng_r.expand_fn is None and eng_r.value_expand_fn is None
-
-
-def test_kernels_import_is_lazy():
-    """`import repro.kernels` must not pull Pallas; only touching a kernel
-    symbol may (the guard that keeps `import repro` working without it)."""
-    code = (
-        "import sys, repro, repro.kernels\n"
-        "assert 'repro.kernels.expand' not in sys.modules\n"
-        "assert 'jax.experimental.pallas' not in sys.modules\n"
-        "from repro.kernels import resolve_expand_path\n"
-        "assert resolve_expand_path('reference') == 'reference'\n"
-        "assert 'jax.experimental.pallas' not in sys.modules\n"
-        "from repro.kernels import local_expand\n"
-        "assert 'repro.kernels.expand' in sys.modules\n")
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                       "src"),
-               JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=120)
-    assert r.returncode == 0, r.stderr[-2000:]

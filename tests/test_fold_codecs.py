@@ -1,4 +1,5 @@
-"""Fold wire-format coverage (DESIGN.md sec. 4 + 10).
+"""Fold wire-format coverage (DESIGN.md sec. 4 + 10), against numpy
+oracles.
 
   * pack/unpack bitmap round-trip at non-multiple-of-32 block sizes;
   * delta encode/decode round-trip (pure, no mesh);
@@ -7,11 +8,13 @@
   * wire-size ordering: bitmap < delta < list for one fold exchange;
   * ONE col_all_to_all per fold (and per value-fold) per level, counted on
     the traced jaxpr of every program x codec (the single-message gate);
-  * the Pallas fold kernels (prefix-sum compaction, bitmap pack/unpack,
-    delta encode/decode) bit-identical to the reference jnp formulas,
-    property-tested incl. S not divisible by 32 and empty/full buckets;
-  * fold-path selection rules (REPRO_FOLD, resolved engine cache keys) and
-    the delta S > 65536 error surfacing through GraphSession/BFSConfig;
+  * the fold's compactions (`compact_blocks`, `pack_blocks`,
+    `owned_to_front`), bitmap pack/unpack and delta encode/decode against
+    numpy, property-tested incl. S not divisible by 32 and empty/full
+    buckets;
+  * BFS and CC through the session equal the host references under every
+    codec, and the delta S > 65536 error surfaces through
+    GraphSession/BFSConfig;
   * the compat shim is the only module touching the version-specific
     shard_map / AxisType jax API surface.
 """
@@ -32,15 +35,6 @@ from repro.core.types import LocalGraph2D
 from repro.dist import exchange as X
 from repro.dist.compat import make_mesh
 from repro.graphgen import rmat_edges, build_csc
-from repro.kernels.select import FOLD_ENV, resolve_fold_path
-
-
-@pytest.fixture(scope="module")
-def fold_ops():
-    """The Pallas fold-kernel bundle in interpret mode (CPU-runnable)."""
-    from repro.kernels import make_fold_ops
-
-    return make_fold_ops(path="pallas-interpret")
 
 
 @pytest.mark.parametrize("S", [1, 7, 31, 32, 33, 63, 64, 65, 96, 127])
@@ -152,24 +146,24 @@ def _canonical_buckets(subsets, vals_rng, S, j):
     return jnp.asarray(ids), jnp.asarray(cnt), jnp.asarray(vals)
 
 
-def _emulate_fold_values(codec_name, ids, cnt, vals, S, j, ops=None):
+def _emulate_fold_values(codec_name, ids, cnt, vals, S, j):
     """Receiver-side (ids, cnt, vals) for one emulated fold exchange."""
     if codec_name == "list":
         return np.asarray(ids), np.asarray(cnt), np.asarray(vals)
     if codec_name == "bitmap":
-        words = X.BitmapFold.encode(ids, cnt, S, ops)
-        ri, rc = X.BitmapFold.decode(words, jnp.int32(j), S, ops)
+        words = X.BitmapFold.encode(ids, cnt, S)
+        ri, rc = X.BitmapFold.decode(words, jnp.int32(j), S)
         return np.asarray(ri), np.asarray(rc), np.asarray(vals)
-    gaps = X.DeltaFold.encode(ids, cnt, S, ops)
+    gaps = X.DeltaFold.encode(ids, cnt, S)
     assert gaps.dtype == jnp.uint16
-    ri, rc = X.DeltaFold.decode(gaps, cnt, jnp.int32(j), S, ops)
+    ri, rc = X.DeltaFold.decode(gaps, cnt, jnp.int32(j), S)
     return np.asarray(ri), np.asarray(rc), np.asarray(vals)
 
 
-def _assert_roundtrip(subsets, S, j, seed=0, ops=None):
+def _assert_roundtrip(subsets, S, j, seed=0):
     ids, cnt, vals = _canonical_buckets(subsets, np.random.default_rng(seed),
                                         S, j)
-    got = {c: _emulate_fold_values(c, ids, cnt, vals, S, j, ops)
+    got = {c: _emulate_fold_values(c, ids, cnt, vals, S, j)
            for c in X.FOLD_CODECS}
     for name, (ri, rc, rv) in got.items():
         assert (rc == np.asarray(cnt)).all(), name
@@ -182,15 +176,15 @@ def _assert_roundtrip(subsets, S, j, seed=0, ops=None):
         assert (rv == np.asarray(vals)).all(), name
 
 
-@pytest.mark.parametrize("path", ["reference", "pallas-interpret"])
+@pytest.mark.parametrize("j", [0, 3], ids=["first-col", "last-col"])
 @pytest.mark.parametrize("S", [1, 32, 33, 64])
 @pytest.mark.parametrize("kind", ["empty", "single", "full", "mixed"])
-def test_fold_values_roundtrip_extremes(S, kind, path, request):
+def test_fold_values_roundtrip_extremes(S, kind, j):
     """Deterministic coverage of the density extremes (runs with or without
     hypothesis): empty frontier, single-vertex frontier, full frontier --
-    on both the reference formulas and the Pallas fold kernels."""
-    ops = request.getfixturevalue("fold_ops") if path != "reference" else None
-    C, j = 4, 2
+    received by the first and the last column of a C = 4 grid row, the
+    `j*S` offset of the owned rows at both ends."""
+    C = 4
     rng = np.random.default_rng(S)
     if kind == "empty":
         subsets = [set() for _ in range(C)]
@@ -202,7 +196,7 @@ def test_fold_values_roundtrip_extremes(S, kind, path, request):
         subsets = [set(), {int(rng.integers(0, S))}, set(range(S)),
                    set(rng.choice(S, size=int(rng.integers(0, S + 1)),
                                   replace=False).tolist())]
-    _assert_roundtrip(subsets, S, j, seed=S, ops=ops)
+    _assert_roundtrip(subsets, S, j, seed=S)
 
 
 @settings(max_examples=60, deadline=None)
@@ -250,63 +244,60 @@ def test_set_fold_encode_decode_property(S, seed):
 
 
 # ----------------------------------------------------------------------------
-# Pallas fold kernels (DESIGN.md sec. 10): property-tested bit-identity of
-# the prefix-sum compaction, bitmap pack/unpack and delta encode/decode
-# against the reference jnp formulas, incl. S not divisible by 32 and
-# empty/full buckets.
+# The fold's compactions, bitmap and delta formulas against numpy, incl. S
+# not divisible by 32 and empty/full buckets.
 # ----------------------------------------------------------------------------
+
+def _np_pack_bits(mask):
+    """(N, S) bool -> (N, ceil(S/32)) uint32, bit s of word w = mask[32w+s]."""
+    N, S = mask.shape
+    W = (S + 31) // 32
+    bits = np.zeros((N, 32 * W), bool)
+    bits[:, :S] = mask
+    return np.packbits(bits, axis=1, bitorder="little").view("<u4") \
+        .astype(np.uint32)
+
 
 @settings(max_examples=40, deadline=None)
 @given(st.integers(1, 4), st.integers(1, 97), st.integers(0, 10_000))
 def test_compact_rows_matches_argsort_property(N, S, seed):
-    """The rank-select compaction kernel front-packs exactly like the
-    reference stable-argsort path, at any density including 0 and S."""
-    from repro.kernels import make_fold_ops
-
-    ops = make_fold_ops(path="pallas-interpret")
+    """`compact_blocks` (the expand exchange's compaction) concatenates
+    each block's valid prefix in block order, pads with `fill`, and counts
+    them, at any density including 0 and S."""
     rng = np.random.default_rng(seed)
     density = rng.choice([0.0, 0.25, 0.75, 1.0])
-    mask = rng.random((N, S)) < density
-    a = rng.integers(-5, 1 << 30, (N, S)).astype(np.int32)
-    b = rng.integers(-5, 1 << 30, (N, S)).astype(np.int32)
-    (pa, pb), cnt = ops.compact_rows(mask, (a, b), (-1, 7))
-    pa, pb, cnt = np.asarray(pa), np.asarray(pb), np.asarray(cnt)
-    for r in range(N):
-        va, vb = a[r][mask[r]], b[r][mask[r]]
-        k = len(va)
-        assert cnt[r] == k
-        assert (pa[r, :k] == va).all() and (pa[r, k:] == -1).all()
-        assert (pb[r, :k] == vb).all() and (pb[r, k:] == 7).all()
+    cnts = (rng.random(N) * density * (S + 1)).astype(np.int32).clip(0, S)
+    vals = rng.integers(-5, 1 << 30, (N, S)).astype(np.int32)
+    out, total = F.compact_blocks(jnp.asarray(vals), jnp.asarray(cnts),
+                                  fill=7)
+    want = np.concatenate([vals[r, :cnts[r]] for r in range(N)])
+    k = want.shape[0]
+    out = np.asarray(out)
+    assert int(total) == k and out.shape == (N * S,)
+    assert (out[:k] == want).all() and (out[k:] == 7).all()
 
 
 @settings(max_examples=40, deadline=None)
 @given(st.integers(1, 97), st.integers(0, 10_000))
 def test_fold_kernel_bitmap_roundtrip_property(S, seed):
-    """pack_bits/unpack_bits == pack_bitmap/unpack_bitmap bit for bit at
-    any S (incl. not divisible by 32); roundtrip recovers the mask."""
-    from repro.kernels import make_fold_ops
-
-    ops = make_fold_ops(path="pallas-interpret")
+    """pack_bitmap == numpy little-endian bit packing at any S (incl. not
+    divisible by 32, pad bits zero); unpack_bitmap recovers the mask."""
     rng = np.random.default_rng(seed)
     mask = rng.random((3, S)) < rng.choice([0.0, 0.3, 1.0])
-    words = ops.pack_bits(jnp.asarray(mask))
-    ref = F.pack_bitmap(jnp.asarray(mask))
+    words = F.pack_bitmap(jnp.asarray(mask))
     assert words.dtype == jnp.uint32
-    np.testing.assert_array_equal(np.asarray(words), np.asarray(ref))
-    back = ops.unpack_bits(words, S)
-    np.testing.assert_array_equal(np.asarray(back), mask)
-    np.testing.assert_array_equal(np.asarray(F.unpack_bitmap(ref, S)), mask)
+    np.testing.assert_array_equal(np.asarray(words), _np_pack_bits(mask))
+    np.testing.assert_array_equal(np.asarray(F.unpack_bitmap(words, S)),
+                                  mask)
 
 
 @settings(max_examples=40, deadline=None)
 @given(st.integers(1, 80), st.integers(0, 10_000))
 def test_fold_kernel_delta_roundtrip_property(S, seed):
-    """Kernel delta encode/decode == the reference formulas on random
-    buckets at any density (empty and full included), and the decode
-    recovers each bucket's sorted id set."""
-    from repro.kernels import make_fold_ops
-
-    ops = make_fold_ops(path="pallas-interpret")
+    """DeltaFold.encode == numpy first-order gaps of each bucket's sorted
+    offsets (slot 0 absolute, pads 0) on random buckets at any density
+    (empty and full included), and decode recovers each bucket's sorted
+    id set."""
     rng = np.random.default_rng(seed)
     C, j = 3, 1
     dst = np.full((C, S), -1, np.int32)
@@ -317,46 +308,55 @@ def test_fold_kernel_delta_roundtrip_property(S, seed):
         dst[m, :k] = j * S + t
         cnts.append(k)
     cnt = jnp.asarray(cnts, jnp.int32)
-    g_ref = X.DeltaFold.encode(jnp.asarray(dst), cnt, S)
-    g_ker = X.DeltaFold.encode(jnp.asarray(dst), cnt, S, ops)
-    assert g_ker.dtype == jnp.uint16
-    np.testing.assert_array_equal(np.asarray(g_ker), np.asarray(g_ref))
-    r_ref, _ = X.DeltaFold.decode(g_ref, cnt, jnp.int32(j), S)
-    r_ker, _ = X.DeltaFold.decode(g_ker, cnt, jnp.int32(j), S, ops)
-    np.testing.assert_array_equal(np.asarray(r_ker), np.asarray(r_ref))
+    gaps = X.DeltaFold.encode(jnp.asarray(dst), cnt, S)
+    assert gaps.dtype == jnp.uint16
+    want = np.zeros((C, S), np.uint16)
     for m in range(C):
-        want = np.sort(dst[m, :cnts[m]])
-        assert (np.asarray(r_ker)[m, :cnts[m]] == want).all()
+        ts = np.sort(dst[m, :cnts[m]] % S)
+        want[m, :cnts[m]] = np.diff(ts, prepend=0)
+    np.testing.assert_array_equal(np.asarray(gaps), want)
+    ri, rc = X.DeltaFold.decode(gaps, cnt, jnp.int32(j), S)
+    ri = np.asarray(ri)
+    assert (np.asarray(rc) == np.asarray(cnts)).all()
+    for m in range(C):
+        assert (ri[m, :cnts[m]] == np.sort(dst[m, :cnts[m]])).all()
+        assert (ri[m, cnts[m]:] == -1).all()
 
 
-def test_fold_kernel_program_helpers_match(fold_ops, rng):
-    """pack_blocks / owned_to_front / compact_blocks / expand_exchange_values
-    compaction: kernel path == reference path on the same inputs."""
+def test_fold_kernel_program_helpers_match(rng):
+    """pack_blocks / owned_to_front / compact_blocks against numpy sets:
+    ascending front-packed ids with aligned values and their counts."""
     from repro.algos import program as PR
 
     grid = Grid2D(1, 4, 4 * 33)                 # S = 33: not a word multiple
     S, C = grid.S, grid.C
+    I32_MAX = int(np.iinfo(np.int32).max)
     improved = rng.random(C * S) < 0.3
     vals = rng.integers(0, 1 << 20, C * S).astype(np.int32)
-    a = PR.pack_blocks(jnp.asarray(improved), jnp.asarray(vals), grid)
-    b = PR.pack_blocks(jnp.asarray(improved), jnp.asarray(vals), grid,
-                       ops=fold_ops)
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    ids, cnt, vs = (np.asarray(x) for x in PR.pack_blocks(
+        jnp.asarray(improved), jnp.asarray(vals), grid))
+    for m in range(C):
+        rows = m * S + np.flatnonzero(improved[m * S:(m + 1) * S])
+        k = rows.size
+        assert cnt[m] == k
+        assert (ids[m, :k] == rows).all() and (ids[m, k:] == -1).all()
+        assert (vs[m, :k] == vals[rows]).all()
+        assert (vs[m, k:] == I32_MAX).all()
     changed = rng.random(S) < 0.4
     ov = rng.integers(0, 1 << 20, S).astype(np.int32)
-    a = PR.owned_to_front(jnp.asarray(changed), jnp.asarray(ov), 2, S)
-    b = PR.owned_to_front(jnp.asarray(changed), jnp.asarray(ov), 2, S,
-                          ops=fold_ops)
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    front, pay, nc = (np.asarray(x) for x in PR.owned_to_front(
+        jnp.asarray(changed), jnp.asarray(ov), 2, S))
+    t = np.flatnonzero(changed)
+    assert int(nc) == t.size
+    assert (front[:t.size] == 2 * S + t).all() and (front[t.size:] == -1).all()
+    assert (pay[:t.size] == ov[t]).all() and (pay[t.size:] == I32_MAX).all()
     blocks = rng.integers(0, 100, (3, 7)).astype(np.int32)
     cnts = rng.integers(0, 8, 3).astype(np.int32)
-    a = F.compact_blocks(jnp.asarray(blocks), jnp.asarray(cnts))
-    b = F.compact_blocks(jnp.asarray(blocks), jnp.asarray(cnts),
-                         ops=fold_ops)
-    np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
-    assert int(a[1]) == int(b[1])
+    out, total = F.compact_blocks(jnp.asarray(blocks), jnp.asarray(cnts))
+    want = np.concatenate([blocks[r, :cnts[r]] for r in range(3)])
+    out = np.asarray(out)
+    assert int(total) == want.size
+    assert (out[:want.size] == want).all() and (out[want.size:] == -1).all()
 
 
 # ----------------------------------------------------------------------------
@@ -364,14 +364,17 @@ def test_fold_kernel_program_helpers_match(fold_ops, rng):
 # the traced jaxpr of the whole engine program (acceptance criterion).
 # ----------------------------------------------------------------------------
 
+def _collectives_edges():
+    return np.asarray(rmat_edges(jax.random.key(5), 8, 8))
+
+
 @pytest.fixture(scope="module")
 def _collectives_graph():
-    edges = np.asarray(rmat_edges(jax.random.key(5), 8, 8))
+    edges = _collectives_edges()
     w = np.random.default_rng(0).integers(1, 256, size=edges.shape[1]) \
         .astype(np.uint8)
     return DistGraph.from_edges(
-        edges, BFSConfig(grid=(1, 1), edge_chunk=256, expand="reference",
-                         fold="reference"), n=256, weights=w)
+        edges, BFSConfig(grid=(1, 1), edge_chunk=256), n=256, weights=w)
 
 
 @pytest.mark.parametrize("codec", ["list", "bitmap", "delta"])
@@ -386,8 +389,7 @@ def test_one_all_to_all_per_fold(_collectives_graph, codec):
 
     g = _collectives_graph
     cs = g.csc
-    sess = g.session(BFSConfig(grid=(1, 1), edge_chunk=256, fold_codec=codec,
-                               expand="reference", fold="reference"))
+    sess = g.session(BFSConfig(grid=(1, 1), edge_chunk=256, fold_codec=codec))
     jx = str(jax.make_jaxpr(sess.engine._run.__wrapped__)(
         cs.col_off, cs.row_idx, cs.nnz, jnp.int32(0)))
     assert jx.count("all_to_all") == 2, codec
@@ -424,8 +426,7 @@ def _butterfly_graph():
     return DistGraph(
         Topology.for_grid(grid, fake), lg, edges=edges, n=256,
         weights=partition_edge_vals(edges, w, grid),
-        config=BFSConfig(grid=(1, 4), edge_chunk=256, expand="reference",
-                         fold="reference"))
+        config=BFSConfig(grid=(1, 4), edge_chunk=256))
 
 
 @pytest.mark.parametrize("codec", ["list", "bitmap", "delta"])
@@ -442,7 +443,6 @@ def test_exchange_collective_counts(_butterfly_graph, codec, exchange):
     g = _butterfly_graph
     cs = g.csc
     sess = g.session(BFSConfig(grid=(1, 4), edge_chunk=256, fold_codec=codec,
-                               expand="reference", fold="reference",
                                exchange=exchange))
     stages = 2                                   # log2(C) at C = 4
     jx = str(jax.make_jaxpr(sess.engine._run.__wrapped__)(
@@ -466,76 +466,38 @@ def test_exchange_collective_counts(_butterfly_graph, codec, exchange):
 
 
 # ----------------------------------------------------------------------------
-# Fold-path selection rules, cache keys, engine parity, delta block-size
-# error surfacing (DESIGN.md sec. 10)
+# Session answers against the host references, delta block-size error
+# surfacing (DESIGN.md sec. 10)
 # ----------------------------------------------------------------------------
-
-def test_resolve_fold_path_rules(monkeypatch):
-    monkeypatch.delenv(FOLD_ENV, raising=False)
-    assert resolve_fold_path("reference") == "reference"
-    assert resolve_fold_path("pallas-interpret") == "pallas-interpret"
-    assert resolve_fold_path("auto", platform="cpu") == "reference"
-    # the TPU compiler refuses the kernels: auto takes the jnp scan there,
-    # an explicit "pallas" is passed through to the compiler, and the
-    # interpreter is refused off CPU
-    assert resolve_fold_path("auto", platform="tpu") == "reference"
-    assert resolve_fold_path("pallas", platform="tpu") == "pallas"
-    with pytest.raises(ValueError, match="CPU only"):
-        resolve_fold_path("pallas-interpret", platform="tpu")
-    assert resolve_fold_path(None, platform="gpu") == "pallas"
-    monkeypatch.setenv(FOLD_ENV, "pallas-interpret")
-    assert resolve_fold_path("auto", platform="cpu") == "pallas-interpret"
-    with pytest.raises(ValueError, match=FOLD_ENV):
-        resolve_fold_path("auto", platform="tpu")
-    # explicit spellings are NOT overridden by the environment
-    assert resolve_fold_path("reference") == "reference"
-    monkeypatch.setenv(FOLD_ENV, "nonsense")
-    with pytest.raises(ValueError, match="REPRO_FOLD"):
-        resolve_fold_path("auto")
-    monkeypatch.delenv(FOLD_ENV)
-    with pytest.raises(ValueError, match="fold="):
-        resolve_fold_path("zstd")
-
-
-def test_config_keys_use_resolved_fold_path(monkeypatch):
-    monkeypatch.delenv(FOLD_ENV, raising=False)
-    ref = BFSConfig(fold="reference")
-    pal = BFSConfig(fold="pallas-interpret")
-    auto = BFSConfig()
-    assert ref.engine_key != pal.engine_key
-    expected = resolve_fold_path("auto")
-    assert auto.fold_path == expected
-    if expected == "reference":
-        assert auto.engine_key == ref.engine_key
-    monkeypatch.setenv(FOLD_ENV, "pallas-interpret")
-    assert auto.fold_path == "pallas-interpret"
-    assert auto.engine_key == pal.engine_key      # env re-keys "auto"
-    k1 = auto.algo_engine_key(("cc",), "bitmap", 10)
-    monkeypatch.delenv(FOLD_ENV)
-    assert auto.algo_engine_key(("cc",), "bitmap", 10) != k1
-
 
 @pytest.mark.parametrize("codec", ["list", "bitmap", "delta"])
 def test_fold_paths_bit_identical_through_session(_collectives_graph, codec):
-    """BFS + CC through the session: fold="pallas-interpret" ==
-    fold="reference", bit for bit (levels, preds, labels, exact counters).
-    The full program x codec x path matrix runs in the REPRO_FOLD CI leg."""
+    """BFS + CC through the session under each codec equal the host
+    references: levels, preds (the smallest frontier parent), scanned
+    edges and component labels."""
+    from repro.algos import cc_reference
+
     g = _collectives_graph
-    outs = {}
-    for path in ("reference", "pallas-interpret"):
-        s = g.session(BFSConfig(grid=(1, 1), edge_chunk=256,
-                                fold_codec=codec, expand="reference",
-                                fold=path))
-        assert s.engine.fold_path == path
-        assert (s.engine.fold_ops is None) == (path == "reference")
-        out = s.bfs(jnp.asarray([3, 11], jnp.int32))
-        cc = s.connected_components(fold_codec=codec)
-        outs[path] = (np.asarray(out.level), np.asarray(out.pred),
-                      out.edges_scanned, np.asarray(cc.labels),
-                      cc.edges_scanned)
-    a, b = outs["reference"], outs["pallas-interpret"]
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    edges, n = _collectives_edges(), 256
+    deg = np.bincount(edges[0], minlength=n)
+    co, ri = build_csc(edges, n)
+    s = g.session(BFSConfig(grid=(1, 1), edge_chunk=256, fold_codec=codec))
+    roots = [3, 11]
+    out = s.bfs(jnp.asarray(roots, jnp.int32))
+    for b, root in enumerate(roots):
+        level = np.asarray(out.level)[b, :n]
+        pred = np.asarray(out.pred)[b, :n]
+        ref, _ = bfs_reference_py(co, ri, root, n)
+        np.testing.assert_array_equal(level, ref)
+        validate_bfs(edges, level, pred, root)
+        for v in np.flatnonzero(level > 0):
+            parents = edges[0][(edges[1] == v)
+                               & (level[edges[0]] == level[v] - 1)]
+            assert pred[v] == parents.min(), v
+        assert out.edges_scanned[b] == int(deg[level >= 0].sum())
+    cc = s.connected_components(fold_codec=codec)
+    np.testing.assert_array_equal(np.asarray(cc.labels)[:n],
+                                  cc_reference(edges, n))
 
 
 def test_delta_block_size_error_names_working_codecs():
@@ -544,16 +506,14 @@ def test_delta_block_size_error_names_working_codecs():
     edges = np.array([[0, 1], [1, 2]])
     n = 1 << 17                                  # 1x1 grid -> S = 131072
     g = DistGraph.from_edges(
-        edges, BFSConfig(grid=(1, 1), expand="reference"), n=n)
+        edges, BFSConfig(grid=(1, 1)), n=n)
     with pytest.raises(ValueError) as ei:
-        g.session(BFSConfig(grid=(1, 1), fold_codec="delta",
-                            expand="reference"))
+        g.session(BFSConfig(grid=(1, 1), fold_codec="delta"))
     msg = str(ei.value)
     assert "delta" in msg and "65536" in msg
     assert "bitmap" in msg and "list" in msg     # the codecs that DO work
     # and the working codecs really do build at this block size
-    g.session(BFSConfig(grid=(1, 1), fold_codec="bitmap",
-                        expand="reference"))
+    g.session(BFSConfig(grid=(1, 1), fold_codec="bitmap"))
 
 
 def test_compat_is_only_direct_importer():

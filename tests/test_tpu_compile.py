@@ -3,8 +3,7 @@
 Nothing here runs on a chip.  `get_topology_desc` describes a v5e 2x2 host
 and each test compiles `engine._run_batch` for it from ShapeDtypeStructs,
 without planning a graph, so what the TPU compiler refuses fails here at no
-chip time.  The paths compiled are the ones "auto" resolves to on TPU
-(`repro.kernels.select.AUTO_PATH`).
+chip time.
 
 The topology is described inside the module fixture and nowhere else: a
 process that loads the TPU library keeps it, and its lock, until it exits,
@@ -28,7 +27,6 @@ from repro.api.session import build_engine
 from repro.core.types import Grid2D
 from repro.dist.compat import make_mesh
 from repro.dist.topology import Topology
-from repro.kernels.select import AUTO_PATH
 
 # chip_smoke.py's one-chip graph: R-MAT scale 20, edge factor 16,
 # symmetrised to 2^25 directed edges, all of them on the 1x1 grid's device
@@ -64,10 +62,8 @@ def compile_batch(topo, R, C, *, scale, e_max, direction=False,
     n = 1 << scale
     grid = Grid2D.for_vertices(n, R, C)
     mesh = make_mesh((R, C), ("r", "c"), devices=topo.devices[:R * C])
-    path = AUTO_PATH["tpu"]
     config = BFSConfig(grid=grid, edge_chunk=EDGE_CHUNK, direction=direction,
-                       exchange=exchange, expand=path, fold=path,
-                       bottomup=path).resolve_exchange(grid)
+                       exchange=exchange).resolve_exchange(grid)
     topology = Topology.for_grid(grid, mesh)
     engine = build_engine(topology, config)
     dev = NamedSharding(mesh, topology.dev_spec)
@@ -100,7 +96,7 @@ def test_one_chip_smoke_shapes_compile(one_chip, direction):
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert need < V5E_HBM, mem
-    assert "tpu_custom_call" not in compiled.as_text()   # no Pallas kernel
+    assert "tpu_custom_call" not in compiled.as_text()   # no custom kernel
 
 
 _GATHER = re.compile(r"=\s*\w+\[(\d+)\][^=]*\sgather\(.*op_name=\"([^\"]*)\"")
